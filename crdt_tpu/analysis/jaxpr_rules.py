@@ -8,8 +8,8 @@ canonical capacity ladder and walks the resulting ``ClosedJaxpr``\\s:
 
 * **KC01 dtype-lowering** — 64-bit values inside a ``pallas_call``
   region.  Mosaic has no 64-bit support; an i64 scalar that slips into
-  a Pallas kernel is exactly the "jax 0.4.x Pallas skew" failure class
-  the conftest xfails at runtime — this pins it statically.  A spec
+  a Pallas kernel fails (or hangs) in its compiler — this pins it
+  statically.  A spec
   declared ``mosaic=True`` that traces no ``pallas_call`` at all is
   also flagged (a stale declaration hides the whole check).
 * **KC02 scatter-determinism** — ``scatter-add``/``scatter-mul`` on
@@ -33,11 +33,10 @@ canonical capacity ladder and walks the resulting ``ClosedJaxpr``\\s:
 Findings anchor at real source coordinates (the offending equation's
 user frame when jax kept one, else the kernel's jit site), so the
 standard ``# crdtlint: disable=KCxx`` pragmas and the shared
-``baseline.json`` park/stale machinery apply unchanged.  One extra
-consistency screw: a pragma sanctioning KC01 on a Mosaic kernel is
-itself re-flagged when :func:`crdt_tpu.config.pallas_mosaic_skew`
-reports no skew — the static gate and the runtime xfail gate can
-never disagree silently.
+``baseline.json`` park/stale machinery apply unchanged, with one
+exception: a pragma sanctioning KC01 is itself re-flagged.  Mosaic has
+no 64-bit support on any supported jax, so a 64-bit op inside a
+``pallas_call`` is never a valid sanction.
 """
 
 from __future__ import annotations
@@ -71,7 +70,6 @@ class KernelReport:
     skipped: List[dict] = dataclasses.field(default_factory=list)
     trace_errors: List[str] = dataclasses.field(default_factory=list)
     mosaic: dict = dataclasses.field(default_factory=dict)
-    skew_reason: Optional[str] = None
     jit_sites: int = 0
     elapsed_s: float = 0.0
 
@@ -118,7 +116,7 @@ def _eqn_loc(eqn, root: str):
     try:
         from jax._src import source_info_util
 
-        for frame in source_info_util.user_frames(eqn.source_info):
+        for frame in source_info_util.user_frames(eqn.source_info.traceback):
             fname = getattr(frame, "file_name", "") or ""
             if fname.startswith(root):
                 rel = os.path.relpath(fname, root).replace(os.sep, "/")
@@ -231,8 +229,7 @@ def _check_spec(spec: KernelSpec, cases: Sequence[TraceCase],
                                 f"kernel {spec.name} [{case.rung}]: 64-bit "
                                 f"value ({aval}) reaches primitive "
                                 f"{name!r} inside a pallas_call — Mosaic "
-                                "cannot lower 64-bit types (the jax 0.4.x "
-                                "Pallas-skew class); keep the kernel "
+                                "cannot lower 64-bit types; keep the kernel "
                                 "domain <=32-bit",
                             ))
             # KC02: order-sensitive scatter accumulation
@@ -319,7 +316,7 @@ def run_kernelcheck(specs: Optional[Sequence[KernelSpec]] = None,
     t0 = time.perf_counter()
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
-    from ..config import enable_x64, pallas_mosaic_skew
+    from ..config import enable_x64
 
     enable_x64()  # the batch package's import-time contract
 
@@ -327,7 +324,6 @@ def run_kernelcheck(specs: Optional[Sequence[KernelSpec]] = None,
         specs = MANIFEST
     root = root or repo_root()
     report = KernelReport(kernels=len(specs))
-    report.skew_reason = pallas_mosaic_skew()
 
     # parse the spec'd source files once: jit-site lines for finding
     # anchors, pragma maps for suppression
@@ -371,20 +367,16 @@ def run_kernelcheck(specs: Optional[Sequence[KernelSpec]] = None,
         else:
             live.append(f)
 
-    # the skew cross-check: a KC01 pragma is only a valid sanction while
-    # the runtime gate (pallas_mosaic_skew) actually reports a skew —
-    # on a fixed jax the pragma must come OFF so the check re-arms
-    if report.skew_reason is None:
-        for f in suppressed:
-            if f.rule == "KC01":
-                live.append(Finding(
-                    "KC01", f.path, f.line, 0,
-                    "stale KC01 sanction: a pragma suppresses a 64-bit "
-                    "Mosaic finding here, but config.pallas_mosaic_skew() "
-                    "reports no skew on this jax — remove the pragma so "
-                    "the static gate re-arms (it must never disagree "
-                    "with the conftest xfail gate silently)",
-                ))
+    # a KC01 pragma is never a valid sanction: Mosaic has no 64-bit
+    # support, so the finding stays live until the op is gone
+    for f in suppressed:
+        if f.rule == "KC01":
+            live.append(Finding(
+                "KC01", f.path, f.line, 0,
+                "stale KC01 sanction: a pragma suppresses a 64-bit "
+                "Mosaic finding here — Mosaic has no 64-bit support, so "
+                "remove the pragma and the 64-bit op",
+            ))
 
     live.sort(key=lambda f: (f.path, f.line, f.col, f.rule))
     result = LintResult(

@@ -386,9 +386,10 @@ def _unjit(fn):
 
 
 def _b_orswot_batch(kernel_attr: str, statics: Callable = None,
-                    extra: Callable = None, stacked: bool = False):
+                    extra: Callable = None, fleets: bool = False):
     """Shared builder for the orswot_batch jitted kernels: planes across
-    the ladder, plus ``extra(a, m, d) -> tuple`` trailing args and
+    the ladder (``fleets``: one tuple of ``LADDER_R`` plane sets), plus
+    ``extra(a, m, d) -> tuple`` trailing args and
     ``statics(a, m, d) -> dict`` pre-bound keywords."""
 
     def build():
@@ -400,8 +401,8 @@ def _b_orswot_batch(kernel_attr: str, statics: Callable = None,
         cases = []
         for (a, m, d) in LADDER:
             planes = _orswot_planes(a, m, d)
-            if stacked:
-                planes = _stacked(planes)
+            if fleets:
+                planes = ((planes,) * LADDER_R,)
             kw = statics(a, m, d) if statics else {}
             args = planes + (extra(a, m, d) if extra else ())
             cases.append(TraceCase(
@@ -1123,9 +1124,9 @@ MANIFEST: tuple = (
                sharding=pointwise(),
                build=_b_orswot_merge()),
     KernelSpec("batch.orswot.fold_tree", _OB, "_fold_tree",
-               sharding=pointwise(axis=1),  # axis 0 is the replica stack
+               sharding=pointwise(),
                build=_b_orswot_batch(
-                   "_fold_tree", stacked=True,
+                   "_fold_tree", fleets=True,
                    statics=lambda a, m, d: {
                        "m_cap": m, "d_cap": d, "plunger": True,
                        "impl": "rank"})),

@@ -88,7 +88,7 @@ _SCATTER_PRIMS = {
 }
 
 _CALL_PRIMS = {
-    "pjit", "closed_call", "core_call", "remat", "checkpoint",
+    "pjit", "jit", "closed_call", "core_call", "remat", "checkpoint",
     "custom_jvp_call", "custom_vjp_call", "custom_vjp_call_jaxpr",
     "shard_map", "custom_partitioning",
 }
@@ -609,7 +609,7 @@ def _shard_args(args, obj_axes: Dict[int, int], s: int):
     import jax
     from jax.sharding import AbstractMesh, NamedSharding, PartitionSpec
 
-    mesh = AbstractMesh((("objects", s),))
+    mesh = AbstractMesh((s,), ("objects",))
     leaves, treedef = jax.tree_util.tree_flatten(args)
     out = []
     for i, leaf in enumerate(leaves):

@@ -93,7 +93,7 @@ _CRDT_ERRORS = {
     "CapacityOverflowError", "ConflictingMarker", "MergeConflict",
     "NestedOpFailed", "TransportError", "SyncTimeoutError",
     "PeerUnavailableError", "TransportClosedError", "TransportFrameError",
-    "OpLogOverflowError", "UnsupportedBackendError",
+    "OpLogOverflowError",
     "DurabilityError", "CheckpointFormatError",
     "ConsistencyUnavailableError",
 }

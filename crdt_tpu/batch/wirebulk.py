@@ -217,7 +217,7 @@ def orswot_planes_from_wire(blobs, universe, out=None):
     ``out``: optional preallocated plane 5-tuple passed through to
     ``engine.orswot_ingest_wire`` for buffer REUSE across calls — fresh
     per-call plane allocations page-fault GBs at north-star chunk scale
-    and were the measured e2e ingest collapse (PERF.md).
+    and were the measured e2e ingest collapse (docs/GUIDE.md).
 
     Hard statuses raise ``ValueError`` with the caller's blob index;
     status==1 blobs (structure outside the fast-path grammar) are

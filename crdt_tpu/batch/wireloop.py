@@ -13,7 +13,7 @@ the ``native_fraction`` counters now prove that from the artifact
 alone); it was **allocation churn**: every ``from_wire`` call allocated
 a fresh ~300 MB dense plane set per fleet, page-faulting ~2.5 GB of
 zeroed memory per chunk and freeing it again, which measured 27× slower
-than the identical parse into warm buffers (see PERF.md "wire-loop
+than the identical parse into warm buffers (see docs/GUIDE.md "wire-loop
 pipeline").
 
 :class:`PipelinedWireLoop` rebuilds the loop around that finding:

@@ -13,7 +13,7 @@ injector to prove all of it converges under loss and flapping links
 
 Everything observable feeds ``crdt_tpu_cluster_*`` metrics and the
 flight recorder; everything that fails speaks the
-:class:`~crdt_tpu.error.TransportError` taxonomy.  PERF.md "Cluster
+:class:`~crdt_tpu.error.TransportError` taxonomy.  docs/GUIDE.md "Cluster
 runtime" documents the defaults and the knobs.
 """
 

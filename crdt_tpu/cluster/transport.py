@@ -52,6 +52,7 @@ import dataclasses
 import queue
 import random
 import re
+import select
 import socket
 import struct
 import time
@@ -178,23 +179,56 @@ def queue_pair(default_timeout: float = 120.0
 class TcpTransport(Transport):
     """Length-prefixed frames (``<I`` prefix) over a connected socket —
     the framing the TCP example always used, packaged so the cluster
-    runtime and the example share one implementation."""
+    runtime and the example share one implementation.
+
+    Sessions are symmetric: both peers send their hello and digest
+    before either reads.  A frame larger than the socket buffers (a
+    125,000-object digest vector is 1 MB) would then block both
+    ``sendall``\\ s for ever, so ``send`` drains the peer's incoming
+    bytes into a buffer while the socket is full, and ``recv`` reads
+    that buffer first."""
 
     _LEN = struct.Struct("<I")
+    _CHUNK = 1 << 20
 
     def __init__(self, sock: socket.socket, default_timeout: float = 120.0):
         self._sock = sock
         self._default_timeout = default_timeout
+        self._rx = bytearray()  # bytes read ahead while sending
+        self._eof = False
 
     def send(self, frame: bytes) -> None:
+        data = memoryview(self._LEN.pack(len(frame)) + frame)
+        poll = select.poll()
         try:
-            self._sock.sendall(self._LEN.pack(len(frame)) + frame)
+            while data:
+                poll.register(self._sock, select.POLLOUT
+                              | (0 if self._eof else select.POLLIN))
+                events = poll.poll(self._default_timeout * 1000)
+                if not events:
+                    raise SyncTimeoutError(
+                        f"socket send made no progress within "
+                        f"{self._default_timeout:.3f}s")
+                ev = events[0][1]
+                if ev & select.POLLIN and not self._eof:
+                    chunk = self._sock.recv(self._CHUNK)
+                    self._rx.extend(chunk)
+                    self._eof = not chunk
+                if ev & (select.POLLOUT | select.POLLERR | select.POLLHUP):
+                    try:  # what fits now; the rest after the next poll
+                        data = data[self._sock.send(data,
+                                                    socket.MSG_DONTWAIT):]
+                    except BlockingIOError:
+                        pass
         except (ConnectionError, BrokenPipeError, OSError) as e:
             raise TransportClosedError(f"socket send failed: {e}") from e
 
     def _recv_exact(self, n: int) -> bytes:
-        buf = bytearray()
+        buf = self._rx[:n]
+        del self._rx[:n]
         while len(buf) < n:
+            if self._eof:
+                raise TransportClosedError("peer closed the socket mid-frame")
             try:
                 chunk = self._sock.recv(n - len(buf))
             except socket.timeout:
@@ -215,10 +249,14 @@ class TcpTransport(Transport):
         return self._recv_exact(ln)
 
     def close(self) -> None:
-        try:
-            self._sock.close()
-        except OSError:
-            pass
+        # shutdown first: close() alone leaves a thread blocked in this
+        # socket's recv/send asleep, and the peer never sees EOF
+        for end in (lambda: self._sock.shutdown(socket.SHUT_RDWR),
+                    self._sock.close):
+            try:
+                end()
+            except OSError:
+                pass
 
 
 # ---- the resilient (ARQ) wrapper -------------------------------------------

@@ -37,53 +37,36 @@ def enable_x64() -> bool:
     return _X64_ENABLED
 
 
+def use_compile_cache() -> str:
+    """Point jax's persistent compilation cache at its one home and
+    return the directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, wins: jax reads it itself
+    and nothing is set here.  Otherwise the cache lives in
+    ``<checkout>/.jax_cache`` (git-ignored).  The path is part of the
+    cache key, so it is fixed — never derived from a temp name, a pid or
+    the time.  Set through ``jax.config`` so it takes effect even after
+    jax has been imported (``enable_x64`` imports it)."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache",
+    )
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def x64_disabled():
     """Context manager forcing 32-bit trace mode for a kernel trace
     (Mosaic has no 64-bit support — Python-int literals must not become
-    i64[] operands).  ``jax.enable_x64(False)`` on new jax,
-    ``jax.experimental.disable_x64()`` on 0.4.x, where ``jax.enable_x64``
-    does not exist."""
+    i64[] operands)."""
     import jax
 
-    try:
-        return jax.enable_x64(False)
-    except AttributeError:
-        from jax.experimental import disable_x64
-
-        return disable_x64()
-
-
-def pallas_mosaic_skew():
-    """Reason string when the installed jax cannot run the interpret-mode
-    Pallas ORSWOT kernels, else ``None`` — the ONE home for the
-    "jax 0.4.x Pallas skew" version gate (ROADMAP carried item).
-
-    Under jax 0.4.x (observed on 0.4.37), i64 scalars lowering into the
-    interpret-mode kernels recurse forever in Mosaic's int64→int32
-    truncation helper; the kernel entry points
-    (:func:`crdt_tpu.ops.orswot_pallas.merge` / ``fold_merge`` and
-    :func:`crdt_tpu.ops.orswot_fold_aligned.fold_merge`) call this and
-    raise a typed :class:`crdt_tpu.error.UnsupportedBackendError` at
-    the API boundary instead of failing deep in the compiler.  The test
-    harness xfail gate (``tests/conftest.py``) keys off the SAME
-    predicate, so the two can never drift.
-    """
-    import jax
-
-    try:
-        major, minor = (int(p) for p in jax.__version__.split(".")[:2])
-    except ValueError:
-        return None
-    if (major, minor) >= (0, 5):
-        return None
-    return (
-        f"jax {jax.__version__} cannot run the interpret-mode Pallas "
-        "ORSWOT kernels: i64 scalars lowering into interpret mode "
-        "recurse in Mosaic's int64->int32 truncation (the 0.4.37 skew; "
-        "ROADMAP 'jax 0.4.x Pallas skew').  Remediation: upgrade to "
-        "jax>=0.5, run on a real TPU backend (interpret=False), or use "
-        "the portable jnp path (crdt_tpu.ops.orswot_ops)"
-    )
+    return jax.enable_x64(False)
 
 
 def counter_dtype(config=None):
@@ -155,7 +138,7 @@ class CrdtConfig:
 
         ``counter_bits=32``: the measured product default (the unrolled
         and fused-Pallas fast paths are exact for uint32 only, and u64
-        measured 1.5× the u32 cost even on CPU — `PERF.md` "Counter
+        measured 1.5× the u32 cost even on CPU — `docs/GUIDE.md` "Counter
         width").  The u64 default on :class:`CrdtConfig` itself stays for
         reference parity (`vclock.rs:23`); use this constructor when the
         per-actor op count fits 2^32."""

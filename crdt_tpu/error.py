@@ -118,21 +118,6 @@ class OpLogOverflowError(CrdtError):
     """
 
 
-class UnsupportedBackendError(CrdtError, RuntimeError):
-    """A kernel cannot run on this backend/toolchain combination.
-
-    Raised by the version gates in front of the Mosaic kernels
-    (:mod:`crdt_tpu.ops.orswot_pallas`,
-    :mod:`crdt_tpu.ops.orswot_fold_aligned`) when the installed jax
-    would fail deep inside the compiler instead of at the API boundary
-    — e.g. the jax 0.4.x interpret-mode i64 lowering skew (ROADMAP
-    "jax 0.4.x Pallas skew").  The message always names the remediation
-    (upgrade jax, or use the portable jnp path).  Subclasses
-    ``RuntimeError`` so generic "kernel unavailable" handlers keep
-    working.
-    """
-
-
 class DurabilityError(CrdtError):
     """The durable-replica layer (:mod:`crdt_tpu.durable`) could not
     produce or restore persistent state: every retained snapshot

@@ -65,7 +65,7 @@ def _step_fn(mesh, axis: str, m_cap: int, d_cap: int, use_table: bool,
 
     from ..obs.kernels import observed_kernel
     from ..ops import orswot_ops
-    from ..parallel._compat import shard_map
+    from jax import shard_map
     from ..parallel.collective import _orswot_pair_merge
     from ..sync.digest import orswot_digest_body
 

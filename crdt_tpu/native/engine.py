@@ -634,7 +634,7 @@ def orswot_ingest_wire(buf, offsets, a: int, m: int, d: int, dtype, out=None):
     which is the point: a fresh ~plane-set allocation per call
     page-faults GBs of zeroed memory and measured a 27x ingest collapse
     at north-star chunk scale (the pipelined wire loop's staging buffers
-    exist to amortize exactly this; see PERF.md).
+    exist to amortize exactly this; see docs/GUIDE.md).
 
     Returns ``(clock, ids, dots, d_ids, d_clocks, status)`` where
     ``status`` is uint8[n]: 0 ok, 1 fast-path fallback (blob structure

@@ -60,7 +60,7 @@ first-class answer, in five parts:
 
 Import-light by design: nothing here imports JAX or numpy, so the
 scalar engine (and any process that only wants a counter) pays nothing
-for it.  PERF.md "Observability" documents naming conventions and how
+for it.  docs/GUIDE.md "Observability" documents naming conventions and how
 to read the flight recorder after a failed sync.
 """
 

@@ -50,7 +50,7 @@ keeps a bounded window of gossip-round outcomes and publishes
 ``sync.slo.converged_frac`` — the fraction of recent rounds that
 converged within the target budget.
 
-PERF.md "Latency & lag" documents the metric table and how to read a
+docs/GUIDE.md "Latency & lag" documents the metric table and how to read a
 :class:`SessionProfile`.
 """
 

@@ -1,6 +1,6 @@
 """The metric-namespace manifest — one source of truth for `crdt_tpu_*`.
 
-PERF.md's "Metric naming" table used to be prose only; a counter and a
+docs/GUIDE.md's "Metric naming" table used to be prose only; a counter and a
 histogram silently sharing a name (`executor.regrow`, PR 3) showed that
 the namespace needs to be machine-checkable.  This module IS the table:
 every metric the process may emit matches exactly one :class:`NameSpec`

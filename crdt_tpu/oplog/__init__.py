@@ -22,7 +22,7 @@ Integration: :class:`crdt_tpu.cluster.ClusterNode.submit_ops` ingests
 live writes between anti-entropy rounds, sync sessions piggyback
 pending op batches exactly like fleet snapshots (PR 6), and
 :class:`crdt_tpu.batch.wireloop.PipelinedOpLoop` overlaps frame decode
-with the fold.  PERF.md "Op-based replication" documents the frame
+with the fold.  docs/GUIDE.md "Op-based replication" documents the frame
 format and the ship-ops-vs-ship-deltas tradeoff.
 """
 

@@ -29,8 +29,9 @@ slot counts ≤ 128), and computes the dot algebra only for the selected
 slots; the
 2M-wide merged table of the classic pipeline is never materialized.
 Deferred-bearing batches take the full-width pipeline with dedup + replay.
-See `reports/ORSWOT_PROFILE.md` for the measured effect (5.9× on the
-BASELINE.md config-4 shapes).
+The effect on the BASELINE.md config-4 shapes was measured before PR 1
+on a capture path that no longer exists; it is not measured on the chip
+yet.
 """
 
 from __future__ import annotations
@@ -179,8 +180,7 @@ def _scatterless_default():
     are served by XLA:TPU's generic scatter path, far slower than dense
     reductions at these tiny slot counts.  (The original CPU-prefers-
     scatter finding predated the rank-select rewrite.)
-    ``CRDT_SCATTERLESS=0/1`` forces a path for A/B measurements
-    (`scripts/tpu_experiments.py`)."""
+    ``CRDT_SCATTERLESS=0/1`` forces a path for A/B measurements."""
     import os
 
     force = os.environ.get("CRDT_SCATTERLESS")
@@ -258,30 +258,26 @@ def resolve_merge_impl(impl: str | None = None) -> str:
     bit-equal outside the conservative-overflow objects, see
     ``tests/test_orswot_unrolled.py``), or ``pallas``.
 
-    ``pallas`` — ROUND-5 DECISION (VERDICT r4 item 4): for PAIRWISE
-    merges it is an alias of ``unrolled``.  The fused pairwise kernel
-    (:mod:`crdt_tpu.ops.orswot_pallas`) measured on-chip strictly worse
-    than the jnp path (0.60M vs 3.17M merges/s, 2026-08-01 window —
-    VPU-compute-bound at 8-object tiles), and a fused PAIRWISE merge
-    cannot beat jnp on traffic anyway (both read 2 states and write 1);
-    it stays importable for benches/tests only.  Where ``pallas`` DOES
-    pay is the R-way FOLD — each replica state read once instead of the
-    sequential fold's 3-states-per-merge — which :func:`fold_merge`
-    dispatches to the union-aligned fused kernel
-    (:mod:`crdt_tpu.ops.orswot_fold_aligned`).
+    ``pallas`` — for PAIRWISE merges an alias of ``unrolled``: a fused
+    pairwise kernel cannot beat jnp on traffic (both read 2 states and
+    write 1), and :mod:`crdt_tpu.ops.orswot_pallas` stays importable for
+    benches/tests only.  Where ``pallas`` can pay is the R-way FOLD —
+    each replica state read once instead of the sequential fold's
+    3-states-per-merge — which :func:`fold_merge` dispatches to the
+    union-aligned fused kernel (:mod:`crdt_tpu.ops.orswot_fold_aligned`).
 
     Precedence: an explicit non-``"auto"`` choice (the ``impl=`` argument
     to :func:`merge`, usually fed from ``CrdtConfig.merge_impl``) wins;
     otherwise the ``CRDT_MERGE_IMPL`` env var (a process-level override —
     set it before the first compile; jit caches key on shapes only, so
     flipping it later does not retrace already-compiled shapes); otherwise
-    the backend default from the round-3 on-chip layout A/B
-    (`reports/LAYOUT_AB_TPU.md`): ``unrolled`` on TPU (54.0 ms vs the
-    rank path's 57.7 ms at config-4 shapes), ``rank`` elsewhere (the
+    the backend default: ``unrolled`` on TPU, ``rank`` elsewhere (the
     unrolled tile math trades extra dot-table reads for regularity —
-    measured 17% slower on the memory-bound CPU backend).  A/B harnesses
-    should pass ``impl=`` explicitly — each choice is a distinct Python
-    call graph, so no cache clearing is needed."""
+    measured 17% slower on the memory-bound CPU backend).  The TPU
+    default rests on a layout A/B taken before PR 1 on a capture path
+    that no longer exists; it waits for a chip A/B (ROADMAP D5).  A/B
+    harnesses should pass ``impl=`` explicitly — each choice is a
+    distinct Python call graph, so no cache clearing is needed."""
     import os
 
     import jax
@@ -555,7 +551,7 @@ def fold_merge(
     (`/root/reference/test/orswot.rs:45-62`) — the anti-entropy join.
 
     This is the level where the fused Pallas arm lives (round-5
-    keep-or-kill decision, `PERF.md`): with ``impl="pallas"`` and
+    keep-or-kill decision, `docs/GUIDE.md`): with ``impl="pallas"`` and
     eligible shapes (uint32 counters, ``[R, N, ...]`` rank-3 planes) the
     whole fold runs in one union-aligned kernel
     (:mod:`~crdt_tpu.ops.orswot_fold_aligned`) that reads each replica
@@ -607,19 +603,22 @@ def fold_merge_sequential(
     return acc + (over_acc,)
 
 
-def fold_merge_tree(
-    clock, ids, dots, dids, dclocks, m_cap: int, d_cap: int,
-    plunger: bool = True, impl: str | None = None,
-):
-    """Join ``R`` stacked replica fleets (arrays ``[R, N, ...]``) into one
-    ``[N, ...]`` state by pairwise tree reduction.
+def fold_merge_fleets(fleets, m_cap: int, d_cap: int,
+                      plunger: bool = True, impl: str | None = None):
+    """Join ``R`` replica fleets (a sequence of ``(clock, ids, dots,
+    d_ids, d_clocks)`` tuples over the same ``N`` objects) into one
+    state by pairwise tree reduction.
 
     Same R-1 merges (plus an optional defer-plunger self-merge,
     `/root/reference/test/orswot.rs:61-62`) as the sequential left fold,
-    but tree level ``l`` executes its ``R / 2**l`` pairwise merges as ONE
-    batched :func:`merge` call over a ``[R/2**l, N, ...]`` leading axis —
-    a log-depth dependency chain with maximal batch per launch, which is
-    the shape accelerators want.
+    in a log-depth dependency chain: level ``l`` merges neighbours
+    ``(0,1), (2,3), ...`` of level ``l-1``, an odd fleet out carrying
+    through to the next level.  Each pair is its own merge — the fleets
+    are never stacked — so the working set is the inputs plus ONE
+    pair's merge temporaries.  A batched merge over all pairs of a level
+    would hold every pair's 2M-wide merged table at once, which at the
+    north-star width (A=64, M=16, R=8 × 125k objects) does not fit a
+    16 GB chip.
 
     Equivalence to the left fold: for deferred-free states the merge is
     a pure lattice join (`orswot.rs:89-156`) over a canonical encoding
@@ -638,30 +637,23 @@ def fold_merge_tree(
     Returns ``(clock, ids, dots, d_ids, d_clocks, overflow)`` with
     ``overflow`` OR-reduced over every merge in the tree.
     """
-    state = (clock, ids, dots, dids, dclocks)
-    r = clock.shape[0]
-    over_acc = jnp.zeros(clock.shape[1:-1] + (2,), bool)
-    while r > 1:
-        half = r // 2
-        lhs = tuple(x[0 : 2 * half : 2] for x in state)
-        rhs = tuple(x[1 : 2 * half : 2] for x in state)
-        out = merge(*lhs, *rhs, m_cap, d_cap, impl=impl)
-        merged, over = out[:5], out[5]
-        over_acc = over_acc | jnp.any(over, axis=0)
-        if r % 2:
-            # odd fleet carries through to the next level
-            merged = tuple(
-                jnp.concatenate([m, x[-1:]], axis=0)
-                for m, x in zip(merged, state)
-            )
-        state = merged
-        r = half + r % 2
-    state = tuple(x[0] for x in state)
+    level = [tuple(f) for f in fleets]
+    over_acc = jnp.zeros(level[0][0].shape[:-1] + (2,), bool)
+    while len(level) > 1:
+        nxt = []
+        for i in range(0, len(level) - 1, 2):
+            out = merge(*level[i], *level[i + 1], m_cap, d_cap, impl=impl)
+            nxt.append(out[:5])
+            over_acc = over_acc | out[5]
+        if len(level) % 2:
+            nxt.append(level[-1])
+        level = nxt
+    state = level[0]
     if plunger:
         out = merge(*state, *state, m_cap, d_cap, impl=impl)
         state, over = out[:5], out[5]
         over_acc = over_acc | over
-    return state + (over_acc,)
+    return tuple(state) + (over_acc,)
 
 
 def apply_add(clock, ids, dots, dids, dclocks, actor_idx, counter, member_id):

@@ -6,15 +6,10 @@ runs the same algebra as unrolled one-hot selects and max-reductions over
 the small static slot axes — the style of the Pallas tile math
 (:mod:`crdt_tpu.ops.orswot_pallas`), which XLA fuses into dense
 elementwise passes.  It trades O(M) extra reads of the dot tables for
-regularity: measured 17% slower on the memory-bound CPU backend, but the
-round-3 on-chip layout A/B made it the **TPU default** (54.0 ms vs the
-rank path's 57.7 ms at config-4 shapes — `reports/LAYOUT_AB_TPU.md`).
-
-The lanes-last (object-axis-minor) variant that shared this module lost
-that A/B 2× (120 ms at config-4: the boundary transposes and broadcasted
-[A, N] selects cost more than the lane under-utilization they recover)
-and was deleted per the round-2 verdict's prune directive; see
-`reports/LAYOUT_AB_TPU.md` for the numbers that killed it.
+regularity: measured 17% slower on the memory-bound CPU backend.  It
+is the **TPU default** on the strength of a layout A/B taken before
+PR 1 on a capture path that no longer exists; the per-backend default
+waits for a chip A/B (ROADMAP D5).
 
 Semantics are `/root/reference/src/orswot.rs:89-156` throughout — the
 rule-by-rule citations live in ``orswot_ops``/``orswot_pallas``; this

@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ._compat import shard_map
+from jax import shard_map
 
 from ..error import CapacityOverflowError, raise_for_overflow
 from ..obs.kernels import observed_kernel

@@ -15,7 +15,7 @@ On TPU the two batch failure modes are:
   merge is idempotent and the regrown batch is the same CRDT state, the
   retry is always algebraically safe.
 * **transient device failure** — a dispatch raising ``RuntimeError``
-  (device OOM, a remote-TPU tunnel dropping, preemption).  Recovery is to
+  (a lost device or connection, preemption).  Recovery is to
   requeue the same join up to ``max_retries`` times.
 
 The executor joins a queue of batches into one state — as a left fold
@@ -76,7 +76,8 @@ class JoinError(RuntimeError):
 
 # substrings that mark a RuntimeError as plausibly transient (device-side,
 # worth requeueing); anything else is treated as deterministic and raised
-# without burning the retry budget on backoff sleeps
+# without burning the retry budget on backoff sleeps.  An HBM OOM is not
+# transient: the same join at the same shape fails the same way again.
 _TRANSIENT_MARKERS = (
     "unavailable",
     "deadline",
@@ -85,11 +86,8 @@ _TRANSIENT_MARKERS = (
     "preempt",
     "connection",
     "socket",
-    "tunnel",
     "device gone",
     "device lost",
-    "out of memory",
-    "resource exhausted",
 )
 
 
@@ -127,7 +125,7 @@ class JoinExecutor:
     # (``join_fleet``) — log-depth, each level one batched call, recovery
     # at whole-tree granularity; "auto" = tree on TPU backends (the
     # launch shape accelerators want), sequential elsewhere (measured
-    # faster on a single CPU core — PERF.md)
+    # faster on a single CPU core — docs/GUIDE.md)
     strategy: str = "auto"
 
     def join_all(
@@ -296,7 +294,7 @@ class JoinExecutor:
                     acc = acc.with_capacity(new_m, new_d)
                     nxt = nxt.with_capacity(new_m, new_d)
             except RuntimeError as transient:
-                # XLA surfaces tunnel drops, preemption AND deterministic
+                # XLA surfaces lost devices, preemption AND deterministic
                 # failures (shape/compile errors) as RuntimeError subclasses;
                 # only messages carrying transient markers are requeued —
                 # deterministic failures surface immediately

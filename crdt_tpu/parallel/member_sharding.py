@@ -43,7 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ._compat import shard_map
+from jax import shard_map
 
 from ..ops import orswot_ops
 from ..error import raise_for_overflow

@@ -17,7 +17,7 @@ indexed encoder (``orswot_encode_wire_rows``, ABI v10) when it applies,
 so the fleet planes are never copied just to serialize 1% of them.  The
 apply side parses delta blobs into REUSED staging planes
 (``engine.orswot_ingest_wire(..., out=)`` — the same warm-buffer path
-that fixed the e2e ingest collapse, PERF.md) and scatter-merges the
+that fixed the e2e ingest collapse, docs/GUIDE.md) and scatter-merges the
 rows into the local fleet.
 """
 

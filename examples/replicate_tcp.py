@@ -52,7 +52,7 @@ replicas (in-process nodes over real loopback TCP sockets), each with a
 listener, a peer roster (``crdt_tpu.cluster.Membership``) and a
 staleness-driven ``GossipScheduler``, reconcile through hardened
 ``ResilientTransport`` links until every node's digest vector is
-byte-identical (PERF.md "Cluster runtime").
+byte-identical (docs/GUIDE.md "Cluster runtime").
 
 ``--metrics-port N`` starts the live observability exporter
 (:mod:`crdt_tpu.obs`) in the peer process: ``GET /metrics`` is the
@@ -63,7 +63,7 @@ session with ``?session=<id>`` — the peer prints its session ID), and
 exporter up for up to S seconds after the sync finishes (returning as
 soon as both ``/metrics`` and ``/events`` have been scraped after the
 sync finished — scrapes that raced the sync don't count), so a
-scraper — PERF.md's ``curl`` walkthrough, or the automated test — can
+scraper — docs/GUIDE.md's ``curl`` walkthrough, or the automated test — can
 read the final state before the process exits.
 
 (`--platform cpu` forces the CPU backend, e.g. when no TPU is
@@ -388,7 +388,7 @@ def gossip_demo(n_peers: int, n_objects: int, platform: str | None,
     piggyback on the gossip sessions; at convergence the demo prints
     ONE merged fleet snapshot (fleet counters = per-node sums) instead
     of N disjoint per-node ``/metrics`` views, plus the shared trace ID
-    of the final session (both halves carry it — PERF.md "Fleet
+    of the final session (both halves carry it — docs/GUIDE.md "Fleet
     observability" walks the curl side).  ``--fleet-port`` additionally
     serves the live merged view on ``GET /fleet``.
 
@@ -398,7 +398,7 @@ def gossip_demo(n_peers: int, n_objects: int, platform: str | None,
     ``derive_add_ctx`` dots, :mod:`crdt_tpu.oplog`) WHILE gossip is
     reconciling, so anti-entropy and ingest genuinely overlap; once the
     writes stop, the fleet must still converge to byte-identical digest
-    vectors — the mixed op+state acceptance shape (PERF.md "Op-based
+    vectors — the mixed op+state acceptance shape (docs/GUIDE.md "Op-based
     replication").
 
     ``--reads R`` adds the READ half of the client protocol
@@ -410,7 +410,7 @@ def gossip_demo(n_peers: int, n_objects: int, platform: str | None,
     reads whose returned tokens must never regress per node and
     frontier-stable reads tallying per-row stability against the PR 15
     frontier.  At quiescence a final frontier-mode read on every node
-    must come back all-rows-stable (PERF.md "Read front-end").
+    must come back all-rows-stable (docs/GUIDE.md "Read front-end").
 
     ``--durable DIR`` arms every node with a :class:`crdt_tpu.durable.
     Durability` manager (WAL-ahead ingest + a checkpoint at every
@@ -421,7 +421,7 @@ def gossip_demo(n_peers: int, n_objects: int, platform: str | None,
     (:func:`crdt_tpu.durable.recover`), rejoins through NORMAL delta
     sync, and the demo prints the recovery wall, bytes replayed from
     the WAL vs bytes delta-synced during the rejoin, and asserts the
-    rejoin shipped zero full-state frames (PERF.md "Durability")."""
+    rejoin shipped zero full-state frames (docs/GUIDE.md "Durability")."""
     import jax
 
     if platform:
@@ -1110,7 +1110,7 @@ def gossip_demo(n_peers: int, n_objects: int, platform: str | None,
 
     # the windowed-ARQ story of the run: fleet-wide recovery tallies,
     # the deepest any link pipelined, and the last session's descent
-    # round-trip count — the numbers PERF.md "Windowed transport" tracks
+    # round-trip count — the numbers docs/GUIDE.md "Windowed transport" tracks
     from crdt_tpu.utils import tracing as _tracing
 
     c = _tracing.counters()
@@ -1143,6 +1143,25 @@ def gossip_demo(n_peers: int, n_objects: int, platform: str | None,
     print(f"gossip: {n_peers} peers x {n_objects} objects  "
           f"sweeps={sweeps}  {verdict}", flush=True)
     return 0 if converged else 1
+
+
+def _peer_backend(platform: str | None) -> str:
+    """The JAX backend a peer process would get.  Asked of a child that
+    exits before the peers start: the parent stays off JAX, since a
+    process that touched it would hold the chip."""
+    import subprocess
+
+    platform = platform or os.environ.get("JAX_PLATFORMS", "")
+    if platform.split(",")[0] == "cpu":
+        return "cpu"
+    env = dict(os.environ)
+    if platform:
+        env["JAX_PLATFORMS"] = platform
+    proc = subprocess.run(
+        [sys.executable, "-c",
+         "import jax; print(jax.default_backend())"],
+        capture_output=True, text=True, env=env, timeout=300, check=True)
+    return proc.stdout.strip().splitlines()[-1]
 
 
 def main() -> int:
@@ -1298,6 +1317,15 @@ def main() -> int:
 
     # demo: spawn both peers as real OS processes
     import subprocess
+
+    if _peer_backend(args.platform) == "tpu":
+        print("replicate_tcp: the two-process demo needs two processes on "
+              "one backend, and a TPU chip belongs to one process at a "
+              "time.  On a TPU host run the in-process form instead — "
+              "`--gossip N` (peers as threads of one process), or the sync "
+              "phase of chip_smoke.py (two SyncSessions over a "
+              "socketpair) — or pass --platform cpu.", file=sys.stderr)
+        return 2
 
     with socket.socket() as probe:
         probe.bind(("127.0.0.1", 0))

@@ -3,7 +3,7 @@
 
 Both tiers: the default stdlib-only AST lint, and ``--kernels`` for the
 jaxpr tier (kernelcheck, KC01-KC05 — imports jax under
-``JAX_PLATFORMS=cpu``; see PERF.md "Kernel contracts").
+``JAX_PLATFORMS=cpu``; see docs/GUIDE.md "Kernel contracts").
 
 Kept as a script so CI configs and editors can point at a file; all
 logic lives in :mod:`crdt_tpu.analysis.__main__`.  Works from any CWD:

@@ -1,11 +1,10 @@
 """The shared chained timer (crdt_tpu.utils.benchtime).
 
-Every capture path (bench.py, profile_stages, tpu_experiments,
-tpu_validate) times through this helper; what matters for correctness is
-that the chain really executes its iterations data-dependently and that
-consts arrive as jit parameters (the closure-inlining failure mode is a
-remote-compile rejection — reports/TPU_LATENCY.md item 4 — which cannot
-be reproduced on CPU, so here we pin the calling convention instead).
+Every capture path (bench.py, profile_stages) times through this
+helper; what matters for correctness is that the chain really executes
+its iterations data-dependently and that consts arrive as jit
+parameters (a closure would inline the arrays into the lowered module
+as constants, so here we pin the calling convention).
 """
 import jax.numpy as jnp
 import numpy as np

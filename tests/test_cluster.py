@@ -105,6 +105,61 @@ def test_queue_pair_roundtrip_and_close():
         b.send(b"after close")
 
 
+def test_tcp_transport_symmetric_large_frames_do_not_deadlock():
+    """Both peers send a frame far larger than the socket buffers
+    before either reads — the session's hello+digest opening at fleet
+    scale — and both frames arrive whole."""
+    import socket
+
+    sa, sb = socket.socketpair()
+    ends = {"a": transport_mod.TcpTransport(sa, default_timeout=10.0),
+            "b": transport_mod.TcpTransport(sb, default_timeout=10.0)}
+    frames = {k: bytes([i]) * (8 << 20) for i, k in enumerate(ends)}
+    got = {}
+
+    def run(me, peer):
+        ends[me].send(frames[me])
+        ends[me].send(b"tail-" + me.encode())
+        got[me] = (ends[me].recv(), ends[me].recv())
+
+    threads = [threading.Thread(target=run, args=p, daemon=True)
+               for p in (("a", "b"), ("b", "a"))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=30.0)
+    for tr in ends.values():
+        tr.close()
+    assert not any(t.is_alive() for t in threads), "send deadlocked"
+    assert got["a"] == (frames["b"], b"tail-b")
+    assert got["b"] == (frames["a"], b"tail-a")
+
+
+def test_tcp_transport_close_wakes_a_blocked_recv():
+    """Closing an end wakes the thread blocked reading it, so a failed
+    session's threads end instead of sleeping out their timeout."""
+    import socket
+
+    sa, sb = socket.socketpair()
+    end = transport_mod.TcpTransport(sa, default_timeout=120.0)
+    errors = []
+
+    def run():
+        try:
+            end.recv()
+        except TransportClosedError as e:
+            errors.append(e)
+
+    t = threading.Thread(target=run, daemon=True)
+    t.start()
+    time.sleep(0.2)
+    end.close()
+    t.join(timeout=10.0)
+    sb.close()
+    assert not t.is_alive(), "recv still blocked after close"
+    assert len(errors) == 1
+
+
 def test_decode_envelope_rejects_malformed():
     env = transport_mod.encode_envelope(transport_mod._DATA, 7, b"payload")
     kind, seq, payload = transport_mod.decode_envelope(env)

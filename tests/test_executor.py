@@ -184,6 +184,35 @@ def test_transient_failures_exhaust_retries():
         JoinExecutor(max_retries=1, retry_backoff_s=0).join_all([AlwaysDown(), b])
 
 
+@pytest.mark.parametrize("msg", [
+    "RESOURCE_EXHAUSTED: Out of memory while trying to allocate 4.1G",
+    "Resource exhausted: HBM",
+])
+def test_device_oom_is_not_retried(msg):
+    """An HBM OOM is deterministic at a given shape: it surfaces as
+    itself at once, never requeued or hidden behind JoinError."""
+    uni = _universe(m=8)
+    b = OrswotBatch.from_scalar(_fleet(uni, [[("a", 0)]]), uni)
+    calls = []
+
+    class OutOfMemory:
+        member_capacity = 8
+        deferred_capacity = 2
+
+        def with_capacity(self, m, d):
+            return self
+
+        def merge(self, other, check=True):
+            calls.append(1)
+            raise RuntimeError(msg)
+
+    with pytest.raises(RuntimeError, match="(?i)exhausted") as exc:
+        JoinExecutor(max_retries=3, retry_backoff_s=0).join_all(
+            [OutOfMemory(), b])
+    assert not isinstance(exc.value, JoinError)
+    assert len(calls) == 1
+
+
 def test_mismatched_capacities_equalized():
     uni = _universe(m=4)
     b_small = OrswotBatch.from_scalar(_fleet(uni, [[("a", 0)]]), uni)

@@ -1,6 +1,6 @@
 """kernelcheck self-tests: the jaxpr tier's repo gate, the fixture
-regression matrix, manifest coverage, and the KC01/conftest skew
-cross-check.
+regression matrix, manifest coverage, and the KC01 stale-sanction
+rule.
 
 The AST tier's tests (tests/test_analysis.py) stay jax-free; this
 module deliberately is NOT — tracing kernels is the whole point — and
@@ -63,12 +63,11 @@ def test_repo_gate_exits_zero_with_empty_baseline(repo_gate):
     assert [e for e in entries if e["rule"].startswith("KC")] == []
 
 
-def test_repo_gate_is_fast_and_covers_the_manifest(repo_gate):
-    """<60 s on CPU, every buildable spec traced, every jit site under
+def test_repo_gate_covers_the_manifest(repo_gate):
+    """Every buildable spec traced, every jit site under
     crdt_tpu/ accounted for."""
     out = json.loads(repo_gate.stdout)
     kc = out["kernelcheck"]
-    assert kc["elapsed_s"] < 60.0, f"kernelcheck took {kc['elapsed_s']}s"
     n_build = sum(1 for s in MANIFEST if s.build is not None)
     assert kc["traced"] == n_build
     assert kc["cases"] >= 2 * kc["traced"]  # ladders, not single traces
@@ -83,29 +82,13 @@ def test_repo_gate_is_fast_and_covers_the_manifest(repo_gate):
 
 def test_mosaic_specs_traced_real_pallas_regions(repo_gate):
     """Each mosaic spec traced >=1 pallas_call and is 64-bit-clean —
-    the static KC01 pin on the Pallas-skew class."""
+    the static KC01 pin on Mosaic's 32-bit limit."""
     mosaic = json.loads(repo_gate.stdout)["kernelcheck"]["mosaic"]
     assert set(mosaic) == {s.name for s in MANIFEST if s.mosaic}
     for name, stats in mosaic.items():
         assert stats["pallas_calls"] > 0, f"{name} traced no pallas_call"
         assert stats["wide_ops"] == 0, (
             f"{name} leaked {stats['wide_ops']} 64-bit ops into Mosaic")
-
-
-def test_kc01_agrees_with_conftest_skew_gate(repo_gate):
-    """The static gate and the runtime xfail gate can never disagree
-    silently: the Mosaic kernels are 64-bit-clean at the jaxpr level
-    (previous test), so any runtime xfail of the Pallas suites must be
-    purely version-gated — i.e. conftest's predicate and kernelcheck's
-    recorded skew reason are the SAME `config.pallas_mosaic_skew()`
-    value.  If KC01 ever finds real 64-bit content, the gate exits 1
-    regardless of the jax version, and a pragma sanctioning it is
-    re-flagged as stale the moment the skew lifts (pinned below in
-    test_stale_kc01_sanction_reflagged_when_skew_lifts)."""
-    from crdt_tpu.config import pallas_mosaic_skew
-
-    kc = json.loads(repo_gate.stdout)["kernelcheck"]
-    assert kc["skew_reason"] == pallas_mosaic_skew()
 
 
 # ---- fixture matrix: every rule fires with the right id + kernel name ------
@@ -169,13 +152,11 @@ def test_ok_twins_suppressed_or_clean():
     assert result.stale_baseline == []
 
 
-def test_stale_kc01_sanction_reflagged_when_skew_lifts(monkeypatch):
-    """A pragma sanctioning KC01 is only valid while the runtime skew
-    gate reports a skew: on a fixed jax the suppression re-arms as a
-    live 'stale sanction' finding (the cross-check screw)."""
+def test_kc01_sanction_reflagged_as_stale(monkeypatch):
+    """A pragma sanctioning KC01 never silences it: Mosaic has no
+    64-bit support on any supported jax, so the suppression re-arms as
+    a live 'stale sanction' finding."""
     import kernels_bad
-
-    import crdt_tpu.config as config
 
     spec = [s for s in kernels_bad.SPECS
             if s.name == "fixture.i64_lowering"]
@@ -194,16 +175,10 @@ def test_stale_kc01_sanction_reflagged_when_skew_lifts(monkeypatch):
 
     monkeypatch.setattr(ParsedFile, "suppressed", fake_suppressed)
     result2, _ = _run_specs(spec)
-    assert all(f.rule != "KC01" or "stale" in f.message
-               for f in result2.findings)
     assert any(f.rule == "KC01" for f in result2.suppressed)
-
-    # now lift the skew: the sanction must re-flag as live
-    monkeypatch.setattr(config, "pallas_mosaic_skew", lambda: None)
-    result3, _ = _run_specs(spec)
-    stale = [f for f in result3.findings
+    stale = [f for f in result2.findings
              if f.rule == "KC01" and "stale KC01 sanction" in f.message]
-    assert stale, [f.render() for f in result3.findings]
+    assert stale, [f.render() for f in result2.findings]
 
 
 # ---- the tier-1 AST rule: kernel-manifest ----------------------------------

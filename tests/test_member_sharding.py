@@ -245,7 +245,7 @@ def test_member_sharded_merge_emits_no_collectives():
 
     import functools
 
-    from crdt_tpu.parallel._compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from crdt_tpu.ops import orswot_ops

@@ -280,7 +280,7 @@ def test_present_but_removed():
 
 
 class TestFoldMergeTree:
-    """fold_merge_tree vs the sequential left fold.
+    """fold_merge_fleets vs the sequential left fold.
 
     The ORSWOT join is associative in its *observable* state — value(),
     set clock, member table — which is the CRDT convergence guarantee.
@@ -328,7 +328,8 @@ class TestFoldMergeTree:
         n, a, m, d = 17, 8, 5 + r, 3
         stacked = self._fleets(rng, n, a, m, d, r, deferred_frac)
         acc = self._seq_fold(stacked, r, m, d)
-        got = orswot_ops.fold_merge_tree(*stacked, m, d)[:5]
+        got = orswot_ops.fold_merge_fleets(
+            [tuple(x[i] for x in stacked) for i in range(r)], m, d)[:5]
 
         # order-independent pieces: set clock and canonical member table
         assert np.array_equal(np.asarray(got[0]), np.asarray(acc[0]))
@@ -381,8 +382,7 @@ class TestFoldMergeTree:
             ids = np.where(ids != -1, ids + 100 * i, ids)
             arrs[1] = ids
             reps.append(tuple(jnp.asarray(x) for x in arrs))
-        stacked = tuple(jnp.stack([rep[k] for rep in reps]) for k in range(5))
-        out = orswot_ops.fold_merge_tree(*stacked, 2, 2)
+        out = orswot_ops.fold_merge_fleets(reps, 2, 2)
         assert bool(np.asarray(out[5]).any()), "tree fold must surface overflow"
 
 

@@ -1,7 +1,6 @@
 """Parity: the unrolled ORSWOT merge vs the production rank path.
 
-``crdt_tpu.ops.orswot_unrolled.merge_unrolled`` (the TPU default since
-the round-3 on-chip layout A/B — `reports/LAYOUT_AB_TPU.md`) must be
+``crdt_tpu.ops.orswot_unrolled.merge_unrolled`` (the TPU default) must be
 bit-identical to ``orswot_ops.merge``'s rank pipeline, which is itself
 bit-exact against the scalar engine (``tests/test_parity.py``) and
 thereby the reference (`/root/reference/src/orswot.rs:89-156`).

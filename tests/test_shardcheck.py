@@ -61,14 +61,13 @@ def test_repo_gate_exits_zero_with_empty_baseline(repo_gate):
     assert [e for e in entries if e["rule"].startswith("SC")] == []
 
 
-def test_repo_gate_is_fast_and_covers_every_contract(repo_gate):
-    """<60 s on CPU; every manifest row carries a contract; every
+def test_repo_gate_covers_every_contract(repo_gate):
+    """Every manifest row carries a contract; every
     buildable non-host_only row traced, with mesh-shaped cases; the
     provenance walker saw no unknown primitives (an unknown prim is a
     silently-unanalyzed data path)."""
     out = json.loads(repo_gate.stdout)
     sc = out["shardcheck"]
-    assert sc["elapsed_s"] < 60.0, f"shardcheck took {sc['elapsed_s']}s"
     assert sc["kernels"] == len(MANIFEST)
     assert sum(sc["contracts"].values()) == len(MANIFEST)
     assert set(sc["contracts"]) <= set(SHARD_CLASSES)

@@ -549,7 +549,7 @@ def test_sharded_truncate_matches_unsharded():
     from functools import partial
 
     from jax.sharding import PartitionSpec as P
-    from crdt_tpu.parallel._compat import shard_map
+    from jax import shard_map
 
     from crdt_tpu.batch.orswot_batch import _truncate
 

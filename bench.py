@@ -2198,8 +2198,8 @@ def bench_kernel_obs():
     window).  (3) Coverage tail: per-kernel compile counts and p50
     wall for every kernel the bench run exercised, so a kernel family
     going dark diffs round over round (``kernel`` family collapse in
-    benchkit/artifacts.py), plus one blocking-mode GB/s + XLA
-    cost-analysis capture for the fold kernel as the roofline anchor."""
+    benchkit/artifacts.py), plus one XLA cost-analysis capture for the
+    fold kernel as the roofline anchor."""
     import jax.numpy as jnp
 
     from crdt_tpu.batch import vclock_batch
@@ -2240,14 +2240,7 @@ def bench_kernel_obs():
         "a wrapper or cache-key regression is churning the jit cache"
     )
 
-    # one blocking-mode pass so the fold kernel owns a GB/s roofline
-    # coordinate + its XLA cost analysis in the artifact
-    obs_kernels.set_blocking(True)
-    try:
-        for _ in range(10):
-            wrapped(plane, plane)
-    finally:
-        obs_kernels.set_blocking(False)
+    # the fold kernel's XLA cost analysis in the artifact
     prof = obs.profile("batch.vclock.merge")
     cost = prof.capture_cost()
     if cost is not None:

@@ -99,29 +99,13 @@ def _expand_record_sync(call: ast.Call) -> List[tuple[str, str]]:
     ]
 
 
-def _expand_timed_kernel(call: ast.Call) -> List[tuple[str, str]]:
-    """``timed_kernel("label")`` → the label's span histogram and its
-    ``kernel.<label>.errors`` counter."""
-    if not call.args:
-        return []
-    label = literal_str(call.args[0])
-    if label is None or "." in label:
-        return []
-    return [
-        (label, "histogram"),
-        (f"kernel.{label}.errors", "counter"),
-    ]
-
-
 def extract_decls(files: List[ParsedFile]) -> List[MetricDecl]:
     """Every statically-nameable metric declaration across ``files``."""
     decls: List[MetricDecl] = []
 
     def add(pattern: Optional[str], kind: str, pf: ParsedFile,
-            call: ast.Call, via: str, dotted_only: bool = True) -> None:
-        if pattern is None:
-            return
-        if dotted_only and not _NAME_RE.match(pattern):
+            call: ast.Call, via: str) -> None:
+        if pattern is None or not _NAME_RE.match(pattern):
             return
         decls.append(MetricDecl(pattern, kind, pf.rel, call.lineno,
                                 call.col_offset, via))
@@ -139,9 +123,6 @@ def extract_decls(files: List[ParsedFile]) -> List[MetricDecl]:
             elif head == "record_sync":
                 for pat, kind in _expand_record_sync(node):
                     add(pat, kind, pf, node, head)
-            elif head == "timed_kernel":
-                for pat, kind in _expand_timed_kernel(node):
-                    add(pat, kind, pf, node, head, dotted_only=False)
             elif head in _DIRECT_HEADS:
                 if head == "observe" and len(node.args) < 2:
                     continue  # Histogram.observe(v) — a value, not a name

@@ -41,7 +41,9 @@ pipeline").
   ``stall_threshold_s`` leaves a ``wireloop.stall`` flight-recorder
   event: an operator watching ``/metrics`` sees a parse-bound loop as
   ``staging_free == 0`` plus a stall count, without attaching a
-  profiler.
+  profiler.  With tracing on, each host leg is a ``wireloop.*`` span
+  (parse, wait_parsed, put, dispatch, wait, fetch, encode), on the
+  profiler's clock when a trace is captured.
 
 ``bench_e2e_wire`` (bench.py) and ``examples/anti_entropy.py`` drive
 this one implementation.
@@ -129,11 +131,11 @@ class PipelinedWireLoop:
         self._staging: list[tuple] = []
         self._pingpong: list[tuple] = []
         self._n: Optional[int] = None
-        if fold_path is None:
-            import jax
+        import jax
 
-            engine = _native_fold_engine() if jax.default_backend() == "cpu" \
-                else None
+        on_cpu = jax.default_backend() == "cpu"
+        if fold_path is None:
+            engine = _native_fold_engine() if on_cpu else None
             fold_path = "native" if engine is not None else "jnp"
         if fold_path not in ("native", "jnp"):
             raise ValueError(f"fold_path {fold_path!r} is not native/jnp")
@@ -144,6 +146,9 @@ class PipelinedWireLoop:
                                "is unavailable")
         self._jit_merge = None
         self._overflow = None  # jnp path: lazily ORed bool[2] flags
+        # the CPU backend may alias an aligned host buffer instead of
+        # copying it, and a put staging set goes back to the parser
+        self._copy_before_put = on_cpu
 
     # -- buffers -------------------------------------------------------------
 
@@ -207,26 +212,29 @@ class PipelinedWireLoop:
             cfg = self.cfg
             self._jit_merge = _fold_merge_kernel(
                 cfg.member_capacity, cfg.deferred_capacity)
-        out = self._jit_merge(*acc, *rhs)
-        ov = out[5].reshape(-1, 2).any(axis=0)
-        self._overflow = ov if self._overflow is None else \
-            (self._overflow | ov)
+        with tracing.span("wireloop.dispatch"):
+            out = self._jit_merge(*acc, *rhs)
+            ov = out[5].reshape(-1, 2).any(axis=0)
+            self._overflow = ov if self._overflow is None else \
+                (self._overflow | ov)
         return out[:5]
 
     def _egress(self, acc: tuple) -> list[bytes]:
         from .wirebulk import orswot_planes_to_wire
 
-        planes = tuple(np.asarray(x) for x in acc)
-        blobs = orswot_planes_to_wire(*planes, self.universe)
-        if blobs is not None:
-            return blobs
-        # Python route (non-identity universe / u64 zigzag overflow) —
-        # already counted by orswot_planes_to_wire
-        from ..utils.serde import to_binary
-        from .orswot_batch import OrswotBatch
+        with tracing.span("wireloop.fetch"):
+            planes = tuple(np.asarray(x) for x in acc)
+        with tracing.span("wireloop.encode"):
+            blobs = orswot_planes_to_wire(*planes, self.universe)
+            if blobs is not None:
+                return blobs
+            # Python route (non-identity universe / u64 zigzag overflow)
+            # — already counted by orswot_planes_to_wire
+            from ..utils.serde import to_binary
+            from .orswot_batch import OrswotBatch
 
-        batch = OrswotBatch(*(np.ascontiguousarray(p) for p in planes))
-        return [to_binary(s) for s in batch.to_scalar(self.universe)]
+            batch = OrswotBatch(*(np.ascontiguousarray(p) for p in planes))
+            return [to_binary(s) for s in batch.to_scalar(self.universe)]
 
     # -- the loop ------------------------------------------------------------
 
@@ -268,7 +276,8 @@ class PipelinedWireLoop:
 
         def parse_one(blobs, staging):
             t0 = time.perf_counter()
-            self._parse_into(blobs, staging)
+            with tracing.span("wireloop.parse"):
+                self._parse_into(blobs, staging)
             stage_s["parse"] += time.perf_counter() - t0
 
         def worker():
@@ -324,7 +333,8 @@ class PipelinedWireLoop:
         def next_staged():
             if overlap:
                 t_wait0 = time.perf_counter()
-                item = parsed_q.get()
+                with tracing.span("wireloop.wait_parsed"):
+                    item = parsed_q.get()
                 waited = time.perf_counter() - t_wait0
                 if self.stall_threshold_s and waited > self.stall_threshold_s:
                     # the fold outran the parser: record the stall so a
@@ -392,12 +402,17 @@ class PipelinedWireLoop:
 
                 t0 = time.perf_counter()
                 if self._overflow is not None:
-                    # jnp path: one deferred overflow check per round —
-                    # the egress fetch syncs the device anyway
+                    # jnp path: the round's fold finishes here, so the
+                    # fetch that follows times the copy alone; then one
+                    # deferred overflow check per round
+                    import jax
+
                     from ..error import raise_for_overflow
 
-                    ov, self._overflow = self._overflow, None
-                    raise_for_overflow(ov, "wire-loop fold")
+                    with tracing.span("wireloop.wait"):
+                        jax.block_until_ready(acc)
+                        ov, self._overflow = self._overflow, None
+                        raise_for_overflow(ov, "wire-loop fold")
                 blobs_out = self._egress(acc)
                 stage_s["egress"] += time.perf_counter() - t0
                 if on_round is not None:
@@ -444,8 +459,10 @@ class PipelinedWireLoop:
         """Host staging planes → device arrays for the jnp fold.
 
         ``device_put`` copies host numpy buffers into the backend's own
-        (aligned) allocations, so once the transfer completes the
-        staging set is safe to hand back to the parser; blocking here
+        allocations (on the CPU backend, which may alias an aligned host
+        buffer instead, the planes are copied on the host first), so
+        once the transfer completes the staging set is safe to hand
+        back to the parser; blocking here
         costs only the H2D — the merges themselves still chain
         asynchronously.  Device-resident accumulators pass through
         untouched."""
@@ -453,8 +470,11 @@ class PipelinedWireLoop:
 
         if not isinstance(planes[0], np.ndarray):
             return planes
-        moved = jax.device_put(planes)
-        jax.block_until_ready(moved)
+        with tracing.span("wireloop.put"):
+            if self._copy_before_put:
+                planes = tuple(np.array(p) for p in planes)
+            moved = jax.device_put(planes)
+            jax.block_until_ready(moved)
         return moved
 
 

@@ -19,6 +19,12 @@ program:
   contracts declare: a ``pmax`` clock join for the fleet version
   vector, a ``psum`` member fold for the live-member count.
 
+With tracing on, the host legs of a step are spans:
+``mesh.step.dispatch`` (salts and the program call), ``mesh.step.wait``
+(the program finishing, then the overflow check) and
+``mesh.step.fetch`` (digests, version vector and member count to the
+host).
+
 Dispatch consults the runtime contract gate
 (:mod:`crdt_tpu.mesh.contracts`) for every composed kernel, so a
 host_only/replicated row can never be placed on the mesh.
@@ -107,6 +113,8 @@ def anti_entropy_step(a: ShardedBatch, b: ShardedBatch, *,
     fleet, two replicas' states).  Raises
     :class:`~crdt_tpu.error.CapacityOverflowError` on slot overflow
     when ``check`` (shard-locally reduced, like every merge path)."""
+    import jax
+
     from ..error import raise_for_overflow
     from ..sync.digest import (_salts_device, actor_salt_table,
                                member_salt_table)
@@ -126,21 +134,28 @@ def anti_entropy_step(a: ShardedBatch, b: ShardedBatch, *,
         contracts.require_shardable(name, 1)
 
     da, db = a.device, b.device
-    m_cap, d_cap = int(da.ids.shape[-1]), int(da.d_ids.shape[-1])
-    asalts = _salts_device(actor_salt_table(
-        a.universe, num_actors=int(da.clock.shape[-1])))
-    mtable = member_salt_table(a.universe)
-    state_a = (da.clock, da.ids, da.dots, da.d_ids, da.d_clocks)
-    state_b = (db.clock, db.ids, db.dots, db.d_ids, db.d_clocks)
-    fn = _step_fn(a.mesh, MESH_AXIS, m_cap, d_cap, mtable is not None,
-                  impl)
-    args = (state_a, state_b, asalts) + (
-        (_salts_device(mtable),) if mtable is not None else ())
-    merged, overflow, digests, vv, members = fn(*args)
+    with tracing.span("mesh.step.dispatch"):
+        m_cap, d_cap = int(da.ids.shape[-1]), int(da.d_ids.shape[-1])
+        asalts = _salts_device(actor_salt_table(
+            a.universe, num_actors=int(da.clock.shape[-1])))
+        mtable = member_salt_table(a.universe)
+        state_a = (da.clock, da.ids, da.dots, da.d_ids, da.d_clocks)
+        state_b = (db.clock, db.ids, db.dots, db.d_ids, db.d_clocks)
+        fn = _step_fn(a.mesh, MESH_AXIS, m_cap, d_cap, mtable is not None,
+                      impl)
+        args = (state_a, state_b, asalts) + (
+            (_salts_device(mtable),) if mtable is not None else ())
+        outputs = fn(*args)
+    merged, overflow, digests, vv, members = outputs
 
-    if check:
-        raise_for_overflow(overflow, "mesh anti_entropy_step")
-    digests = np.asarray(digests).astype(np.uint64)[:lay.n]
+    with tracing.span("mesh.step.wait"):
+        jax.block_until_ready(outputs)
+        if check:
+            raise_for_overflow(overflow, "mesh anti_entropy_step")
+    with tracing.span("mesh.step.fetch"):
+        digests = np.asarray(digests).astype(np.uint64)[:lay.n]
+        version_vector = np.asarray(vv).astype(np.uint64)
+        live_members = int(np.asarray(members))
     tracing.count("mesh.step.rounds")
     tracing.count("mesh.step.digest_bytes", int(digests.nbytes))
     out = type(da)(clock=merged[0], ids=merged[1], dots=merged[2],
@@ -148,6 +163,6 @@ def anti_entropy_step(a: ShardedBatch, b: ShardedBatch, *,
     return MeshStepResult(
         batch=a.replace(out),
         digests=digests,
-        version_vector=np.asarray(vv).astype(np.uint64),
-        live_members=int(np.asarray(members)),
+        version_vector=version_vector,
+        live_members=live_members,
     )

@@ -243,7 +243,7 @@ class MetricsServer:
             # the runtime kernel observatory (crdt_tpu/obs/kernels.py):
             # prom text of the kernel./devicemem. plane by default,
             # ?format=json for the per-kernel table (compiles, budget
-            # frac, wall quantiles, GB/s, cost analysis) + the
+            # frac, wall quantiles, cost analysis) + the
             # recompile-storm classification.  ?cost=1 triggers the
             # lazy XLA cost_analysis capture first (one extra
             # lower+compile per kernel signature — deliberate, so the

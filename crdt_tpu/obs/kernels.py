@@ -28,10 +28,10 @@ on the SAME single source of kernel identity — the
   (:func:`storm_report`).  KC04's static budget becomes a runtime
   gauge: ``kernel.<label>.compile_budget_frac`` with an ok/warn/
   critical watermark like the PR 9 capacity gauges.
-* **Device accounting** — per-kernel log2 wall histograms
+* **Dispatch accounting** — per-kernel log2 wall histograms
   (``kernel.<label>.wall``; compile calls are recorded on the compile
   event instead, so the histogram stays steady-state), bytes-moved
-  counters and a GB/s gauge, plus one-time-per-compilation XLA
+  counters, plus one-time-per-compilation XLA
   ``cost_analysis()`` capture (:meth:`KernelProfile.capture_cost`,
   lazy — triggered by ``/kernels?cost=1`` or the bench, never on the
   hot path) giving every kernel a roofline position.
@@ -43,11 +43,10 @@ on the SAME single source of kernel identity — the
   construction" and what the device actually holds.  Sampled on the
   PR 9 capacity cadence (``CapacityTracker.sample_device_memory``).
 
-Timing semantics: by default a call's wall is the DISPATCH wall (jax
-dispatch is async; blocking every call would not be "always cheap").
-With ``CRDT_TRACE=1`` or :func:`set_blocking` the wrapper blocks on the
-outputs — true device time — which is how ``bench_kernel_obs`` fills
-the GB/s gauges.  The per-call fast path touches ONLY the
+Timing semantics: a call's wall is the DISPATCH wall (jax dispatch is
+async, and the wrapper never blocks on the outputs, so an observed
+kernel overlaps exactly as a bare one does).  Device time per kernel
+comes from a profiler trace.  The per-call fast path touches ONLY the
 profile's own lock (dict increments); pending aggregates drain into
 the registry at every read boundary (``/kernels``, ``/metrics``,
 ``json_snapshot``, fleet slice capture) via :func:`publish`, so
@@ -66,7 +65,6 @@ a kernel never pays for either.
 from __future__ import annotations
 
 import math
-import os
 import sys
 import threading
 import time
@@ -191,19 +189,6 @@ def _ladder_epoch() -> int:
         return _LADDER_EPOCH
 
 
-# -- blocking switch ---------------------------------------------------------
-
-_BLOCKING = os.environ.get("CRDT_TRACE") == "1"
-
-
-def set_blocking(on: bool = True) -> None:
-    """Block on kernel outputs so recorded walls are device time (what
-    ``bench_kernel_obs`` does for the GB/s roofline).  Off by default:
-    the always-on path records dispatch wall only."""
-    global _BLOCKING
-    _BLOCKING = on
-
-
 class KernelProfile:
     """One manifested kernel's runtime record.
 
@@ -226,9 +211,6 @@ class KernelProfile:
         self.errors = 0
         self.bytes_total = 0
         self.wall_total_s = 0.0
-        # device-true (blocking-mode) accumulation behind the GB/s gauge
-        self.blocking_bytes = 0
-        self.blocking_wall_s = 0.0
         self.last_signature: Optional[str] = None
         self.cost: Optional[dict] = None
         self._cost_at_compiles = -1
@@ -261,7 +243,6 @@ class KernelProfile:
                 reg.counter(f"kernel.{label}.compiles"),
                 reg.counter(f"kernel.{label}.bytes"),
                 reg.counter(f"kernel.{label}.errors"),
-                reg.gauge(f"kernel.{label}.gbps"),
                 reg.gauge(f"kernel.{label}.compile_budget_frac"),
             )
             reg.histogram(f"kernel.{label}.wall")
@@ -283,7 +264,7 @@ class KernelProfile:
 
     # -- per-call recording (wrapper-driven) ---------------------------------
 
-    def record_call(self, dt: float, nbytes: int, blocking: bool) -> None:
+    def record_call(self, dt: float, nbytes: int) -> None:
         """The always-on per-call path: ONE profile-lock acquisition,
         dict increments only — no registry traffic.  publish() drains
         the pending aggregates at scrape/snapshot boundaries."""
@@ -301,9 +282,6 @@ class KernelProfile:
                 self._pend_min = dt
             if dt > self._pend_max:
                 self._pend_max = dt
-            if blocking:
-                self.blocking_bytes += nbytes
-                self.blocking_wall_s += dt
 
     def publish(self) -> None:
         """Drain the pending per-call aggregates into the registry.
@@ -318,8 +296,6 @@ class KernelProfile:
             buckets = self._pend_buckets
             count, total = self._pend_count, self._pend_sum
             vmin, vmax = self._pend_min, self._pend_max
-            gbps = self.blocking_bytes / self.blocking_wall_s / 1e9 \
-                if self.blocking_wall_s > 0.0 else None
             self._pend_calls = 0
             self._pend_bytes = 0
             self._pend_buckets = {}
@@ -327,19 +303,17 @@ class KernelProfile:
             self._pend_sum = 0.0
             self._pend_min = math.inf
             self._pend_max = -math.inf
-        calls_c, _, bytes_c, _, gbps_g, _ = self._ensure_handles()
+        calls_c, _, bytes_c, _, _ = self._ensure_handles()
         if calls:
             calls_c.inc(calls)
         if nbytes:
             bytes_c.inc(nbytes)
         self._reg.observe_aggregate(self._wall_name, buckets, count,
                                     total, vmin, vmax)
-        if gbps is not None:
-            gbps_g.set(gbps)
 
     def record_compile(self, count: int, dt: float, args: tuple,
                        kwargs: dict, fn: Any, nbytes: int) -> None:
-        calls, compiles_c, bytes_c, _, _, frac_g = self._ensure_handles()
+        calls, compiles_c, bytes_c, _, frac_g = self._ensure_handles()
         calls.inc()
         compiles_c.inc(count)
         self._reg.counter_inc("kernel.compiles", count)
@@ -443,8 +417,6 @@ class _ObservedKernel:
         t0 = time.perf_counter()
         try:
             out = self._fn(*args, **kwargs)
-            if _BLOCKING:
-                _jax().block_until_ready(out)
         except BaseException:
             prof.record_error()
             raise
@@ -463,7 +435,7 @@ class _ObservedKernel:
             prof.record_compile(compiled, dt, args, kwargs, self._fn,
                                 nbytes)
         else:
-            prof.record_call(dt, nbytes, _BLOCKING)
+            prof.record_call(dt, nbytes)
         return out
 
     def __getattr__(self, item):
@@ -570,9 +542,6 @@ class KernelObservatory:
                 "bytes_total": p.bytes_total,
                 "wall_p50_s": _hist_quantile(h, 0.5),
                 "wall_p99_s": _hist_quantile(h, 0.99),
-                "gbps": round(
-                    p.blocking_bytes / p.blocking_wall_s / 1e9, 4
-                ) if p.blocking_wall_s > 0 else None,
                 "last_compile_shapes": p.last_signature,
                 "cost_flops": p.cost["flops"] if p.cost else None,
                 "cost_bytes_accessed":

@@ -454,14 +454,10 @@ NAMESPACE: tuple[NameSpec, ...] = (
              "(decode thread behind)"),
     NameSpec("serve.reads_per_s", "gauge",
              "rows/s of the most recent served batch"),
-    NameSpec("serve.read_latency", "histogram",
-             "per-batch serve wall (admission park included)"),
-    NameSpec("serve.park_wait", "histogram",
-             "admission park wall per parked batch"),
     NameSpec("serve.latency.*", "histogram",
              "per-batch serve wall by consistency mode "
-             "(eventual/ryw/monotonic/frontier) — the PR 17 gap: "
-             "serve.read_latency aggregated, nothing split by mode"),
+             "(eventual/ryw/monotonic/frontier; admission park "
+             "included)"),
     NameSpec("serve.park_wait_s", "histogram",
              "admission park duration in seconds per parked batch "
              "(what /healthz's serve section reports as wall)"),
@@ -473,6 +469,11 @@ NAMESPACE: tuple[NameSpec, ...] = (
              "read rows per serve wire direction (encode/decode)"),
     NameSpec("wire.serve.*.bytes", "counter",
              "serve frame bytes per direction"),
+    NameSpec("serve.leg.*", "histogram",
+             "one host leg of a served read frame (span): decode / "
+             "admit / dispatch (padding, indices to the device, the "
+             "gather call) / wait (the gather's device time) / fetch "
+             "(outputs to the host) / heat (record_reads) / encode"),
     # -- pipelined wire loop (batch/wireloop.py) -----------------------------
     NameSpec("wireloop.stalls", "counter",
              "folds that waited on the parse thread past the threshold"),
@@ -480,6 +481,24 @@ NAMESPACE: tuple[NameSpec, ...] = (
              "free staging plane sets (0 = parse-bound)"),
     NameSpec("wireloop.parsed_depth", "gauge",
              "parsed fleets queued ahead of the fold"),
+    NameSpec("wireloop.parse", "histogram",
+             "one fleet's blobs parsed into a staging set (span, on "
+             "the parser thread when overlapped)"),
+    NameSpec("wireloop.wait_parsed", "histogram",
+             "the fold waiting for the next parsed fleet (span)"),
+    NameSpec("wireloop.put", "histogram",
+             "one staging set copied to the device, until the copy "
+             "completes (span, jnp fold)"),
+    NameSpec("wireloop.dispatch", "histogram",
+             "one fold merge dispatched (span, the merge runs async "
+             "on the jnp fold)"),
+    NameSpec("wireloop.wait", "histogram",
+             "a round's fold finishing on the device, then its "
+             "overflow check (span, jnp fold)"),
+    NameSpec("wireloop.fetch", "histogram",
+             "a round's fixpoint planes copied to the host (span)"),
+    NameSpec("wireloop.encode", "histogram",
+             "a round's fixpoint encoded as wire blobs (span)"),
     # -- executor (parallel/executor.py) -------------------------------------
     NameSpec("executor.recovery.*", "counter",
              "recoveries by kind (regrow/transient_retry) — disjoint from "
@@ -491,9 +510,9 @@ NAMESPACE: tuple[NameSpec, ...] = (
     NameSpec("executor.shrink", "histogram",
              "capacity shrink (GC re-pack) span — the regrow path in "
              "reverse (crdt_tpu/gc/repack.py)"),
-    # -- kernels (utils/tracing.timed_kernel, obs/kernels.py) ----------------
+    # -- kernels (obs/kernels.py) --------------------------------------------
     NameSpec("kernel.*.errors", "counter",
-             "raising calls per timed/observed kernel label"),
+             "raising calls per observed kernel label"),
     NameSpec("kernel.*.calls", "counter",
              "invocations per observed kernel label (manifest name with "
              "dots flattened to underscores)"),
@@ -503,13 +522,8 @@ NAMESPACE: tuple[NameSpec, ...] = (
              "array bytes moved through an observed kernel (inputs + "
              "outputs; an HBM-traffic lower bound)"),
     NameSpec("kernel.*.wall", "histogram",
-             "per-call wall per observed kernel (dispatch wall by "
-             "default; device time under CRDT_TRACE=1/set_blocking; "
-             "compiling calls excluded — they ride kernel.compile "
-             "events)"),
-    NameSpec("kernel.*.gbps", "gauge",
-             "bytes-moved throughput per observed kernel (blocking-mode "
-             "samples only — the bandwidth-roofline coordinate)"),
+             "per-call dispatch wall per observed kernel (compiling "
+             "calls excluded — they ride kernel.compile events)"),
     NameSpec("kernel.*.compile_budget_frac", "gauge",
              "runtime compiles over the kernelcheck KC04 compile_budget "
              "— KC04's static bound as a live watermark (>1 sustained "
@@ -602,6 +616,14 @@ NAMESPACE: tuple[NameSpec, ...] = (
     NameSpec("mesh.step.digest_bytes", "counter",
              "bytes moved by the step's digest all_gather (the whole "
              "collective bill of a converged round)"),
+    NameSpec("mesh.step.dispatch", "histogram",
+             "one step's salts and program call (span)"),
+    NameSpec("mesh.step.wait", "histogram",
+             "one step's program finishing on the chips, then its "
+             "overflow check (span)"),
+    NameSpec("mesh.step.fetch", "histogram",
+             "one step's digests, version vector and member count "
+             "copied to the host and widened to u64 (span)"),
     NameSpec("mesh.sync.rounds", "counter",
              "shard-subset sync passes (digest compare + per-shard "
              "descent)"),

@@ -114,51 +114,51 @@ class ServeLoop:
         t0 = time.perf_counter()
         deadline = None
         parked = False
-        while True:
-            # snapshot FIRST: admission evidence and the gather must
-            # come from the same batch object, or a concurrent fold
-            # could admit against a newer clock and gather older rows
-            snapshot = self.node.batch
-            vv = visible_vv(snapshot)
-            frontier_vv, subtree_clocks, span = self._frontier()
-            ruling = cons.admit(req.mode, req.require, vv,
-                                frontier_vv=frontier_vv)
-            if ruling.admitted:
-                break
-            if ruling.reason == "not_visible" and self.park_timeout_s > 0:
-                now = time.perf_counter()
-                if deadline is None:
-                    deadline = now + self.park_timeout_s
-                    parked = True
-                    tracing.count(f"serve.park.{req.mode}")
-                if now < deadline:
-                    # nudge pending ops toward visibility, then re-poll
-                    drain = getattr(self.node, "try_drain", None)
-                    if drain is not None:
-                        drain()
-                    time.sleep(self.park_poll_s)
-                    continue
-            tracing.count(f"serve.reject.{req.mode}")
-            raise ConsistencyUnavailableError(
-                f"{req.mode} read not servable: {ruling.reason} "
-                f"(parked {'yes' if parked else 'no'}, "
-                f"timeout {self.park_timeout_s}s)",
-                mode=req.mode, reason=ruling.reason or "",
-            )
-        tracing.count(f"serve.admit.{req.mode}")
-        if parked:
-            park_wall = time.perf_counter() - t0
-            reg.observe("serve.park_wait", park_wall)
-            reg.observe("serve.park_wait_s", park_wall)
-        # node serving is single-kind (the node holds one dense batch);
-        # a request naming a different kind is a caller error, not wire
-        node_kind = infer_kind(snapshot)
-        if len(req) and not (req.kind == node_kind).all():
-            raise ValueError(
-                f"read batch names kind(s) "
-                f"{sorted(set(int(k) for k in req.kind))} but this node "
-                f"serves kind {node_kind} only"
-            )
+        with tracing.span("serve.leg.admit"):
+            while True:
+                # snapshot FIRST: admission evidence and the gather must
+                # come from the same batch object, or a concurrent fold
+                # could admit against a newer clock and gather older rows
+                snapshot = self.node.batch
+                vv = visible_vv(snapshot)
+                frontier_vv, subtree_clocks, span = self._frontier()
+                ruling = cons.admit(req.mode, req.require, vv,
+                                    frontier_vv=frontier_vv)
+                if ruling.admitted:
+                    break
+                if ruling.reason == "not_visible" and self.park_timeout_s > 0:
+                    now = time.perf_counter()
+                    if deadline is None:
+                        deadline = now + self.park_timeout_s
+                        parked = True
+                        tracing.count(f"serve.park.{req.mode}")
+                    if now < deadline:
+                        # nudge pending ops toward visibility, then re-poll
+                        drain = getattr(self.node, "try_drain", None)
+                        if drain is not None:
+                            drain()
+                        time.sleep(self.park_poll_s)
+                        continue
+                tracing.count(f"serve.reject.{req.mode}")
+                raise ConsistencyUnavailableError(
+                    f"{req.mode} read not servable: {ruling.reason} "
+                    f"(parked {'yes' if parked else 'no'}, "
+                    f"timeout {self.park_timeout_s}s)",
+                    mode=req.mode, reason=ruling.reason or "",
+                )
+            tracing.count(f"serve.admit.{req.mode}")
+            if parked:
+                reg.observe("serve.park_wait_s",
+                            time.perf_counter() - t0)
+            # node serving is single-kind (the node holds one dense batch);
+            # a request naming a different kind is a caller error, not wire
+            node_kind = infer_kind(snapshot)
+            if len(req) and not (req.kind == node_kind).all():
+                raise ValueError(
+                    f"read batch names kind(s) "
+                    f"{sorted(set(int(k) for k in req.kind))} but this node "
+                    f"serves kind {node_kind} only"
+                )
         frame = gather(snapshot, req.obj, member=req.member,
                        kind=node_kind)
         frame.token = vv
@@ -166,12 +166,13 @@ class ServeLoop:
             # read heat: this gather batch's rows, attributed to the
             # admission mode (node-private tracker when the node has
             # one; the process-global otherwise)
-            heat = getattr(self.node, "heat", None)
-            if heat is None:
-                from ..obs import heat as obs_heat
-                heat = obs_heat.tracker()
-            heat.record_reads(req.obj, _plane_rows(snapshot, node_kind),
-                              mode=req.mode)
+            with tracing.span("serve.leg.heat"):
+                heat = getattr(self.node, "heat", None)
+                if heat is None:
+                    from ..obs import heat as obs_heat
+                    heat = obs_heat.tracker()
+                heat.record_reads(req.obj, _plane_rows(snapshot, node_kind),
+                                  mode=req.mode)
         if req.mode == cons.MODE_FRONTIER:
             frame.status = cons.stability_statuses(
                 frame, subtree_clocks, span)
@@ -179,7 +180,6 @@ class ServeLoop:
             if bad:
                 tracing.count("serve.not_stable_rows", bad)
         wall = time.perf_counter() - t0
-        reg.observe("serve.read_latency", wall)
         reg.observe(f"serve.latency.{req.mode}", wall)
         if wall > 0 and len(frame):
             reg.gauge_set("serve.reads_per_s", len(frame) / wall)

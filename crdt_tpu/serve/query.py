@@ -86,7 +86,7 @@ def _pad_rows(obj: np.ndarray, member: Optional[np.ndarray] = None):
         if member is not None:
             member = np.concatenate(
                 [member, np.full(bp - b, NO_MEMBER, member.dtype)])
-    return obj, member, b
+    return obj, member
 
 
 @functools.lru_cache(maxsize=None)
@@ -295,13 +295,21 @@ def row_to_vclock(row, universe=None):
     return vc
 
 
-def _gather_orswot(batch, obj, member):
+# Each kind's gather is two halves: ``dispatch`` pads the batch, moves
+# the indices to the device and calls the jitted kernel (async);
+# ``rows`` copies the kernel's outputs to the host and cuts the padding
+# off.  ``gather`` times each half, and the wait between them, as a leg.
+
+def _dispatch_orswot(batch, obj, member):
     import jax.numpy as jnp
 
-    obj_p, mem_p, b = _pad_rows(obj, member)
-    val, add, rm, ids, count = _orswot_kernel()(
-        batch.clock, batch.ids, batch.dots,
-        jnp.asarray(obj_p), jnp.asarray(mem_p))
+    obj_p, mem_p = _pad_rows(obj, member)
+    return _orswot_kernel()(batch.clock, batch.ids, batch.dots,
+                            jnp.asarray(obj_p), jnp.asarray(mem_p))
+
+
+def _rows_orswot(out, b):
+    val, add, rm, ids, count = out
     return (np.asarray(val, np.uint64)[:b],
             np.asarray(add, np.uint64)[:b],
             np.asarray(rm, np.uint64)[:b],
@@ -309,23 +317,31 @@ def _gather_orswot(batch, obj, member):
              "count": np.asarray(count, np.uint64)[:b]})
 
 
-def _gather_gcounter(batch, obj, member):
+def _dispatch_gcounter(batch, obj, member):
     import jax.numpy as jnp
 
-    obj_p, _, b = _pad_rows(obj)
-    val, row = _counter_kernel()(batch.clocks, jnp.asarray(obj_p))
+    obj_p, _ = _pad_rows(obj)
+    return _counter_kernel()(batch.clocks, jnp.asarray(obj_p))
+
+
+def _rows_gcounter(out, b):
+    val, row = out
     row = np.asarray(row, np.uint64)[:b]
     return np.asarray(val, np.uint64)[:b], row, row.copy(), {}
 
 
-def _gather_pncounter(batch, obj, member):
+def _dispatch_pncounter(batch, obj, member):
     import jax.numpy as jnp
 
-    obj_p, _, b = _pad_rows(obj)
+    obj_p, _ = _pad_rows(obj)
     kern = _counter_kernel()
     jobj = jnp.asarray(obj_p)
-    p_sum, p_row = kern(batch.planes[:, 0, :], jobj)
-    n_sum, n_row = kern(batch.planes[:, 1, :], jobj)
+    return (*kern(batch.planes[:, 0, :], jobj),
+            *kern(batch.planes[:, 1, :], jobj))
+
+
+def _rows_pncounter(out, b):
+    p_sum, p_row, n_sum, n_row = out
     p_sum = np.asarray(p_sum, np.uint64)[:b]
     n_sum = np.asarray(n_sum, np.uint64)[:b]
     # P − N in two's complement (`pncounter.rs:117-119`; reinterpret as
@@ -337,23 +353,29 @@ def _gather_pncounter(batch, obj, member):
     return val, clock, clock.copy(), {"p": p_sum, "n": n_sum}
 
 
-def _gather_lww(batch, obj, member):
+def _dispatch_lww(batch, obj, member):
     import jax.numpy as jnp
 
-    obj_p, _, b = _pad_rows(obj)
-    vals, markers = _lww_kernel()(batch.vals, batch.markers,
-                                  jnp.asarray(obj_p))
+    obj_p, _ = _pad_rows(obj)
+    return _lww_kernel()(batch.vals, batch.markers, jnp.asarray(obj_p))
+
+
+def _rows_lww(out, b):
+    vals, markers = out
     zeros = np.zeros((b, 0), np.uint64)  # clockless
     return (np.asarray(vals, np.uint64)[:b], zeros, zeros.copy(),
             {"marker": np.asarray(markers, np.uint64)[:b]})
 
 
-def _gather_mvreg(batch, obj, member):
+def _dispatch_mvreg(batch, obj, member):
     import jax.numpy as jnp
 
-    obj_p, _, b = _pad_rows(obj)
-    vals, clocks, fold, live, count = _mvreg_kernel()(
-        batch.clocks, batch.vals, jnp.asarray(obj_p))
+    obj_p, _ = _pad_rows(obj)
+    return _mvreg_kernel()(batch.clocks, batch.vals, jnp.asarray(obj_p))
+
+
+def _rows_mvreg(out, b):
+    vals, clocks, fold, live, count = out
     fold = np.asarray(fold, np.uint64)[:b]
     return (np.asarray(count, np.uint64)[:b], fold, fold.copy(),
             {"mv_vals": np.asarray(vals)[:b],
@@ -361,26 +383,30 @@ def _gather_mvreg(batch, obj, member):
              "mv_live": np.asarray(live, bool)[:b]})
 
 
-def _gather_map(batch, obj, member):
+def _dispatch_map(batch, obj, member):
     import jax.numpy as jnp
 
-    obj_p, key_p, b = _pad_rows(obj, member)
-    val, add, rm, count = _map_kernel()(
-        batch.clock, batch.keys, batch.entry_clocks,
-        jnp.asarray(obj_p), jnp.asarray(key_p))
+    obj_p, key_p = _pad_rows(obj, member)
+    return _map_kernel()(batch.clock, batch.keys, batch.entry_clocks,
+                         jnp.asarray(obj_p), jnp.asarray(key_p))
+
+
+def _rows_map(out, b):
+    val, add, rm, count = out
     return (np.asarray(val, np.uint64)[:b],
             np.asarray(add, np.uint64)[:b],
             np.asarray(rm, np.uint64)[:b],
             {"count": np.asarray(count, np.uint64)[:b]})
 
 
+#: kind → (dispatch, rows)
 _GATHERS = {
-    K_ORSWOT: _gather_orswot,
-    K_GCOUNTER: _gather_gcounter,
-    K_PNCOUNTER: _gather_pncounter,
-    K_LWW: _gather_lww,
-    K_MVREG: _gather_mvreg,
-    K_MAP: _gather_map,
+    K_ORSWOT: (_dispatch_orswot, _rows_orswot),
+    K_GCOUNTER: (_dispatch_gcounter, _rows_gcounter),
+    K_PNCOUNTER: (_dispatch_pncounter, _rows_pncounter),
+    K_LWW: (_dispatch_lww, _rows_lww),
+    K_MVREG: (_dispatch_mvreg, _rows_mvreg),
+    K_MAP: (_dispatch_map, _rows_map),
 }
 
 
@@ -430,7 +456,15 @@ def gather(batch, obj, *, member=None, kind: Optional[int] = None
         add = rm = np.zeros((0, 0), np.uint64)
         extras = {}
     else:
-        val, add, rm, extras = _GATHERS[kind](batch, obj, member)
+        import jax
+
+        dispatch, rows = _GATHERS[kind]
+        with tracing.span("serve.leg.dispatch"):
+            out = dispatch(batch, obj, member)
+        with tracing.span("serve.leg.wait"):
+            jax.block_until_ready(out)
+        with tracing.span("serve.leg.fetch"):
+            val, add, rm, extras = rows(out, b)
     tracing.count("serve.reads", b)
     tracing.count("serve.batches")
     return ResultFrame(

@@ -149,70 +149,73 @@ def decode_read_request(frame: bytes, *, num_objects: int | None = None
     ``num_objects`` additionally bounds the object column against the
     serving fleet (an object outside the dense axis cannot be
     gathered)."""
-    payload = _open(frame, FRAME_READ, "read-request")
-    head, off = _take(payload, 0, _REQ_FIXED.size, "the request header")
-    b, w, mode_code = _REQ_FIXED.unpack(bytes(head))
-    if mode_code not in CODE_MODES:
-        raise _reject("bad_mode",
-                      f"read frame carries unknown consistency mode "
-                      f"code {mode_code}", hard=True)
-    raw, off = _take(payload, off, b * 8, "the object column")
-    obj = np.frombuffer(raw, dtype="<u8").astype(np.int64)
-    raw, off = _take(payload, off, b, "the kind column")
-    kind = np.frombuffer(raw, dtype="<u1")
-    raw, off = _take(payload, off, b * 4, "the member column")
-    member = np.frombuffer(raw, dtype="<i4").astype(np.int32)
-    raw, off = _take(payload, off, w * 8, "the require clock")
-    require = np.frombuffer(raw, dtype="<u8").astype(np.uint64)
-    if off != len(payload):
-        raise _reject(
-            "trailing_bytes",
-            f"read payload carries {len(payload) - off} trailing bytes",
-            hard=True,
-        )
-    if b and not np.isin(kind, np.asarray(READ_KINDS, np.uint8)).all():
-        bad = int(kind[~np.isin(kind, np.asarray(READ_KINDS, np.uint8))][0])
-        raise _reject("bad_kind",
-                      f"read frame carries unknown kind {bad}", hard=True)
-    if b and int(member.min()) < NO_MEMBER:
-        raise _reject("bad_member",
-                      f"read frame member {int(member.min())} below the "
-                      f"NO_MEMBER sentinel {NO_MEMBER}", hard=True)
-    if b and num_objects is not None and int(obj.max()) >= num_objects:
-        raise _reject(
-            "object_range",
-            f"read object {int(obj.max())} outside the serving fleet's "
-            f"dense axis [0, {num_objects})", hard=True,
-        )
-    req = ReadRequest(obj=obj, kind=kind.copy(), member=member,
-                      mode=CODE_MODES[mode_code],
-                      require=require if w else None)
-    tracing.count("serve.frames.decoded")
-    tracing.count("wire.serve.decode.ops", b)
-    tracing.count("wire.serve.decode.bytes", len(bytes(frame)))
-    return req
+    with tracing.span("serve.leg.decode"):
+        payload = _open(frame, FRAME_READ, "read-request")
+        head, off = _take(payload, 0, _REQ_FIXED.size, "the request header")
+        b, w, mode_code = _REQ_FIXED.unpack(bytes(head))
+        if mode_code not in CODE_MODES:
+            raise _reject("bad_mode",
+                          f"read frame carries unknown consistency mode "
+                          f"code {mode_code}", hard=True)
+        raw, off = _take(payload, off, b * 8, "the object column")
+        obj = np.frombuffer(raw, dtype="<u8").astype(np.int64)
+        raw, off = _take(payload, off, b, "the kind column")
+        kind = np.frombuffer(raw, dtype="<u1")
+        raw, off = _take(payload, off, b * 4, "the member column")
+        member = np.frombuffer(raw, dtype="<i4").astype(np.int32)
+        raw, off = _take(payload, off, w * 8, "the require clock")
+        require = np.frombuffer(raw, dtype="<u8").astype(np.uint64)
+        if off != len(payload):
+            raise _reject(
+                "trailing_bytes",
+                f"read payload carries {len(payload) - off} trailing bytes",
+                hard=True,
+            )
+        known = np.isin(kind, np.asarray(READ_KINDS, np.uint8))
+        if b and not known.all():
+            bad = int(kind[~known][0])
+            raise _reject("bad_kind",
+                          f"read frame carries unknown kind {bad}", hard=True)
+        if b and int(member.min()) < NO_MEMBER:
+            raise _reject("bad_member",
+                          f"read frame member {int(member.min())} below the "
+                          f"NO_MEMBER sentinel {NO_MEMBER}", hard=True)
+        if b and num_objects is not None and int(obj.max()) >= num_objects:
+            raise _reject(
+                "object_range",
+                f"read object {int(obj.max())} outside the serving fleet's "
+                f"dense axis [0, {num_objects})", hard=True,
+            )
+        req = ReadRequest(obj=obj, kind=kind.copy(), member=member,
+                          mode=CODE_MODES[mode_code],
+                          require=require if w else None)
+        tracing.count("serve.frames.decoded")
+        tracing.count("wire.serve.decode.ops", b)
+        tracing.count("wire.serve.decode.bytes", len(bytes(frame)))
+        return req
 
 
 def encode_result_frame(res: ResultFrame) -> bytes:
     """One result frame for a gathered batch."""
-    b = len(res)
-    w = int(res.add_clock.shape[1]) if res.add_clock.ndim == 2 else 0
-    token = np.asarray(res.token, np.uint64).reshape(-1)
-    payload = b"".join([
-        _RES_FIXED.pack(b, w, token.size),
-        np.ascontiguousarray(res.obj, dtype="<u8").tobytes(),
-        np.ascontiguousarray(res.kind, dtype="<u1").tobytes(),
-        np.ascontiguousarray(res.member, dtype="<i4").tobytes(),
-        np.ascontiguousarray(res.status, dtype="<u1").tobytes(),
-        np.ascontiguousarray(res.val, dtype="<u8").tobytes(),
-        np.ascontiguousarray(res.add_clock, dtype="<u8").tobytes(),
-        np.ascontiguousarray(res.rm_clock, dtype="<u8").tobytes(),
-        np.ascontiguousarray(token, dtype="<u8").tobytes(),
-    ])
-    frame = _envelope(FRAME_RESULT, payload)
-    tracing.count("wire.serve.encode.ops", b)
-    tracing.count("wire.serve.encode.bytes", len(frame))
-    return frame
+    with tracing.span("serve.leg.encode"):
+        b = len(res)
+        w = int(res.add_clock.shape[1]) if res.add_clock.ndim == 2 else 0
+        token = np.asarray(res.token, np.uint64).reshape(-1)
+        payload = b"".join([
+            _RES_FIXED.pack(b, w, token.size),
+            np.ascontiguousarray(res.obj, dtype="<u8").tobytes(),
+            np.ascontiguousarray(res.kind, dtype="<u1").tobytes(),
+            np.ascontiguousarray(res.member, dtype="<i4").tobytes(),
+            np.ascontiguousarray(res.status, dtype="<u1").tobytes(),
+            np.ascontiguousarray(res.val, dtype="<u8").tobytes(),
+            np.ascontiguousarray(res.add_clock, dtype="<u8").tobytes(),
+            np.ascontiguousarray(res.rm_clock, dtype="<u8").tobytes(),
+            np.ascontiguousarray(token, dtype="<u8").tobytes(),
+        ])
+        frame = _envelope(FRAME_RESULT, payload)
+        tracing.count("wire.serve.encode.ops", b)
+        tracing.count("wire.serve.encode.bytes", len(frame))
+        return frame
 
 
 def decode_result_frame(frame: bytes) -> ResultFrame:

@@ -3,18 +3,17 @@
 The reference has no tracing at all (no logging crates in
 `/root/reference/Cargo.toml:17-25`; its only observability is ``Display``
 impls driven by `examples/pprint.rs`).  On TPU the equivalent first-class
-needs are (a) wall-time accounting per kernel invocation — merges are
-dispatched asynchronously, so timing must block on the result — and (b)
-XLA profiler capture for inspecting fusion/HBM behavior.  This module
-provides both, dependency-free:
+needs are (a) wall-time accounting of the host legs around the device
+work — merges are dispatched asynchronously, so a span names what the
+host did while the device ran or waited — and (b) XLA profiler capture
+for inspecting fusion/HBM behavior.  This module provides both,
+dependency-free:
 
 * :func:`span` / :class:`Tracer` — nestable wall-time spans aggregated
   into per-name statistics (count / total / mean / min / max).  When JAX
   is importable each span also emits a ``jax.profiler.TraceAnnotation``
-  so spans line up with XLA ops in captured traces.
-* :func:`timed_kernel` — decorator that wraps a jitted kernel so every
-  call is traced as a span (blocking on the outputs, so the time is the
-  device time + dispatch, not just the enqueue).
+  so spans line up with XLA ops in captured traces: per-kernel device
+  time comes from that trace, not from blocking on kernel outputs.
 * :func:`profile` — context manager around ``jax.profiler.trace`` writing
   a TensorBoard-loadable XLA trace directory; no-ops cleanly when the
   backend can't profile.
@@ -50,38 +49,16 @@ class SpanStats:
     total_s: float = 0.0
     min_s: float = float("inf")
     max_s: float = 0.0
-    bytes_total: int = 0
 
-    def add(self, dt: float, nbytes: int = 0) -> None:
+    def add(self, dt: float) -> None:
         self.count += 1
         self.total_s += dt
         self.min_s = min(self.min_s, dt)
         self.max_s = max(self.max_s, dt)
-        self.bytes_total += nbytes
 
     @property
     def mean_s(self) -> float:
         return self.total_s / self.count if self.count else 0.0
-
-    @property
-    def gbps(self) -> float:
-        """Effective memory bandwidth (bytes moved / wall time) — the
-        roofline coordinate for bandwidth-bound merge kernels."""
-        return self.bytes_total / self.total_s / 1e9 if self.total_s else 0.0
-
-
-def pytree_bytes(*trees: Any) -> int:
-    """Total array bytes across pytrees — feed as a span's ``nbytes`` to
-    get bytes-moved / effective-GB/s accounting in the report."""
-    import jax
-
-    total = 0
-    for tree in trees:
-        for leaf in jax.tree_util.tree_leaves(tree):
-            nb = getattr(leaf, "nbytes", None)
-            if nb is not None:
-                total += int(nb)
-    return total
 
 
 # metric names whose registry forwarding already warned about a
@@ -137,10 +114,10 @@ class Tracer:
             self._reg = obs_metrics.registry()
         return self._reg
 
-    def add(self, name: str, dt: float, nbytes: int = 0) -> None:
+    def add(self, name: str, dt: float) -> None:
         """Record one observation for ``name`` (thread-safe)."""
         with self._lock:
-            self.stats.setdefault(name, SpanStats()).add(dt, nbytes)
+            self.stats.setdefault(name, SpanStats()).add(dt)
         if self.forward_metrics:
             # span latency histogram (log2 buckets), seconds
             _forward(self._registry().observe, name, dt)
@@ -164,7 +141,7 @@ class Tracer:
             return dict(self.counts)
 
     @contextlib.contextmanager
-    def span(self, name: str, nbytes: int = 0) -> Iterator[None]:
+    def span(self, name: str) -> Iterator[None]:
         if not self.enabled:
             yield
             return
@@ -174,7 +151,7 @@ class Tracer:
             with annot:
                 yield
         finally:
-            self.add(name, time.perf_counter() - t0, nbytes)
+            self.add(name, time.perf_counter() - t0)
 
     def reset(self) -> None:
         with self._lock:
@@ -203,14 +180,13 @@ class Tracer:
         w = max([32] + [len(name) for name, _ in rows])
         lines = [
             f"{'span':<{w}} {'count':>7} {'total':>10} {'mean':>10} "
-            f"{'min':>10} {'max':>10} {'GB/s':>8}"
+            f"{'min':>10} {'max':>10}"
         ]
         for name, s in rows:
-            gbps = f"{s.gbps:>7.2f}" if s.bytes_total else f"{'—':>7}"
             lines.append(
                 f"{name:<{w}} {s.count:>7} {s.total_s*1e3:>9.2f}ms "
                 f"{s.mean_s*1e3:>9.3f}ms {s.min_s*1e3:>9.3f}ms "
-                f"{s.max_s*1e3:>9.3f}ms {gbps}"
+                f"{s.max_s*1e3:>9.3f}ms"
             )
         cw = max(cw, w)
         lines.extend(f"{name:<{cw}} {n:>12}" for name, n in counter_rows)
@@ -321,52 +297,6 @@ def report() -> str:
 
 def reset() -> None:
     _GLOBAL.reset()
-
-
-def timed_kernel(name: Optional[str] = None, count_bytes: bool = False) -> Callable:
-    """Wrap a (jitted) kernel so each call is a blocking span.
-
-    Blocks on the outputs via ``jax.block_until_ready`` so the recorded
-    time covers device execution, not just async dispatch — without this,
-    XLA's async dispatch makes per-call wall times meaningless.
-
-    With ``count_bytes=True`` each call also records input + output array
-    bytes (a lower bound on HBM traffic), so the report's GB/s column
-    places the kernel on the bandwidth roofline."""
-
-    def deco(fn: Callable) -> Callable:
-        label = name or getattr(fn, "__name__", "kernel")
-
-        def wrapped(*args: Any, **kwargs: Any):
-            if not _GLOBAL.enabled:
-                return fn(*args, **kwargs)
-            import jax
-
-            t0 = time.perf_counter()
-            try:
-                with _trace_annotation(label):
-                    out = fn(*args, **kwargs)
-                    jax.block_until_ready(out)
-            except BaseException:
-                # record failing calls too — a raising kernel (overflow,
-                # device error) must not vanish from the report.  Bytes
-                # cover INPUTS ONLY (outputs were never materialized,
-                # whether fn raised with out unbound or block_until_ready
-                # raised on a poisoned result), and the per-label errors
-                # counter makes a flaky kernel visible from the artifact.
-                nbytes = pytree_bytes(args, kwargs) if count_bytes else 0
-                _GLOBAL.add(label, time.perf_counter() - t0, nbytes)
-                _GLOBAL.count(f"kernel.{label}.errors")
-                raise
-            nbytes = pytree_bytes(args, kwargs, out) if count_bytes else 0
-            _GLOBAL.add(label, time.perf_counter() - t0, nbytes)
-            return out
-
-        wrapped.__name__ = getattr(fn, "__name__", "kernel")
-        wrapped.__doc__ = fn.__doc__
-        return wrapped
-
-    return deco
 
 
 # profiler-setup failures already flight-recorded, one event per

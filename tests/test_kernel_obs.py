@@ -144,23 +144,6 @@ def test_wall_histogram_steady_state_and_storm_report():
     assert hist_after - hist_before == 20
 
 
-def test_blocking_mode_fills_gbps_and_bytes():
-    plane = jnp.zeros((83, 8), dtype=jnp.uint32)
-    before = _counters()
-    obs_kernels.set_blocking(True)
-    try:
-        vclock_batch._merge(plane, plane)  # compile call (event, no hist)
-        vclock_batch._merge(plane, plane)
-    finally:
-        obs_kernels.set_blocking(False)
-    after = _counters()
-    per_call = 3 * plane.nbytes  # two inputs + one output
-    assert _delta(before, after, "kernel.batch_vclock_merge.bytes") == \
-        2 * per_call
-    gauges = _snap()["gauges"]
-    assert gauges["kernel.batch_vclock_merge.gbps"] > 0
-
-
 def test_cost_analysis_capture_is_lazy_and_memoized():
     plane = jnp.zeros((79, 8), dtype=jnp.uint32)
     vclock_batch._merge(plane, plane)

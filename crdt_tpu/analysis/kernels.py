@@ -902,7 +902,8 @@ def _b_heat_sketch():
 
 def _b_serve_gather(which: str):
     """The read front-end's gather kernels (serve/query.py): pure
-    gathers from the dense planes into columnar result frames.  Read
+    gathers from the dense planes (ORSWOT: from its row view, whose
+    build is the ``view`` row) into columnar result frames.  Read
     batches pad to the power-of-two ladder (serve.query.PAD_FLOOR), so
     the traced rungs walk capacity x padded-batch — one legitimate
     lowering per rung."""
@@ -913,16 +914,24 @@ def _b_serve_gather(which: str):
         dt = _clock_dt()
         idt = "int64" if dt == "uint64" else "int32"
         cases = []
-        if which == "orswot":
-            fn = _unjit(serve_query._orswot_kernel())
+        if which == "view":
+            fn = _unjit(serve_query._view_kernel())
             for (a, m, _d) in LADDER:
+                cases.append(TraceCase(
+                    rung=f"A{a}.M{m}", fn=fn,
+                    args=(_mat((LADDER_N, a), dt),
+                          _mat((LADDER_N, m), "int32"),
+                          _mat((LADDER_N, m, a), dt))))
+        elif which == "orswot":
+            for (a, m, _d) in LADDER:
+                fn = _unjit(serve_query._orswot_kernel(a, m))
+                w = serve_query._view_width(a, m)
                 for b in (8, 64):
                     cases.append(TraceCase(
                         rung=f"A{a}.M{m}.B{b}", fn=fn,
-                        args=(_mat((LADDER_N, a), dt),
-                              _mat((LADDER_N, m), "int32"),
-                              _mat((LADDER_N, m, a), dt),
-                              _vec(b, idt), _vec(b, "int32"))))
+                        args=(_mat((LADDER_N, w), dt),
+                              _vec(b, idt), _vec(b, "int32")),
+                        key=(a, m)))
         elif which == "counter":
             fn = _unjit(serve_query._counter_kernel())
             for a in ACTOR_LADDER:
@@ -1303,10 +1312,15 @@ MANIFEST: tuple = (
                    routed=(3,)),
                build=_b_heat_sketch()),
     # serve/query.py (the read front-end's gather kernels) -------------------
+    KernelSpec("serve.view.orswot", "crdt_tpu/serve/query.py",
+               "_view_kernel.build",
+               compile_budget=len(LADDER),
+               sharding=pointwise(0, 1, 2),
+               build=_b_serve_gather("view")),
     KernelSpec("serve.gather.orswot", "crdt_tpu/serve/query.py",
                "_orswot_kernel.kernel",
                compile_budget=2 * len(LADDER),  # capacity x padded batch
-               sharding=pointwise(0, 1, 2, routed=(3,)),
+               sharding=pointwise(0, routed=(1,)),
                build=_b_serve_gather("orswot")),
     KernelSpec("serve.gather.counter", "crdt_tpu/serve/query.py",
                "_counter_kernel.kernel",

@@ -112,6 +112,7 @@ class ClusterNode:
         from ..obs import heat as obs_heat
         from ..obs import latency as obs_latency
         from ..obs import stability as obs_stability
+        from ..serve.query import ViewCache
 
         self.node_id = node_id
         self.universe = universe
@@ -197,6 +198,14 @@ class ClusterNode:
         # the read front-end (crdt_tpu/serve): built lazily on the
         # first serve_reads call so write-only nodes pay nothing
         self._serve_loop = None
+        #: the row view the serve path reads the current ORSWOT
+        #: snapshot through (:class:`crdt_tpu.serve.query.ViewCache`,
+        #: empty until the first read).  Every path that replaces the
+        #: batch releases it before its fold allocates the next
+        #: snapshot: the old batch, the new one and the view do not fit
+        #: one chip together at the ★ size (6.2 + 6.2 + 5.76 GB); the
+        #: next read rebuilds it from the new snapshot
+        self.serve_views = ViewCache()
 
     @property
     def batch(self):
@@ -410,6 +419,7 @@ class ClusterNode:
             clock = getattr(batch, "clock", None)
             if clock is not None:
                 self.heat.record_writes(ops.obj, int(clock.shape[0]))
+        self.serve_views.release()
         batch, report = self._applier.apply_ops(batch, ops)
         with self._lock:
             self._batch = batch
@@ -483,6 +493,7 @@ class ClusterNode:
                 heat=self.heat,
                 **op_hooks,
             )
+            self.serve_views.release()
             report = session.sync(transport)
             with self._lock:
                 self._batch = session.batch
@@ -518,6 +529,7 @@ class ClusterNode:
         try:
             with self._lock:
                 batch = self._batch
+            self.serve_views.release()
             batch, report = self.gc.collect(
                 batch, universe=self.universe, oplog=self._oplog,
                 applier=self._applier, peers=peers)
@@ -674,6 +686,7 @@ class ClusterNode:
                     mine = self._batch
                 with peer._lock:
                     theirs = peer._batch
+                self.serve_views.release()
                 merged, stats = mesh_sync.shard_subset_sync(
                     mine, theirs, layout, self.universe,
                     applier=self._applier)

@@ -474,6 +474,14 @@ NAMESPACE: tuple[NameSpec, ...] = (
              "admit / dispatch (padding, indices to the device, the "
              "gather call) / wait (the gather's device time) / fetch "
              "(outputs to the host) / heat (record_reads) / encode"),
+    NameSpec("serve.view.build", "histogram",
+             "one ORSWOT row-view build (span, outside every "
+             "serve.leg.*): the snapshot's planes laid out "
+             "object-major, once per served snapshot"),
+    NameSpec("serve.view.builds", "counter",
+             "ORSWOT row views built (one per new snapshot served)"),
+    NameSpec("serve.view.hits", "counter",
+             "ORSWOT gathers served from an already-built row view"),
     # -- pipelined wire loop (batch/wireloop.py) -----------------------------
     NameSpec("wireloop.stalls", "counter",
              "folds that waited on the parse thread past the threshold"),

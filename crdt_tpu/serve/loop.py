@@ -159,8 +159,10 @@ class ServeLoop:
                     f"{sorted(set(int(k) for k in req.kind))} but this node "
                     f"serves kind {node_kind} only"
                 )
+        # the node's view cache: the node drops its view before it folds
         frame = gather(snapshot, req.obj, member=req.member,
-                       kind=node_kind)
+                       kind=node_kind,
+                       views=getattr(self.node, "serve_views", None))
         frame.token = vv
         if len(req):
             # read heat: this gather batch's rows, attributed to the

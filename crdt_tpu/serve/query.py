@@ -24,17 +24,25 @@ with the add/rm clocks — parity-pinned row-for-row against the scalar
 ``ReadCtx`` loop (tests/test_serve.py), so a remove derived from a
 gathered row is byte-identical to one derived from a scalar clone.
 
+The ORSWOT gather reads an object-major row view of the snapshot
+(:class:`RowView`), built once per snapshot and kept by a
+:class:`ViewCache`: the device stores the planes object-minor, and a
+row gather straight from them relayouts each whole plane on every call.
+
 Batch sizes pad to the next power of two (floor :data:`PAD_FLOOR`) so
 the jit cache walks a log-bounded ladder, the same discipline as the
 op-path scatter (`oplog/apply.py`).  Every jit site here has a
-manifest row (``serve.gather.*``, `analysis/kernels.py`).
+manifest row (``serve.gather.*``, ``serve.view.orswot``,
+`analysis/kernels.py`).
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, Optional
+import threading
+import weakref
+from typing import Any, Dict, NamedTuple, Optional
 
 import numpy as np
 
@@ -89,25 +97,87 @@ def _pad_rows(obj: np.ndarray, member: Optional[np.ndarray] = None):
     return obj, member
 
 
+#: objects per step of the row-view build: its temporaries are a chunk
+#: of rows (302 MB planned at the ★ width, u32), not a whole plane
+VIEW_CHUNK = 32768
+
+
+def _view_width(a: int, m: int) -> int:
+    """Lanes of one view row: the clock, the member ids and the dots,
+    padded to a whole number of 128-lane tiles."""
+    return -(-(a + m + m * a) // 128) * 128
+
+
+def _signed(dtype):
+    import jax.numpy as jnp
+
+    return jnp.int64 if jnp.dtype(dtype).itemsize == 8 else jnp.int32
+
+
 @functools.lru_cache(maxsize=None)
-def _orswot_kernel():
-    """ONE jitted ORSWOT read gather: ``(clock[N,A], ids[N,M],
-    dots[N,M,A], obj[B], member[B])`` → per-row val, add clock row, rm
-    clock row, member-id row, and live-member count.  ``member >= 0``
-    rows are ``contains`` probes (rm = the matched slot's witnessing
-    dots, zeros when absent — the empty ``VClock()`` of
-    `orswot.rs:214-224`); ``NO_MEMBER`` rows are ``value()`` reads
-    (rm = the set clock)."""
+def _view_kernel():
+    """The jitted ORSWOT row-view build: ``(clock[N,A], ids[N,M],
+    dots[N,M,A])`` → ``rows[N,W]`` in the counter dtype, each object's
+    row its clock, its member ids (a lossless bitcast) and its dots,
+    zero-padded to :func:`_view_width`.
+
+    The device keeps the planes object-minor (the object axis on the
+    lanes), which a row gather cannot read without relayouting the
+    whole plane; the row view is that relayout, paid once per snapshot.
+    It is written :data:`VIEW_CHUNK` objects at a time so its
+    temporaries stay one chunk; the last chunk is clamped to end at N
+    and rewrites rows it shares with the one before with the same
+    values."""
     import jax
     import jax.numpy as jnp
+    from jax import lax
+
+    from ..obs.kernels import observed_kernel
+
+    def build(clock, ids, dots):
+        n, a = clock.shape
+        m = ids.shape[1]
+        dt = clock.dtype
+        w = _view_width(a, m)
+        c = min(VIEW_CHUNK, n)
+
+        def step(i, rows):
+            at = jnp.minimum(i * c, n - c)
+            cl, idc, dc = (lax.dynamic_slice_in_dim(p, at, c, 0)
+                           for p in (clock, ids, dots))
+            chunk = jnp.concatenate(
+                [cl, lax.bitcast_convert_type(idc.astype(_signed(dt)), dt),
+                 dc.reshape(c, m * a),
+                 jnp.zeros((c, w - a - m - m * a), dt)], axis=1)
+            return lax.dynamic_update_slice_in_dim(rows, chunk, at, 0)
+
+        return lax.fori_loop(0, -(-n // c), step, jnp.zeros((n, w), dt))
+
+    return observed_kernel("serve.view.orswot")(jax.jit(build))
+
+
+@functools.lru_cache(maxsize=None)
+def _orswot_kernel(a: int, m: int):
+    """ONE jitted ORSWOT read gather over the row view (``a`` actors,
+    ``m`` member slots): ``(rows[N,W], obj[B], member[B])`` → per-row
+    val, add clock row, rm clock row, member-id row, and live-member
+    count.  ``member >= 0`` rows are ``contains`` probes (rm = the
+    matched slot's witnessing dots, zeros when absent — the empty
+    ``VClock()`` of `orswot.rs:214-224`); ``NO_MEMBER`` rows are
+    ``value()`` reads (rm = the set clock)."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
 
     from ..obs.kernels import observed_kernel
     from ..ops import orswot_ops
 
-    def kernel(clock, ids, dots, obj, member):
-        crow = jnp.take(clock, obj, axis=0)               # [B, A]
-        idrow = jnp.take(ids, obj, axis=0)                # [B, M]
-        dotrow = jnp.take(dots, obj, axis=0)              # [B, M, A]
+    def kernel(rows, obj, member):
+        row = jnp.take(rows, obj, axis=0)                 # [B, W]
+        crow = row[:, :a]                                 # [B, A]
+        idrow = lax.bitcast_convert_type(                 # [B, M]
+            row[:, a:a + m], _signed(rows.dtype)).astype(jnp.int32)
+        dotrow = row[:, a + m:a + m + m * a].reshape(-1, m, a)
         want = member[:, None]
         hit = (idrow == want) & (want >= 0) \
             & (idrow != orswot_ops.EMPTY)                 # [B, M]
@@ -295,17 +365,88 @@ def row_to_vclock(row, universe=None):
     return vc
 
 
+class RowView(NamedTuple):
+    """An ORSWOT snapshot's object-major row view (:func:`_view_kernel`)
+    and the widths that slice its rows."""
+
+    rows: Any                           # [N, W], counter dtype
+    actors: int
+    members: int
+
+
+def build_view(batch) -> RowView:
+    """Build ``batch``'s row view, waiting for the device to finish."""
+    import jax
+
+    with tracing.span("serve.view.build"):
+        rows = jax.block_until_ready(
+            _view_kernel()(batch.clock, batch.ids, batch.dots))
+    tracing.count("serve.view.builds")
+    return RowView(rows, int(batch.clock.shape[1]), int(batch.ids.shape[1]))
+
+
+class ViewCache:
+    """The row view of the last ORSWOT snapshot served: one slot, keyed
+    by the snapshot's identity.  A batch is immutable and every write
+    makes a new one, so a view is never stale.  The slot holds the
+    snapshot weakly and its view strongly, and drops the view when the
+    snapshot is collected, when :meth:`release` is called (a node does
+    so before each fold, so the old batch, the new one and a view are
+    never held together), and before a new snapshot's view is built,
+    so one view is held at a time.  Thread-safe: concurrent first reads of a snapshot build it
+    once; the others wait for that build."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._slot = None                   # (weakref to batch, RowView)
+
+    def view(self, batch) -> RowView:
+        with self._lock:
+            slot = self._slot
+            if slot is not None and slot[0]() is batch:
+                tracing.count("serve.view.hits")
+                return slot[1]
+            # drop every reference first: the old view's device memory
+            # is freed before the new one is allocated
+            self._slot = slot = None
+            view = build_view(batch)
+            self._slot = (weakref.ref(batch, self._forget), view)
+            return view
+
+    def release(self) -> None:
+        """Drop the held view; the next read rebuilds it."""
+        with self._lock:
+            self._slot = None
+
+    def _forget(self, ref) -> None:
+        # the snapshot was collected: its view goes with it.  If the lock
+        # is taken, its holder is replacing or dropping this slot anyway
+        # (a dead snapshot never hits), so never wait: the last reference
+        # to a batch may fall on a thread that holds other locks
+        if self._lock.acquire(blocking=False):
+            try:
+                if self._slot is not None and self._slot[0] is ref:
+                    self._slot = None  # crdtlint: disable=lock-discipline — held: acquired without waiting
+            finally:
+                self._lock.release()
+
+
+#: the view cache of a :func:`gather` called without one
+_VIEWS = ViewCache()
+
+
 # Each kind's gather is two halves: ``dispatch`` pads the batch, moves
 # the indices to the device and calls the jitted kernel (async);
 # ``rows`` copies the kernel's outputs to the host and cuts the padding
 # off.  ``gather`` times each half, and the wait between them, as a leg.
+# The ORSWOT halves read the snapshot's row view, not the batch.
 
-def _dispatch_orswot(batch, obj, member):
+def _dispatch_orswot(view, obj, member):
     import jax.numpy as jnp
 
     obj_p, mem_p = _pad_rows(obj, member)
-    return _orswot_kernel()(batch.clock, batch.ids, batch.dots,
-                            jnp.asarray(obj_p), jnp.asarray(mem_p))
+    return _orswot_kernel(view.actors, view.members)(
+        view.rows, jnp.asarray(obj_p), jnp.asarray(mem_p))
 
 
 def _rows_orswot(out, b):
@@ -430,13 +571,15 @@ def infer_kind(batch) -> int:
     )
 
 
-def gather(batch, obj, *, member=None, kind: Optional[int] = None
-           ) -> ResultFrame:
+def gather(batch, obj, *, member=None, kind: Optional[int] = None,
+           views: Optional[ViewCache] = None) -> ResultFrame:
     """Resolve one single-kind read batch against ``batch`` — one
     jitted gather regardless of batch size.  ``member`` probes
     membership (ORSWOT) / keys (map); ``NO_MEMBER`` rows read the
-    whole object.  The frame's ``token`` is left empty — the serve
-    loop stamps it from the snapshot's version vector."""
+    whole object.  An ORSWOT gather reads ``batch``'s row view from
+    ``views`` (by default the module's own :class:`ViewCache`).  The
+    frame's ``token`` is left empty — the serve loop stamps it from the
+    snapshot's version vector."""
     obj = np.asarray(obj, np.int64).reshape(-1)
     if kind is None:
         kind = infer_kind(batch)
@@ -459,8 +602,14 @@ def gather(batch, obj, *, member=None, kind: Optional[int] = None
         import jax
 
         dispatch, rows = _GATHERS[kind]
+        src = batch
+        if kind == K_ORSWOT:
+            src = (_VIEWS if views is None else views).view(batch)
         with tracing.span("serve.leg.dispatch"):
-            out = dispatch(batch, obj, member)
+            out = dispatch(src, obj, member)
+        # the gather holds what it reads; a view released meanwhile is
+        # freed once the gather is done with it, not with this frame
+        del src
         with tracing.span("serve.leg.wait"):
             jax.block_until_ready(out)
         with tracing.span("serve.leg.fetch"):
@@ -494,6 +643,7 @@ class QueryEngine:
             if k not in _GATHERS:
                 raise ValueError(f"unknown read kind {k}")
         self.batches = dict(batches)
+        self.views = ViewCache()
 
     def width(self) -> int:
         """The widest clock row any served kind produces."""
@@ -536,7 +686,7 @@ class QueryEngine:
         for k in present:
             idx = np.nonzero(kind == k)[0]
             sub = gather(self.batches[int(k)], obj[idx],
-                         member=member[idx], kind=int(k))
+                         member=member[idx], kind=int(k), views=self.views)
             val[idx] = sub.val
             wk = sub.add_clock.shape[1]
             add[idx, :wk] = sub.add_clock

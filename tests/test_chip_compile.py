@@ -79,15 +79,44 @@ def test_wireloop_fold_kernel_compiles(one_chip, monkeypatch):
     assert compiled.memory_analysis().output_size_in_bytes > 0
 
 
-def test_serve_gather_compiles(one_chip):
-    from crdt_tpu.serve.query import _orswot_kernel
+STAR_N = 1_250_000  # the ★ replica, one chip's whole share
 
-    f = _fleet(65_536, one_chip)
+
+def test_serve_gather_compiles(one_chip):
+    """The largest read frame of the ★ replica, gathered from its row
+    view: the view is read in place (no whole-view copy) and the program
+    plans a few MB of temporaries.  A gather straight from the
+    object-minor planes relayouts each whole plane (10.24 GB planned)."""
+    import re
+
+    import crdt_tpu.batch  # noqa: F401  (x64 on, as on the run path)
+    from crdt_tpu.serve.query import _orswot_kernel, _view_width
+
+    rows = jax.ShapeDtypeStruct((STAR_N, _view_width(A, M)), jnp.uint32,
+                                sharding=one_chip)
     obj = jax.ShapeDtypeStruct((4096,), jnp.int64, sharding=one_chip)
     member = jax.ShapeDtypeStruct((4096,), jnp.int32, sharding=one_chip)
-    compiled = _orswot_kernel().lower(f[0], f[1], f[2], obj, member) \
-        .compile()
-    assert compiled.memory_analysis().argument_size_in_bytes > 0
+    compiled = _orswot_kernel(A, M).lower(rows, obj, member).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+    whole = [ln for ln in compiled.as_text().splitlines()
+             if re.search(rf"= \S+\[{STAR_N},[^=]*\bcopy(-start)?\(", ln)]
+    assert not whole, whole
+
+
+def test_serve_view_build_plans_under_one_chip(one_chip):
+    """The ★ replica's row view (1,152 lanes a row, 5.76 GB) is built a
+    chunk at a time: the planes in, the view out and the temporaries
+    plan under 13 GB (built in one piece: 17.0 GB)."""
+    import crdt_tpu.batch  # noqa: F401
+    from crdt_tpu.serve.query import _view_kernel, _view_width
+
+    assert _view_width(A, M) == 1152
+    f = _fleet(STAR_N, one_chip)
+    ma = _view_kernel().lower(f[0], f[1], f[2]).compile().memory_analysis()
+    assert ma.output_size_in_bytes == STAR_N * 1152 * 4
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             + ma.temp_size_in_bytes)
+    assert total < 13 * 10**9, total
 
 
 def test_mesh_step_compiles_on_four_chips(topo):

@@ -382,13 +382,21 @@ class OrswotBatch:
         available, the blobs are parsed IN PARALLEL by the C++ decoder
         (`crdt_tpu/native/wire_ingest.cpp`) directly into dense planes —
         no Python objects, no per-value interning; measured ≥10× the
-        ``from_binary``+``from_scalar`` walk at 1M objects.  Blobs
-        outside the integer-keyed grammar (string members, big-int
-        counters) fall back to the Python decoder per blob, so the fast
-        path never changes semantics — ``from_wire(blobs, uni)`` always
-        equals ``from_scalar([from_binary(b) for b in blobs], uni)``.
+        ``from_binary``+``from_scalar`` walk at 1M objects.  A universe
+        whose actors and members are ``str`` / ``bytes`` names takes the
+        same parser with a native name table per registry: names are
+        looked up in parallel, and names never seen before are interned
+        in blob order, with the ids ``Registry.intern`` would hand out
+        (save that unseen members buffered under one deferred clock take
+        ids in wire order, where ``from_scalar`` takes them in set
+        order).  Blobs outside the native grammar (keys of another type,
+        big-int counters, overlong varints) fall back to the Python
+        decoder per blob, so the fast path never changes semantics —
+        ``from_wire(blobs, uni)`` equals ``from_scalar([from_binary(b)
+        for b in blobs], uni)``, for named universes up to that id
+        order.
 
-        Without an identity universe (arbitrary hashable actors/members)
+        With keys of any other type (ints in a named registry, tuples)
         or without the native engine, the whole batch takes the Python
         path.
 
@@ -405,8 +413,8 @@ class OrswotBatch:
             return cls.zeros(0, universe)
         planes = orswot_planes_from_wire(blobs, universe)
         if planes is None:
-            # no native fast path (engine missing / non-identity
-            # universe): the whole batch decodes in Python
+            # no native fast path (engine missing / keys neither
+            # identity ints nor names): the whole batch decodes in Python
             return cls.from_scalar(
                 [from_binary(b) for b in blobs], universe
             )
@@ -442,12 +450,13 @@ class OrswotBatch:
         """Bulk egress to wire blobs — the inverse of :meth:`from_wire`,
         byte-identical to ``[to_binary(s) for s in self.to_scalar(uni)]``.
 
-        Fast path (identity universe + native engine): the parallel C++
-        encoder (`crdt_tpu/native/wire_ingest.cpp`) serializes the dense
-        planes directly — no scalar objects; the deterministic orderings
-        of the serde codec (encoded-bytes pair sort, repr-sorted clock
-        keys) are reproduced exactly.  Counters at or above 2^63 (u64
-        planes only) and non-identity universes take the Python path."""
+        Fast path (identity or named universe + native engine): the
+        parallel C++ encoder (`crdt_tpu/native/wire_ingest.cpp`)
+        serializes the dense planes directly — no scalar objects; the
+        deterministic orderings of the serde codec (encoded-bytes pair
+        sort, repr-sorted clock keys) are reproduced exactly.  Counters
+        at or above 2^63 (u64 planes only) and keys of other types take
+        the Python path."""
         import numpy as np
 
         from ..utils.serde import to_binary

@@ -35,9 +35,12 @@ def record_wire(leg: str, direction: str, *, native: int = 0,
     regression is visible from the JSON artifact alone (the round-5 e2e
     ingest collapse was initially blamed on exactly such an invisible
     fallback).  Reasons in use: ``no_engine`` (native library absent or
-    symbol missing), ``non_identity`` (universe is not identity-interned),
-    ``grammar`` (per-blob status==1 splice), ``overflow_zigzag`` (u64
-    counters past the native encoder's range).
+    symbol missing), ``non_identity`` (universe is not identity-interned;
+    the legs without a named codec), ``key_type`` (ORSWOT: keys neither
+    identity ints nor ``str``/``bytes`` names), ``unnamed_id`` (ORSWOT
+    egress: a row holds an id its universe has no name for), ``grammar``
+    (per-blob status==1 splice), ``overflow_zigzag`` (u64 counters past
+    the native encoder's range).
 
     A reasoned fallback also lands in the flight recorder (kind
     ``wire.fallback``) — one event per bulk call, so the recorder shows
@@ -202,112 +205,258 @@ def planes_to_wire(planes, universe, probe_name, encode, python_path,
 # ---- ORSWOT shared triage (OrswotBatch.from_wire + PipelinedWireLoop) ------
 
 
+def named_engine(universe, fn_name: str, dtype):
+    """``(engine, (actor table, member table), None)`` when the named
+    codec applies to ``universe``, else ``(None, None, reason)``.
+
+    Applies = a universe whose actor and member registries are
+    :class:`~crdt_tpu.utils.interning.Registry` holding only ``str`` /
+    ``bytes`` values, and an engine that exports ``fn_name``.  Reasons:
+    ``key_type`` (a registry holds another type of value, or is not such
+    a registry), ``no_engine``."""
+    regs = (universe.actors, universe.members)
+    if not all(hasattr(r, "native_names") for r in regs):
+        return None, None, "key_type"
+    try:
+        from ..native import engine
+
+        engine._fn(fn_name, dtype)
+        tables = tuple(r.native_names() for r in regs)
+    except (ImportError, OSError, RuntimeError, AttributeError, TypeError):
+        return None, None, "no_engine"
+    if None in tables:
+        return None, None, "key_type"
+    return engine, tables, None
+
+
+def _registries(universe) -> list:
+    """The universe's registries, each once (actors and members may be
+    one registry)."""
+    regs = [universe.actors]
+    if universe.members is not universe.actors:
+        regs.append(universe.members)
+    return regs
+
+
+def adopt_interned(universe) -> int:
+    """Append the names a native parse interned to the universe's
+    registries (the ``wireloop.intern`` span, counted under
+    ``wire.names.interned``); returns how many."""
+    from ..utils import tracing
+
+    regs = _registries(universe)
+    new = sum(r.native_backlog() for r in regs)
+    if new:
+        with tracing.span("wireloop.intern"):
+            for r in regs:
+                r.adopt_native()
+        tracing.count("wire.names.interned", new)
+    return new
+
+
+def _scalar_rows(blobs, idx, universe, planes) -> None:
+    """Decode blobs ``idx`` with the Python codec and splice their rows
+    into ``planes`` (raises exactly where the scalar path would, with
+    the caller's blob indices)."""
+    import numpy as np
+
+    from ..utils.serde import from_binary
+    from .orswot_batch import OrswotBatch
+
+    try:
+        sub = OrswotBatch.from_scalar(
+            [from_binary(blobs[i]) for i in idx], universe, via_device=False
+        )
+    except (ValueError, TypeError) as e:
+        # from_scalar reports indices relative to the fallback sublist;
+        # translate so the operator can find the blob
+        try:
+            err = type(e)(
+                f"{e} [object indices above are relative to the "
+                f"python-fallback sublist; its blob indices are "
+                f"{idx[:16]}{'...' if len(idx) > 16 else ''}]"
+            )
+        except TypeError:  # an exception type with arguments of its own
+            raise e from None
+        raise err from None
+    rows = np.asarray(idx, dtype=np.int64)
+    for dst, src in zip(planes, (sub.clock, sub.ids, sub.dots, sub.d_ids,
+                                 sub.d_clocks)):
+        dst[rows] = np.asarray(src)
+
+
+def _intern_pending(blobs, buf, offsets, pending, universe, planes,
+                    status, engine) -> list:
+    """The serial pass of the named ingest over blobs ``pending``
+    (status 1 or 5 after the parallel pass, ascending): each is parsed
+    again natively, interning its unseen names in blob order; one the
+    native grammar refuses is decoded in Python at its turn, so the ids
+    come out as ``Registry.intern`` would hand them out to
+    ``from_scalar([from_binary(b) for b in blobs])`` (which takes the
+    unseen members buffered under one deferred clock in set order, this
+    pass in wire order).  Returns the blob indices the Python codec
+    decoded."""
+    fallback: list = []
+    regs = _registries(universe)
+    pending = [int(i) for i in pending]
+    with regs[0].lock, regs[-1].lock:
+        try:
+            pos = 0
+            while pos < len(pending):
+                tables = (universe.actors.native_names(),
+                          universe.members.native_names())
+                if None in tables:
+                    # the Python codec interned a value that is not a
+                    # name: the rest decode in Python, in order
+                    _scalar_rows(blobs, pending[pos:], universe, planes)
+                    status[pending[pos:]] = 0
+                    fallback.extend(pending[pos:])
+                    break
+                pos += engine.orswot_intern_named(
+                    buf, offsets, pending[pos:], planes, status, *tables)
+                adopt_interned(universe)
+                last = pending[pos - 1]
+                if status[last] == 1:
+                    _scalar_rows(blobs, [last], universe, planes)
+                    status[last] = 0
+                    fallback.append(last)
+        finally:
+            adopt_interned(universe)
+    return fallback
+
+
+def _raise_hard_status(status, cfg, actor_range: str) -> None:
+    """Raise ``WireFormatError`` for the first blob whose status is a
+    hard error (2 member overflow, 3 deferred overflow, 4 actor)."""
+    import numpy as np
+
+    hard = np.nonzero(status > 1)[0]
+    if not hard.size:
+        return
+    first = int(hard[0])
+    code = int(status[first])
+    if code == 2:
+        raise WireFormatError(
+            f"object {first}: members > member_capacity "
+            f"{cfg.member_capacity}"
+        )
+    if code == 3:
+        raise WireFormatError(
+            f"object {first}: deferred rows > deferred_capacity "
+            f"{cfg.deferred_capacity}"
+        )
+    raise WireFormatError(f"object {first}: actor outside {actor_range}")
+
+
 def orswot_planes_from_wire(blobs, universe, out=None):
     """Dense ORSWOT planes (host numpy) straight from wire blobs, with
     the full status triage — the shared ingest core of
-    ``OrswotBatch.from_wire`` and :class:`crdt_tpu.batch.wireloop.
-    PipelinedWireLoop`.
+    ``OrswotBatch.from_wire``, :class:`crdt_tpu.batch.wireloop.
+    PipelinedWireLoop` and delta sync.
 
-    Returns ``(clock, ids, dots, d_ids, d_clocks)``, or ``None`` when
-    the native fast path does not apply at all (missing engine /
-    non-identity universe) — the caller then takes its own full-Python
-    route.  Every outcome is counted under the ``wire.orswot.from_wire``
-    counters (:func:`record_wire`).
+    Identity universes take the native integer-keyed parser; universes
+    whose actors and members are ``str`` / ``bytes`` names take the
+    native named parser, which interns unseen names in blob order
+    (:func:`_intern_pending`).  Returns ``(clock, ids, dots, d_ids,
+    d_clocks)``, or ``None`` when no native parser applies (missing
+    engine, or keys of another type) — the caller then takes its own
+    full-Python route.  Every outcome is counted under the
+    ``wire.orswot.from_wire`` counters (:func:`record_wire`).
 
-    ``out``: optional preallocated plane 5-tuple passed through to
-    ``engine.orswot_ingest_wire`` for buffer REUSE across calls — fresh
-    per-call plane allocations page-fault GBs at north-star chunk scale
-    and were the measured e2e ingest collapse (docs/GUIDE.md).
+    ``out``: optional preallocated plane 5-tuple to parse into, for
+    buffer REUSE across calls — fresh per-call plane allocations
+    page-fault GBs at north-star chunk scale and were the measured e2e
+    ingest collapse (docs/GUIDE.md).
 
-    Hard statuses raise ``ValueError`` with the caller's blob index;
-    status==1 blobs (structure outside the fast-path grammar) are
-    decoded by the Python codec and their rows spliced in, so the result
-    always equals the pure-Python decode."""
+    Hard statuses raise ``WireFormatError`` with the caller's blob
+    index; blobs outside the native grammar are decoded by the Python
+    codec and their rows spliced in, so the result equals the
+    pure-Python decode — for a named universe up to the ids of unseen
+    members buffered under one deferred clock, which this parse hands
+    out in wire order and ``from_scalar`` in set order (same states,
+    same names)."""
     import numpy as np
 
     from ..config import counter_dtype
 
     cfg = universe.config
-    engine = probe_engine(universe, "orswot_ingest_wire", counter_dtype(cfg))
+    dt = counter_dtype(cfg)
+    if universe.is_identity:
+        engine = probe_engine(universe, "orswot_ingest_wire", dt)
+        reason = "no_engine"
+    else:
+        engine, tables, reason = named_engine(universe,
+                                              "orswot_ingest_named", dt)
     if engine is None:
-        record_wire("orswot", "from_wire", fallback=len(blobs),
-                    reason=fallback_reason(universe))
+        record_wire("orswot", "from_wire", fallback=len(blobs), reason=reason)
         return None
     buf, offsets = concat_blobs(blobs)
-    clock, ids, dots, d_ids, d_clocks, status = engine.orswot_ingest_wire(
-        buf, offsets, cfg.num_actors, cfg.member_capacity,
-        cfg.deferred_capacity, counter_dtype(cfg), out=out,
-    )
-    n_fb = 0
-    if status.any():
-        # hard errors first, reported with the CALLER's blob index
-        hard = np.nonzero(status > 1)[0]
-        if hard.size:
-            first = int(hard[0])
-            code = int(status[first])
-            if code == 2:
-                raise WireFormatError(
-                    f"object {first}: members > member_capacity "
-                    f"{cfg.member_capacity}"
-                )
-            if code == 3:
-                raise WireFormatError(
-                    f"object {first}: deferred rows > deferred_capacity "
-                    f"{cfg.deferred_capacity}"
-                )
-            raise WireFormatError(
-                f"object {first}: actor outside the identity registry "
-                f"range [0, {cfg.num_actors})"
-            )
-        # code 1: structure outside the fast-path grammar — decode those
-        # blobs in Python and patch their rows (raises exactly where the
-        # scalar path would, e.g. non-int members against an identity
-        # registry)
-        from ..utils.serde import from_binary
-        from .orswot_batch import OrswotBatch
-
+    if universe.is_identity:
+        *planes, status = engine.orswot_ingest_wire(
+            buf, offsets, cfg.num_actors, cfg.member_capacity,
+            cfg.deferred_capacity, dt, out=out,
+        )
+        # hard errors first, reported with the CALLER's blob index;
+        # status 1 (outside the fast-path grammar): decoded in Python
+        # (raises exactly where the scalar path would, e.g. non-int
+        # members against an identity registry)
+        _raise_hard_status(status, cfg, "the identity registry range "
+                           f"[0, {cfg.num_actors})")
         fb = np.nonzero(status == 1)[0].tolist()
-        n_fb = len(fb)
-        try:
-            sub = OrswotBatch.from_scalar(
-                [from_binary(blobs[i]) for i in fb], universe
-            )
-        except (ValueError, TypeError) as e:
-            # from_scalar reports indices relative to the fallback
-            # sublist; translate so the operator can find the blob
-            raise type(e)(
-                f"{e} [object indices above are relative to the "
-                f"python-fallback sublist; its blob indices are "
-                f"{fb[:16]}{'...' if len(fb) > 16 else ''}]"
-            ) from None
-        idx = np.asarray(fb, dtype=np.int64)
-        clock[idx] = np.asarray(sub.clock)
-        ids[idx] = np.asarray(sub.ids)
-        dots[idx] = np.asarray(sub.dots)
-        d_ids[idx] = np.asarray(sub.d_ids)
-        d_clocks[idx] = np.asarray(sub.d_clocks)
-    record_wire("orswot", "from_wire", native=len(blobs) - n_fb,
-                fallback=n_fb, reason="grammar")
-    return clock, ids, dots, d_ids, d_clocks
+        if fb:
+            _scalar_rows(blobs, fb, universe, planes)
+    else:
+        *planes, status = engine.orswot_ingest_named(
+            buf, offsets, cfg.num_actors, cfg.member_capacity,
+            cfg.deferred_capacity, dt, *tables, out=out,
+        )
+        fb = []
+        pending = np.nonzero((status == 1) | (status == 5))[0]
+        if pending.size:
+            fb = _intern_pending(blobs, buf, offsets, pending, universe,
+                                 planes, status, engine)
+        _raise_hard_status(status, cfg, f"the {cfg.num_actors} actor "
+                           "columns (actor registry full)")
+    record_wire("orswot", "from_wire", native=len(blobs) - len(fb),
+                fallback=len(fb), reason="grammar")
+    return tuple(planes)
+
+
+def _repr_rank(universe, width: int):
+    """int32[width]: each actor column's rank by ``repr`` of its name,
+    the pair order of a deferred remove's ClockKey
+    (``VClock.key``)."""
+    import numpy as np
+
+    names = universe.actors.values()[:width]
+    rank = np.full(width, np.iinfo(np.int32).max, dtype=np.int32)
+    order = sorted(range(len(names)), key=lambda i: repr(names[i]))
+    rank[order] = np.arange(len(order), dtype=np.int32)
+    return rank
 
 
 def orswot_planes_to_wire(clock, ids, dots, d_ids, d_clocks, universe):
     """Wire blobs from dense host ORSWOT planes — the shared egress core
-    of ``OrswotBatch.to_wire`` and the pipelined wire loop.
+    of ``OrswotBatch.to_wire`` and the pipelined wire loop, identity or
+    named universes alike.
 
     Returns the blob list, or ``None`` when the Python encoder must run
-    (missing engine / non-identity universe / the u64 zigzag-overflow
-    guard) — the caller serializes via ``to_binary`` then.  Outcomes are
+    (missing engine, keys that are neither identity ints nor names, a
+    row holding an id without a name, or the u64 zigzag-overflow guard)
+    — the caller serializes via ``to_binary`` then.  Outcomes are
     counted under ``wire.orswot.to_wire``."""
     from ..config import counter_dtype
 
     n = clock.shape[0]
     if n == 0:
         return []
-    engine = probe_engine(
-        universe, "orswot_encode_wire", counter_dtype(universe.config)
-    )
-    reason = fallback_reason(universe)
+    dt = counter_dtype(universe.config)
+    if universe.is_identity:
+        engine = probe_engine(universe, "orswot_encode_wire", dt)
+        reason = "no_engine"
+    else:
+        engine, tables, reason = named_engine(universe,
+                                              "orswot_encode_named", dt)
     if engine is not None and counters_overflow_zigzag(
         (clock, dots, d_clocks)
     ):
@@ -315,12 +464,20 @@ def orswot_planes_to_wire(clock, ids, dots, d_ids, d_clocks, universe):
         # varints handle it — take the Python path
         engine = None
         reason = "overflow_zigzag"
-    if engine is None:
-        record_wire("orswot", "to_wire", fallback=n, reason=reason)
-        return None
-    buf, offsets = engine.orswot_encode_wire(clock, ids, dots, d_ids, d_clocks)
-    record_wire("orswot", "to_wire", native=n)
-    return slice_blobs(buf, offsets)
+    if engine is not None:
+        if universe.is_identity:
+            encoded = engine.orswot_encode_wire(clock, ids, dots, d_ids,
+                                                d_clocks)
+        else:
+            encoded = engine.orswot_encode_named(
+                clock, ids, dots, d_ids, d_clocks, *tables,
+                _repr_rank(universe, clock.shape[1]))
+            reason = "unnamed_id"
+        if encoded is not None:
+            record_wire("orswot", "to_wire", native=n)
+            return slice_blobs(*encoded)
+    record_wire("orswot", "to_wire", fallback=n, reason=reason)
+    return None
 
 
 def clockish_from_wire(blobs, universe, tag, planes_of_scalars):

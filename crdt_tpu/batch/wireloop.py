@@ -43,7 +43,8 @@ pipeline").
   ``staging_free == 0`` plus a stall count, without attaching a
   profiler.  With tracing on, each host leg is a ``wireloop.*`` span
   (parse, wait_parsed, put, dispatch, wait, fetch, encode), on the
-  profiler's clock when a trace is captured.
+  profiler's clock when a trace is captured; ``wireloop.intern`` marks
+  a named parse adopting the names it interned.
 
 ``bench_e2e_wire`` (bench.py) and ``examples/anti_entropy.py`` drive
 this one implementation.
@@ -107,11 +108,12 @@ class PipelinedWireLoop:
     out, with host parse overlapped against the fold.
 
     One instance owns the staging/accumulator buffer pools for a fixed
-    ``universe`` (identity universes take the native parse/encode fast
-    path; any other universe still works through the Python codec, just
-    without the zero-allocation steady state).  ``run`` processes any
-    number of rounds; buffers are sized on first use and reused across
-    rounds and across ``run`` calls.
+    ``universe`` (identity universes and universes named by ``str`` /
+    ``bytes`` take the native parse/encode fast path, a named parse
+    interning unseen names as it goes; any other universe still works
+    through the Python codec, just without the zero-allocation steady
+    state).  ``run`` processes any number of rounds; buffers are sized on
+    first use and reused across rounds and across ``run`` calls.
 
     ``fold_path``: ``"native"`` (C++ row kernels, the CPU best engine),
     ``"jnp"`` (jitted device merge, async dispatch), or None to pick
@@ -228,8 +230,8 @@ class PipelinedWireLoop:
             blobs = orswot_planes_to_wire(*planes, self.universe)
             if blobs is not None:
                 return blobs
-            # Python route (non-identity universe / u64 zigzag overflow)
-            # — already counted by orswot_planes_to_wire
+            # Python route (keys neither identity ints nor names / u64
+            # zigzag overflow) — already counted by orswot_planes_to_wire
             from ..utils.serde import to_binary
             from .orswot_batch import OrswotBatch
 

@@ -645,33 +645,7 @@ def orswot_ingest_wire(buf, offsets, a: int, m: int, d: int, dtype, out=None):
     offsets = np.ascontiguousarray(offsets, dtype=np.int64)
     n = offsets.shape[0] - 1
     dt = np.dtype(dtype)
-    if out is None:
-        clear = 0
-        clock = np.zeros((n, a), dtype=dt)
-        ids = np.full((n, m), -1, dtype=np.int32)
-        dots = np.zeros((n, m, a), dtype=dt)
-        d_ids = np.full((n, d), -1, dtype=np.int32)
-        d_clocks = np.zeros((n, d, a), dtype=dt)
-    else:
-        clear = 1
-        clock, ids, dots, d_ids, d_clocks = out
-        expect = (
-            ((n, a), dt), ((n, m), np.dtype(np.int32)),
-            ((n, m, a), dt), ((n, d), np.dtype(np.int32)),
-            ((n, d, a), dt),
-        )
-        for name, buf_, (shape, dtype_) in zip(
-            ("clock", "ids", "dots", "d_ids", "d_clocks"),
-            (clock, ids, dots, d_ids, d_clocks), expect,
-        ):
-            if (not isinstance(buf_, np.ndarray) or buf_.shape != shape
-                    or buf_.dtype != dtype_
-                    or not buf_.flags.c_contiguous):
-                raise ValueError(
-                    f"out[{name}]: need C-contiguous {dtype_}{shape}, got "
-                    f"{getattr(buf_, 'dtype', type(buf_))}"
-                    f"{getattr(buf_, 'shape', '')}"
-                )
+    planes = _orswot_out(n, a, m, d, dt, out)
     status = np.zeros(n, dtype=np.uint8)
     _count_native("orswot_ingest_wire", n)
     fn = _fn("orswot_ingest_wire", dt)
@@ -679,10 +653,40 @@ def orswot_ingest_wire(buf, offsets, a: int, m: int, d: int, dtype, out=None):
     fn(
         _ptr(buf), _ptr(offsets), ctypes.c_int64(n),
         ctypes.c_int64(a), ctypes.c_int64(m), ctypes.c_int64(d),
-        _ptr(clock), _ptr(ids), _ptr(dots), _ptr(d_ids), _ptr(d_clocks),
-        _ptr(status), ctypes.c_int64(clear),
+        *(_ptr(p) for p in planes), _ptr(status),
+        ctypes.c_int64(0 if out is None else 1),
     )
-    return clock, ids, dots, d_ids, d_clocks, status
+    return (*planes, status)
+
+
+def _orswot_out(n: int, a: int, m: int, d: int, dt, out) -> tuple:
+    """The plane 5-tuple a parse writes into: fresh empty planes, or
+    ``out`` checked for shape, dtype and C order."""
+    if out is None:
+        return (
+            np.zeros((n, a), dtype=dt),
+            np.full((n, m), -1, dtype=np.int32),
+            np.zeros((n, m, a), dtype=dt),
+            np.full((n, d), -1, dtype=np.int32),
+            np.zeros((n, d, a), dtype=dt),
+        )
+    expect = (
+        ((n, a), dt), ((n, m), np.dtype(np.int32)),
+        ((n, m, a), dt), ((n, d), np.dtype(np.int32)),
+        ((n, d, a), dt),
+    )
+    for name, buf_, (shape, dtype_) in zip(
+        ("clock", "ids", "dots", "d_ids", "d_clocks"), out, expect,
+    ):
+        if (not isinstance(buf_, np.ndarray) or buf_.shape != shape
+                or buf_.dtype != dtype_
+                or not buf_.flags.c_contiguous):
+            raise ValueError(
+                f"out[{name}]: need C-contiguous {dtype_}{shape}, got "
+                f"{getattr(buf_, 'dtype', type(buf_))}"
+                f"{getattr(buf_, 'shape', '')}"
+            )
+    return tuple(out)
 
 
 def orswot_encode_wire(clock, ids, dots, d_ids, d_clocks):
@@ -750,6 +754,143 @@ def orswot_encode_wire_rows(clock, ids, dots, d_ids, d_clocks, rows):
         ctypes.c_int64(m), ctypes.c_int64(d),
     )
     fn(*args, _ptr(offsets), None)
+    np.cumsum(offsets, out=offsets)
+    buf = np.empty(int(offsets[-1]), dtype=np.uint8)
+    fn(*args, _ptr(offsets), _ptr(buf))
+    return buf, offsets
+
+
+# -- named ORSWOT codec (str / bytes actors and members) ----------------------
+
+
+class NameTable:
+    """One registry's native name table (`wire_ingest.cpp` ``NameTable``):
+    the encoded serde bytes of every interned name, in id order, with a
+    hash index over them.  Append-only; parses and encodes read it under
+    a shared lock while an append takes it alone.  ``capacity`` bounds
+    the ids a parse may hand out."""
+
+    def __init__(self, capacity: int):
+        lib = loader.load()
+        lib.names_new.restype = ctypes.c_void_p
+        lib.names_new.argtypes = [ctypes.c_int64]
+        lib.names_free.argtypes = [ctypes.c_void_p]
+        for name in ("names_count", "names_append", "names_span",
+                     "names_read"):
+            getattr(lib, name).restype = ctypes.c_int64
+        self._lib = lib
+        self.handle = ctypes.c_void_p(lib.names_new(capacity))
+
+    def __del__(self):
+        handle = getattr(self, "handle", None)
+        if handle:
+            self._lib.names_free(handle)
+            self.handle = None
+
+    def __len__(self) -> int:
+        return int(self._lib.names_count(self.handle))
+
+    def append(self, encoded: list[bytes]) -> int:
+        """Append names given as their encoded serde bytes, in order;
+        returns the new count."""
+        from ..batch.wirebulk import concat_blobs
+
+        buf, offsets = concat_blobs(encoded)
+        buf = np.frombuffer(buf, dtype=np.uint8)
+        return int(self._lib.names_append(
+            self.handle, _ptr(buf), _ptr(offsets),
+            ctypes.c_int64(len(encoded))))
+
+    def read(self, start: int, end: int) -> list[bytes]:
+        """The encoded bytes of names ``[start, end)``."""
+        from ..batch.wirebulk import slice_blobs
+
+        size = self._lib.names_span(self.handle, ctypes.c_int64(start),
+                                    ctypes.c_int64(end))
+        buf = np.empty(size, dtype=np.uint8)
+        offsets = np.empty(end - start + 1, dtype=np.int64)
+        self._lib.names_read(self.handle, ctypes.c_int64(start),
+                             ctypes.c_int64(end), _ptr(buf), _ptr(offsets))
+        return slice_blobs(buf.tobytes(), offsets)
+
+
+def orswot_ingest_named(buf, offsets, a: int, m: int, d: int, dtype,
+                        actors: NameTable, members: NameTable, out=None):
+    """The parallel pass of the named wire decode: like
+    :func:`orswot_ingest_wire`, with every actor and member key a str or
+    bytes name looked up in ``actors`` / ``members`` (the row's actor
+    column and member id are the names' ids).  Rows are always cleared
+    first, so ``out`` may be reused.  Status 5 marks a blob holding a
+    name neither table has yet: :func:`orswot_intern_named` takes it."""
+    buf = np.ascontiguousarray(np.frombuffer(buf, dtype=np.uint8))
+    offsets = np.ascontiguousarray(offsets, dtype=np.int64)
+    n = offsets.shape[0] - 1
+    dt = np.dtype(dtype)
+    planes = _orswot_out(n, a, m, d, dt, out)
+    status = np.zeros(n, dtype=np.uint8)
+    _count_native("orswot_ingest_named", n)
+    fn = _fn("orswot_ingest_named", dt)
+    fn.restype = ctypes.c_int64
+    fn(
+        _ptr(buf), _ptr(offsets), ctypes.c_int64(n),
+        ctypes.c_int64(a), ctypes.c_int64(m), ctypes.c_int64(d),
+        actors.handle, members.handle,
+        *(_ptr(p) for p in planes), _ptr(status),
+    )
+    return (*planes, status)
+
+
+def orswot_intern_named(buf, offsets, idx, planes, status,
+                        actors: NameTable, members: NameTable) -> int:
+    """The serial pass of the named decode: re-parse blobs ``idx``
+    (ascending) into their rows of ``planes``, appending each unseen name
+    to its table where it is met, and update ``status``.  Stops after the
+    first blob whose status comes out 1 (outside the grammar); returns
+    how many of ``idx`` it took."""
+    buf = np.ascontiguousarray(np.frombuffer(buf, dtype=np.uint8))
+    offsets = np.ascontiguousarray(offsets, dtype=np.int64)
+    idx = np.ascontiguousarray(idx, dtype=np.int64)
+    clock, ids = planes[0], planes[1]
+    dt = np.dtype(clock.dtype)
+    fn = _fn("orswot_intern_named", dt)
+    fn.restype = ctypes.c_int64
+    return int(fn(
+        _ptr(buf), _ptr(offsets), _ptr(idx), ctypes.c_int64(idx.shape[0]),
+        ctypes.c_int64(clock.shape[1]), ctypes.c_int64(ids.shape[1]),
+        ctypes.c_int64(planes[3].shape[1]), actors.handle, members.handle,
+        *(_ptr(p) for p in planes), _ptr(status),
+    ))
+
+
+def orswot_encode_named(clock, ids, dots, d_ids, d_clocks,
+                        actors: NameTable, members: NameTable, repr_rank):
+    """Parallel wire ENCODE of dense planes with names for keys —
+    byte-identical to ``to_binary`` of the per-object scalar states of a
+    universe whose names the two tables hold.  ``repr_rank``: int32[A],
+    each actor column's rank by ``repr`` of its name (the ClockKey pair
+    order of deferred removes).
+
+    Returns ``(buf, offsets)``, or None when a row holds an actor column
+    or member id without a name (nothing is encoded then)."""
+    clock, ids, dots, d_ids, d_clocks = _contig(
+        clock, ids, dots, d_ids, d_clocks
+    )
+    dt = _check_counters(clock, dots, d_clocks)
+    n, a = clock.shape
+    m = ids.shape[-1]
+    d = d_ids.shape[-1]
+    repr_rank = np.ascontiguousarray(repr_rank, dtype=np.int32)
+    offsets = np.zeros(n + 1, dtype=np.int64)
+    _count_native("orswot_encode_named", n)
+    fn = _fn("orswot_encode_named", dt)
+    fn.restype = ctypes.c_int64
+    args = (
+        _ptr(clock), _ptr(ids), _ptr(dots), _ptr(d_ids), _ptr(d_clocks),
+        ctypes.c_int64(n), ctypes.c_int64(a), ctypes.c_int64(m),
+        ctypes.c_int64(d), actors.handle, members.handle, _ptr(repr_rank),
+    )
+    if fn(*args, _ptr(offsets), None):
+        return None
     np.cumsum(offsets, out=offsets)
     buf = np.empty(int(offsets[-1]), dtype=np.uint8)
     fn(*args, _ptr(offsets), _ptr(buf))

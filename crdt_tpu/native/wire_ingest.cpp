@@ -27,7 +27,9 @@
 //
 // Identity interning: the caller guarantees a Universe whose actor index
 // IS the actor value (< A) and whose member id IS the member value
-// (int32) — see crdt_tpu.utils.interning.IdentityRegistry.  Counters
+// (int32) — see crdt_tpu.utils.interning.IdentityRegistry.  Named
+// universes (str/bytes actors and members) take the same grammar with
+// names for keys: the named codec at the end of this file.  Counters
 // beyond the counter dtype flag the blob for fallback (the Python path
 // raises OverflowError at the numpy conversion; the fast path must never
 // silently wrap a causal counter).
@@ -36,6 +38,7 @@
 //   0 ok    1 fallback (structure outside the fast-path grammar)
 //   2 member overflow (> M)      3 deferred overflow (> D)
 //   4 actor out of range (>= A or negative)
+//   5 (named codec only) a name the table has not interned yet
 //
 // Each object writes only its own rows, so the object loop is
 // embarrassingly parallel (OpenMP).
@@ -44,6 +47,10 @@
 #include <cstdint>
 #include <cstdio>
 #include <cstring>
+#include <limits>
+#include <mutex>
+#include <numeric>
+#include <shared_mutex>
 #include <vector>
 
 #if defined(_OPENMP)
@@ -75,7 +82,12 @@ struct Cursor {
 
   // unsigned LEB128, capped at the u64 range — anything longer (or any
   // byte contributing bits past 2^64) is a legitimate big-int payload
-  // the fast path hands to Python rather than silently truncating
+  // the fast path hands to Python rather than silently truncating.
+  // Only the minimal encoding is taken: the parsers compare and look
+  // keys up by their wire bytes, which stand for the value only when
+  // the value has one encoding (serde's reader also takes an overlong
+  // varint — a last byte of 0 after the first — so such a blob goes to
+  // Python, which decodes it by value)
   bool uv(uint64_t* out) {
     uint64_t v = 0;
     int shift = 0;
@@ -85,6 +97,7 @@ struct Cursor {
       if (shift == 63 && (b & 0x7F) > 1) return false;  // bits >= 2^64
       v |= static_cast<uint64_t>(b & 0x7F) << shift;
       if (!(b & 0x80)) {
+        if (i > 0 && b == 0) return false;  // overlong
         *out = v;
         return true;
       }
@@ -104,9 +117,37 @@ struct Cursor {
   }
 };
 
-// defined with the egress helpers below; declared here for the parsers'
-// canonical-order checks
+// defined with the egress helpers below; declared here for the Map
+// parser's canonical-order checks
 bool varint_bytes_less(uint64_t za, uint64_t zb);
+
+// python-bytes comparison of two encoded keys: lexicographic,
+// shorter-prefix-first (the order of serde.py's sorted() over encoded
+// key bytes)
+inline bool span_less(const uint8_t* a, size_t la, const uint8_t* b,
+                      size_t lb) {
+  const size_t m = la < lb ? la : lb;
+  const int c = std::memcmp(a, b, m);
+  return c < 0 || (c == 0 && la < lb);
+}
+
+// Key readers: how an actor or member key on the wire becomes a dense
+// index.  ``IntKeys`` is the identity universe (the key IS the index);
+// ``NamedKeys`` (the named codec, below) looks names up in a table.
+// Both return a status code (0 ok, 1 fallback); the caller range-checks
+// an actor against A once its counter is read (status 4).
+struct IntKeys {
+  // 0x03 zz(actor): the actor column
+  int actor(Cursor& c, uint64_t* out) const { return c.nonneg(out) ? 0 : 1; }
+  // 0x03 zz(member), a member id in the int32 id space
+  int member(Cursor& c, int32_t* out) const {
+    uint64_t m;
+    if (!c.nonneg(&m)) return 1;
+    if (m > 0x7FFFFFFFull) return 1;  // beyond int32 id space
+    *out = static_cast<int32_t>(m);
+    return 0;
+  }
+};
 
 // deferred section (shared by ORSWOT and Map): uv groups, each a
 // clock-key tuple + member/key list.  One dense row per (clock, id)
@@ -114,9 +155,9 @@ bool varint_bytes_less(uint64_t za, uint64_t zb);
 // scratch row and copied to every row buffered under it (matches
 // from_scalar's layout: `for member in members: one row sharing the
 // clock columns`).
-template <typename C>
+template <typename C, typename K = IntKeys>
 int parse_deferred_section(Cursor& c, int64_t A, int64_t D, int32_t* d_ids,
-                           C* d_clocks) {
+                           C* d_clocks, const K& keys = K{}) {
   constexpr uint64_t kCounterMax = static_cast<uint64_t>(~C{0});
   uint64_t n;
   if (!c.uv(&n)) return 1;
@@ -138,34 +179,34 @@ int parse_deferred_section(Cursor& c, int64_t A, int64_t D, int32_t* d_ids,
     for (uint64_t i = 0; i < k; ++i) {
       uint64_t two, actor, counter;
       if (!c.byte(kTagTuple) || !c.uv(&two) || two != 2) return 1;
-      if (!c.nonneg(&actor) || !c.nonneg(&counter)) return 1;
+      if (int st = keys.actor(c, &actor)) return st;
+      if (!c.nonneg(&counter)) return 1;
       if (actor >= static_cast<uint64_t>(A)) return 4;
       if (counter > kCounterMax) return 1;
       scratch[actor] = static_cast<C>(counter);
     }
     const size_t key_len = static_cast<size_t>(c.p - key_start);
-    if (q > 0) {
-      // strictly ascending encoded clock-key bytes (the egress group
-      // comparator: memcmp, shorter-is-less on shared-prefix tie)
-      const size_t m_ = prev_key_len < key_len ? prev_key_len : key_len;
-      const int cmp = std::memcmp(prev_key, key_start, m_);
-      if (!(cmp < 0 || (cmp == 0 && prev_key_len < key_len))) return 1;
-    }
+    // strictly ascending encoded clock-key bytes (the egress group
+    // comparator)
+    if (q > 0 && !span_less(prev_key, prev_key_len, key_start, key_len))
+      return 1;
     prev_key = key_start;
     prev_key_len = key_len;
     uint64_t m;
     if (!c.uv(&m)) return 1;
-    uint64_t prev_member = 0;
+    const uint8_t* prev_member = nullptr;
+    size_t prev_len = 0;
     for (uint64_t j = 0; j < m; ++j) {
-      uint64_t member;
-      if (!c.nonneg(&member)) return 1;
-      if (member > 0x7FFFFFFFull) return 1;
-      if (j > 0 && !varint_bytes_less(prev_member << 1, member << 1))
-        return 1;
-      prev_member = member;
+      const uint8_t* start = c.p;
+      int32_t member;
+      if (int st = keys.member(c, &member)) return st;
+      const size_t len = static_cast<size_t>(c.p - start);
+      if (j > 0 && !span_less(prev_member, prev_len, start, len)) return 1;
+      prev_member = start;
+      prev_len = len;
       if (drow >= D) return 3;
       std::memcpy(d_clocks + drow * A, scratch.data(), sizeof(C) * A);
-      d_ids[drow] = static_cast<int32_t>(member);
+      d_ids[drow] = member;
       ++drow;
     }
   }
@@ -175,9 +216,10 @@ int parse_deferred_section(Cursor& c, int64_t A, int64_t D, int32_t* d_ids,
 // one full ORSWOT value from the cursor (tag 0x26 through the deferred
 // section, NO end-of-blob check) — shared by the top-level blob parser
 // and the Map<K, Orswot> entry values
-template <typename C>
+template <typename C, typename K = IntKeys>
 int parse_orswot_value(Cursor& c, int64_t A, int64_t M, int64_t D, C* clock,
-                       int32_t* ids, C* dots, int32_t* d_ids, C* d_clocks) {
+                       int32_t* ids, C* dots, int32_t* d_ids, C* d_clocks,
+                       const K& keys = K{}) {
   // counters beyond the counter dtype are NOT wrapped: the Python path
   // (numpy conversion) raises OverflowError, so the fast path flags the
   // blob for fallback and lets that exact behavior happen
@@ -189,7 +231,8 @@ int parse_orswot_value(Cursor& c, int64_t A, int64_t M, int64_t D, C* clock,
   if (!c.uv(&n)) return 1;
   for (uint64_t i = 0; i < n; ++i) {
     uint64_t actor, counter;
-    if (!c.nonneg(&actor) || !c.nonneg(&counter)) return 1;
+    if (int st = keys.actor(c, &actor)) return st;
+    if (!c.nonneg(&counter)) return 1;
     if (actor >= static_cast<uint64_t>(A)) return 4;
     if (counter > kCounterMax) return 1;
     clock[actor] = static_cast<C>(counter);
@@ -203,21 +246,23 @@ int parse_orswot_value(Cursor& c, int64_t A, int64_t M, int64_t D, C* clock,
   // falls back to the Python path (which dedupes/handles it ITS way)
   if (!c.uv(&n)) return 1;
   if (n > static_cast<uint64_t>(M)) return 2;
-  uint64_t prev_member = 0;
+  const uint8_t* prev_member = nullptr;
+  size_t prev_len = 0;
   for (uint64_t e = 0; e < n; ++e) {
-    uint64_t member;
-    if (!c.nonneg(&member)) return 1;
-    if (member > 0x7FFFFFFFull) return 1;  // beyond int32 id space
-    if (e > 0 && !varint_bytes_less(prev_member << 1, member << 1)) return 1;
-    prev_member = member;
-    ids[e] = static_cast<int32_t>(member);
+    const uint8_t* start = c.p;
+    if (int st = keys.member(c, ids + e)) return st;
+    const size_t len = static_cast<size_t>(c.p - start);
+    if (e > 0 && !span_less(prev_member, prev_len, start, len)) return 1;
+    prev_member = start;
+    prev_len = len;
     if (!c.byte(kTagVClock)) return 1;
     uint64_t k;
     if (!c.uv(&k)) return 1;
     C* row = dots + e * A;
     for (uint64_t i = 0; i < k; ++i) {
       uint64_t actor, counter;
-      if (!c.nonneg(&actor) || !c.nonneg(&counter)) return 1;
+      if (int st = keys.actor(c, &actor)) return st;
+      if (!c.nonneg(&counter)) return 1;
       if (actor >= static_cast<uint64_t>(A)) return 4;
       if (counter > kCounterMax) return 1;
       row[actor] = static_cast<C>(counter);
@@ -225,16 +270,16 @@ int parse_orswot_value(Cursor& c, int64_t A, int64_t M, int64_t D, C* clock,
   }
 
   // deferred: one dense row per (clock, member) pair
-  return parse_deferred_section<C>(c, A, D, d_ids, d_clocks);
+  return parse_deferred_section<C>(c, A, D, d_ids, d_clocks, keys);
 }
 
-template <typename C>
+template <typename C, typename K = IntKeys>
 int parse_one(const uint8_t* buf, int64_t lo, int64_t hi, int64_t A,
               int64_t M, int64_t D, C* clock, int32_t* ids, C* dots,
-              int32_t* d_ids, C* d_clocks) {
+              int32_t* d_ids, C* d_clocks, const K& keys = K{}) {
   Cursor c{buf + lo, buf + hi};
   int st = parse_orswot_value<C>(c, A, M, D, clock, ids, dots, d_ids,
-                                 d_clocks);
+                                 d_clocks, keys);
   if (st) return st;
   if (c.p != c.end) return 1;  // trailing bytes: not a lone ORSWOT blob
   return 0;
@@ -255,11 +300,11 @@ void clear_orswot_row(int64_t A, int64_t M, int64_t D, C* clock, int32_t* ids,
 // a fresh np.zeros alloc per chunk page-faults ~GBs and was the measured
 // e2e ingest collapse, PERF.md).  0 keeps the historical contract
 // (caller pre-zeroed the planes) and skips the memset pass.
-template <typename C>
+template <typename C, typename K = IntKeys>
 int64_t ingest_impl(const uint8_t* buf, const int64_t* offsets, int64_t n,
                     int64_t A, int64_t M, int64_t D, C* clock, int32_t* ids,
                     C* dots, int32_t* d_ids, C* d_clocks, uint8_t* status,
-                    int64_t clear) {
+                    int64_t clear, const K& keys = K{}) {
   int64_t bad = 0;
 #if defined(_OPENMP)
 #pragma omp parallel for schedule(dynamic, 1024) reduction(+ : bad)
@@ -270,7 +315,7 @@ int64_t ingest_impl(const uint8_t* buf, const int64_t* offsets, int64_t n,
                           dots + i * M * A, d_ids + i * D, d_clocks + i * D * A);
     int st = parse_one<C>(buf, offsets[i], offsets[i + 1], A, M, D,
                           clock + i * A, ids + i * M, dots + i * M * A,
-                          d_ids + i * D, d_clocks + i * D * A);
+                          d_ids + i * D, d_clocks + i * D * A, keys);
     status[i] = static_cast<uint8_t>(st);
     if (st != 0) {
       // leave the row pristine for the Python fallback / error report
@@ -322,6 +367,14 @@ struct Emitter {
     byte(kTagInt);
     uv(v << 1);
   }
+
+  void raw(const uint8_t* src, int64_t n) {  // an already-encoded value
+    if (p) {
+      std::memcpy(p, src, static_cast<size_t>(n));
+      p += n;
+    }
+    count += n;
+  }
 };
 
 inline int write_varint(uint64_t v, uint8_t* out) {
@@ -363,48 +416,75 @@ inline bool decimal_repr_less(uint64_t a, uint64_t b) {
   return la < lb;
 }
 
+// Key writers: how a dense actor column or member id goes back on the
+// wire, and the two orders serde gives keys.  ``IntEnc`` is the
+// identity universe; ``NamedEnc`` (the named codec, below) writes names
+// from a table.
+struct IntEnc {
+  void actor(Emitter& e, int64_t a) const {
+    e.tagged_nonneg(static_cast<uint64_t>(a));
+  }
+  // keys are 0x03 + varint(2a): shared tag, so encoded-bytes order is
+  // the varint-bytes order of 2a
+  bool actor_less(int64_t x, int64_t y) const {
+    return varint_bytes_less(static_cast<uint64_t>(x) << 1,
+                             static_cast<uint64_t>(y) << 1);
+  }
+  // ClockKey pair order: repr(actor), the decimal string for ints
+  bool actor_repr_less(int64_t x, int64_t y) const {
+    return decimal_repr_less(static_cast<uint64_t>(x),
+                             static_cast<uint64_t>(y));
+  }
+  void member(Emitter& e, int32_t m) const {
+    e.tagged_nonneg(static_cast<uint64_t>(static_cast<uint32_t>(m)));
+  }
+  bool member_less(int32_t x, int32_t y) const {
+    return varint_bytes_less(
+        static_cast<uint64_t>(static_cast<uint32_t>(x)) << 1,
+        static_cast<uint64_t>(static_cast<uint32_t>(y)) << 1);
+  }
+};
+
 // emit one vclock BODY (uv n + sorted pairs) from a dense counter row.
 // ``sorted=false`` skips the order work — the SIZE of the body is
 // order-invariant, so the counting pass never pays for sorts.
-template <typename C>
+template <typename C, typename K = IntEnc>
 void emit_clock_body(Emitter& e, const C* row, int64_t A,
-                     std::vector<int64_t>& idx, bool sorted = true) {
+                     std::vector<int64_t>& idx, bool sorted = true,
+                     const K& keys = K{}) {
   idx.clear();
   for (int64_t a = 0; a < A; ++a)
     if (row[a]) idx.push_back(a);
-  // keys are 0x03 + varint(2a): shared tag, so encoded-bytes order is
-  // the varint-bytes order of 2a
   if (sorted)
-    std::sort(idx.begin(), idx.end(), [](int64_t x, int64_t y) {
-      return varint_bytes_less(static_cast<uint64_t>(x) << 1,
-                               static_cast<uint64_t>(y) << 1);
+    std::sort(idx.begin(), idx.end(), [&](int64_t x, int64_t y) {
+      return keys.actor_less(x, y);
     });
   e.uv(static_cast<uint64_t>(idx.size()));
   for (int64_t a : idx) {
-    e.tagged_nonneg(static_cast<uint64_t>(a));
+    keys.actor(e, a);
     e.tagged_nonneg(static_cast<uint64_t>(row[a]));
   }
 }
 
 // the encoded clock-KEY tuple for a deferred group (0x08 uv k + pairs
 // as 2-tuples, pair order = decimal repr of the actor)
-template <typename C>
+template <typename C, typename K = IntEnc>
 void emit_clock_key(Emitter& e, const C* row, int64_t A,
-                    std::vector<int64_t>& idx, bool sorted = true) {
+                    std::vector<int64_t>& idx, bool sorted = true,
+                    const K& keys = K{}) {
   idx.clear();
   for (int64_t a = 0; a < A; ++a)
     if (row[a]) idx.push_back(a);
   if (sorted)
-    std::sort(idx.begin(), idx.end(), [](int64_t x, int64_t y) {
-      return decimal_repr_less(static_cast<uint64_t>(x),
-                               static_cast<uint64_t>(y));
+    std::sort(idx.begin(), idx.end(), [&](int64_t x, int64_t y) {
+      return keys.actor_repr_less(x, y);
     });
   e.byte(kTagTuple);
   e.uv(static_cast<uint64_t>(idx.size()));
   for (int64_t a : idx) {
     e.byte(kTagTuple);
     e.uv(2);
-    e.tagged_nonneg(static_cast<uint64_t>(a));
+    keys.actor(e, a);
     e.tagged_nonneg(static_cast<uint64_t>(row[a]));
   }
 }
@@ -413,10 +493,11 @@ void emit_clock_key(Emitter& e, const C* row, int64_t A,
 // rows by identical clock rows; each group is (encoded clock key,
 // sorted member blobs); groups sort by the encoded clock-key bytes.
 // D is small (a handful of rows), so the quadratic grouping is free.
-template <typename C>
+template <typename C, typename K = IntEnc>
 void emit_deferred_section(Emitter& e, const int32_t* d_ids,
                            const C* d_clocks, int64_t A, int64_t D,
-                           std::vector<int64_t>& scratch, bool sizing) {
+                           std::vector<int64_t>& scratch, bool sizing,
+                           const K& keys = K{}) {
   std::vector<int64_t> rows;
   for (int64_t r = 0; r < D; ++r)
     if (d_ids[r] != kEmpty) rows.push_back(r);
@@ -424,7 +505,7 @@ void emit_deferred_section(Emitter& e, const int32_t* d_ids,
   struct Group {
     const C* crow;                   // the witnessing clock's dense row
     std::vector<uint8_t> key;        // encoded clock-key tuple (write pass)
-    std::vector<int64_t> members;    // member values, deduped
+    std::vector<int32_t> members;    // member ids, deduped
   };
   std::vector<Group> groups;
   for (size_t i = 0; i < rows.size(); ++i) {
@@ -450,19 +531,17 @@ void emit_deferred_section(Emitter& e, const int32_t* d_ids,
     // rows never legitimately repeat a (clock, member) pair, but match
     // to_binary on any input); dedup changes the SIZE, so both passes
     // run it — the sort is its implementation, members lists are tiny
-    std::sort(g.members.begin(), g.members.end(), [](int64_t x, int64_t y) {
-      return varint_bytes_less(static_cast<uint64_t>(x) << 1,
-                               static_cast<uint64_t>(y) << 1);
-    });
+    std::sort(g.members.begin(), g.members.end(),
+              [&](int32_t x, int32_t y) { return keys.member_less(x, y); });
     g.members.erase(std::unique(g.members.begin(), g.members.end()),
                     g.members.end());
     if (!sizing) {
       // stage the encoded clock key for the cross-group sort
       Emitter cnt{nullptr};
-      emit_clock_key(cnt, g.crow, A, scratch);
+      emit_clock_key(cnt, g.crow, A, scratch, true, keys);
       g.key.resize(static_cast<size_t>(cnt.count));
       Emitter w{g.key.data()};
-      emit_clock_key(w, g.crow, A, scratch);
+      emit_clock_key(w, g.crow, A, scratch, true, keys);
     }
     groups.push_back(std::move(g));
   }
@@ -478,20 +557,19 @@ void emit_deferred_section(Emitter& e, const int32_t* d_ids,
   e.uv(static_cast<uint64_t>(groups.size()));
   for (const Group& g : groups) {
     if (sizing) {
-      emit_clock_key(e, g.crow, A, scratch, false);
+      emit_clock_key(e, g.crow, A, scratch, false, keys);
     } else {
       for (uint8_t b : g.key) e.byte(b);
     }
     e.uv(static_cast<uint64_t>(g.members.size()));
-    for (int64_t m : g.members)
-      e.tagged_nonneg(static_cast<uint64_t>(static_cast<uint32_t>(m)));
+    for (int32_t m : g.members) keys.member(e, m);
   }
 }
 
-template <typename C>
+template <typename C, typename K = IntEnc>
 int64_t encode_one(const C* clock, const int32_t* ids, const C* dots,
                    const int32_t* d_ids, const C* d_clocks, int64_t A,
-                   int64_t M, int64_t D, uint8_t* out) {
+                   int64_t M, int64_t D, uint8_t* out, const K& keys = K{}) {
   // out == nullptr is the counting pass: every blob's SIZE is
   // order-invariant, so the sorts (and group-key staging buffers) are
   // skipped there — the write pass alone pays for ordering
@@ -499,27 +577,25 @@ int64_t encode_one(const C* clock, const int32_t* ids, const C* dots,
   Emitter e{out};
   std::vector<int64_t> scratch;
   e.byte(kTagOrswot);
-  emit_clock_body(e, clock, A, scratch, !sizing);
+  emit_clock_body(e, clock, A, scratch, !sizing, keys);
 
-  // entries: member keys sorted by encoded bytes (0x03 + varint(2m))
+  // entries: member keys sorted by encoded bytes
   std::vector<int64_t> slots;
   for (int64_t s = 0; s < M; ++s)
     if (ids[s] != kEmpty) slots.push_back(s);
   if (!sizing)
     std::sort(slots.begin(), slots.end(), [&](int64_t x, int64_t y) {
-      return varint_bytes_less(
-          static_cast<uint64_t>(static_cast<uint32_t>(ids[x])) << 1,
-          static_cast<uint64_t>(static_cast<uint32_t>(ids[y])) << 1);
+      return keys.member_less(ids[x], ids[y]);
     });
   e.uv(static_cast<uint64_t>(slots.size()));
   for (int64_t s : slots) {
-    e.tagged_nonneg(static_cast<uint64_t>(static_cast<uint32_t>(ids[s])));
+    keys.member(e, ids[s]);
     e.byte(kTagVClock);
-    emit_clock_body(e, dots + s * A, A, scratch, !sizing);
+    emit_clock_body(e, dots + s * A, A, scratch, !sizing, keys);
   }
 
   // deferred section
-  emit_deferred_section(e, d_ids, d_clocks, A, D, scratch, sizing);
+  emit_deferred_section(e, d_ids, d_clocks, A, D, scratch, sizing, keys);
   return e.count;
 }
 
@@ -1687,4 +1763,421 @@ CRDT_MAP_MAP_MVREG_INGEST(u32, uint32_t)
 CRDT_MAP_MAP_MVREG_INGEST(u64, uint64_t)
 CRDT_MAP_MAP_MVREG_ENCODE(u32, uint32_t)
 CRDT_MAP_MAP_MVREG_ENCODE(u64, uint64_t)
+}  // extern "C"
+
+// ---- named ORSWOT codec ----------------------------------------------------
+//
+// Real stores key by name: Riak's set members are binaries, and the
+// actor of an update is the coordinating vnode's id.  The named codec
+// reads and writes the ORSWOT grammar above with every actor and member
+// key a serde str (0x05 uv(len) utf-8) or bytes (0x06 uv(len) raw)
+// value, and maps each key to its dense index through a NameTable: the
+// encoded bytes of every interned name, in id order, with a hash index
+// over them.  The Python Registry (crdt_tpu/utils/interning.py) stays
+// the truth: it appends its own names here before a call, and adopts
+// the names a call interned after it.
+//
+// Ingest runs in two passes.  The parallel pass only looks names up; a
+// blob holding a name the table lacks gets status 5 and an empty row.
+// The serial pass re-parses those blobs in blob order and appends each
+// unseen name where it is met — per registry the order Registry.intern
+// sees the names of from_binary(blob) handed to OrswotBatch.from_scalar
+// (set-clock actors, then entry members, entry dot actors, deferred
+// clock-key actors and deferred members, each in wire order; the one
+// difference: from_scalar takes the members buffered under one deferred
+// clock in Python set order, this pass in wire order).
+//
+// Egress writes each key from the table; clock pairs and entries sort
+// by encoded name bytes, deferred clock-key pairs by repr(actor) (a
+// rank the caller computes, vclock.py ClockKey).  A table is
+// append-only and guarded by a reader-writer lock: parses and encodes
+// read under a shared lock, appends take it alone, so a parse on one
+// thread may intern while another thread encodes.
+
+namespace {
+
+constexpr uint8_t kTagStr = 0x05;
+constexpr uint8_t kTagBytes = 0x06;
+constexpr int kNewName = 5;
+
+inline uint64_t hash_name(const uint8_t* p, size_t n) {
+  uint64_t h = 0x9E3779B97F4A7C15ull ^ (n * 0xC2B2AE3D27D4EB4Full);
+  while (n >= 8) {
+    uint64_t w;
+    std::memcpy(&w, p, 8);
+    h = (h ^ w) * 0xFF51AFD7ED558CCDull;
+    h ^= h >> 32;
+    p += 8;
+    n -= 8;
+  }
+  uint64_t w = 0;
+  std::memcpy(&w, p, n);
+  h = (h ^ w) * 0xC4CEB9FE1A85EC53ull;
+  return h ^ (h >> 29);
+}
+
+// strict UTF-8, as Python's decoder takes it (no overlongs, no
+// surrogates, nothing past U+10FFFF)
+inline bool valid_utf8(const uint8_t* s, size_t n) {
+  size_t i = 0;
+  while (i < n) {
+    const uint8_t c = s[i];
+    if (c < 0x80) {
+      ++i;
+      continue;
+    }
+    size_t k;
+    uint32_t cp;
+    if (c >= 0xC2 && c <= 0xDF) {
+      k = 1;
+      cp = c & 0x1F;
+    } else if (c >= 0xE0 && c <= 0xEF) {
+      k = 2;
+      cp = c & 0x0F;
+    } else if (c >= 0xF0 && c <= 0xF4) {
+      k = 3;
+      cp = c & 0x07;
+    } else {
+      return false;
+    }
+    if (n - i <= k) return false;
+    for (size_t j = 1; j <= k; ++j) {
+      const uint8_t b = s[i + j];
+      if ((b & 0xC0) != 0x80) return false;
+      cp = (cp << 6) | (b & 0x3F);
+    }
+    if (k == 2 && (cp < 0x800 || (cp >= 0xD800 && cp <= 0xDFFF))) return false;
+    if (k == 3 && (cp < 0x10000 || cp > 0x10FFFF)) return false;
+    i += k + 1;
+  }
+  return true;
+}
+
+struct NameTable {
+  explicit NameTable(int64_t cap) : capacity(cap), slots(64, -1) {}
+
+  mutable std::shared_mutex mu;
+  int64_t capacity;               // ids handed out stay below it
+  std::vector<uint8_t> bytes;     // encoded names back to back
+  std::vector<int64_t> offs{0};   // name i = bytes[offs[i], offs[i + 1])
+  std::vector<uint64_t> hashes;   // per id
+  std::vector<int32_t> slots;     // open addressing over ids, -1 empty
+
+  int64_t count() const { return static_cast<int64_t>(hashes.size()); }
+  const uint8_t* ptr(int64_t i) const { return bytes.data() + offs[i]; }
+  int64_t len(int64_t i) const { return offs[i + 1] - offs[i]; }
+
+  int64_t find(const uint8_t* k, size_t n, uint64_t h) const {
+    const size_t mask = slots.size() - 1;
+    for (size_t s = h & mask;; s = (s + 1) & mask) {
+      const int32_t id = slots[s];
+      if (id < 0) return -1;
+      if (hashes[id] == h && static_cast<size_t>(len(id)) == n &&
+          std::memcmp(ptr(id), k, n) == 0)
+        return id;
+    }
+  }
+
+  void place(int64_t id) {
+    const size_t mask = slots.size() - 1;
+    size_t s = hashes[id] & mask;
+    while (slots[s] >= 0) s = (s + 1) & mask;
+    slots[s] = static_cast<int32_t>(id);
+  }
+
+  int64_t append(const uint8_t* k, size_t n, uint64_t h) {
+    const int64_t id = count();
+    bytes.insert(bytes.end(), k, k + n);
+    offs.push_back(static_cast<int64_t>(bytes.size()));
+    hashes.push_back(h);
+    if (2 * static_cast<size_t>(id + 1) > slots.size()) {
+      slots.assign(slots.size() * 2, -1);
+      for (int64_t i = 0; i <= id; ++i) place(i);
+    } else {
+      place(id);
+    }
+    return id;
+  }
+};
+
+// both tables of a universe under one lock kind; a universe may hand
+// the same registry for actors and members, so that one is locked once
+template <typename Lock>
+struct TableLocks {
+  Lock a, m;
+  TableLocks(NameTable* actors, NameTable* members)
+      : a(actors->mu),
+        m(members == actors ? Lock() : Lock(members->mu)) {}
+};
+using ReadLocks = TableLocks<std::shared_lock<std::shared_mutex>>;
+using WriteLocks = TableLocks<std::unique_lock<std::shared_mutex>>;
+
+struct NamedKeys {
+  NameTable* actors;
+  NameTable* members;
+  bool intern;  // the serial pass: unseen names are appended
+
+  // one str/bytes key; ``*id`` past every index when the table is full
+  int name(Cursor& c, NameTable* t, int64_t* id) const {
+    const uint8_t* start = c.p;
+    if (c.p >= c.end || (*c.p != kTagStr && *c.p != kTagBytes)) return 1;
+    const bool is_str = *c.p == kTagStr;
+    ++c.p;
+    uint64_t n;
+    if (!c.uv(&n) || n > static_cast<uint64_t>(c.end - c.p)) return 1;
+    const uint8_t* raw = c.p;
+    c.p += n;
+    const size_t klen = static_cast<size_t>(c.p - start);
+    const uint64_t h = hash_name(start, klen);
+    *id = t->find(start, klen, h);
+    if (*id >= 0) return 0;
+    if (!intern) return kNewName;
+    if (is_str && !valid_utf8(raw, n)) return 1;  // from_binary raises
+    if (t->count() >= t->capacity) {
+      *id = std::numeric_limits<int64_t>::max();
+      return 0;
+    }
+    *id = t->append(start, klen, h);
+    return 0;
+  }
+
+  int actor(Cursor& c, uint64_t* out) const {
+    int64_t id;
+    if (int st = name(c, actors, &id)) return st;
+    *out = static_cast<uint64_t>(id);
+    return 0;
+  }
+
+  int member(Cursor& c, int32_t* out) const {
+    int64_t id;
+    if (int st = name(c, members, &id)) return st;
+    if (id > 0x7FFFFFFF) return 1;  // beyond int32 id space
+    *out = static_cast<int32_t>(id);
+    return 0;
+  }
+};
+
+// the parallel pass: names looked up, never appended (status 5 marks a
+// blob holding an unseen name); rows always cleared first
+template <typename C>
+int64_t named_ingest_impl(const uint8_t* buf, const int64_t* offsets,
+                          int64_t n, int64_t A, int64_t M, int64_t D,
+                          NameTable* actors, NameTable* members, C* clock,
+                          int32_t* ids, C* dots, int32_t* d_ids, C* d_clocks,
+                          uint8_t* status) {
+  ReadLocks locks(actors, members);
+  return ingest_impl<C>(buf, offsets, n, A, M, D, clock, ids, dots, d_ids,
+                        d_clocks, status, 1,
+                        NamedKeys{actors, members, false});
+}
+
+// the serial pass over blobs ``idx[0..k)`` (ascending), interning unseen
+// names in order.  Stops after the first blob that comes out status 1,
+// so the caller can decode it in Python (which interns its remaining
+// names) before any later blob interns; returns the blobs taken.
+template <typename C>
+int64_t named_intern_impl(const uint8_t* buf, const int64_t* offsets,
+                          const int64_t* idx, int64_t k, int64_t A, int64_t M,
+                          int64_t D, NameTable* actors, NameTable* members,
+                          C* clock, int32_t* ids, C* dots, int32_t* d_ids,
+                          C* d_clocks, uint8_t* status) {
+  WriteLocks locks(actors, members);
+  const NamedKeys keys{actors, members, true};
+  for (int64_t j = 0; j < k; ++j) {
+    const int64_t i = idx[j];
+    C* cl = clock + i * A;
+    int32_t* id = ids + i * M;
+    C* dt = dots + i * M * A;
+    int32_t* di = d_ids + i * D;
+    C* dc = d_clocks + i * D * A;
+    clear_orswot_row<C>(A, M, D, cl, id, dt, di, dc);
+    int st = parse_one<C>(buf, offsets[i], offsets[i + 1], A, M, D, cl, id,
+                          dt, di, dc, keys);
+    status[i] = static_cast<uint8_t>(st);
+    if (st != 0) clear_orswot_row<C>(A, M, D, cl, id, dt, di, dc);
+    if (st == 1) return j + 1;
+  }
+  return k;
+}
+
+struct NamedEnc {
+  const NameTable* actors;
+  const NameTable* members;
+  const int32_t* byte_rank;  // actor column -> rank of its encoded name
+  const int32_t* repr_rank;  // actor column -> rank of repr(name)
+
+  void actor(Emitter& e, int64_t a) const {
+    e.raw(actors->ptr(a), actors->len(a));
+  }
+  bool actor_less(int64_t x, int64_t y) const {
+    return byte_rank[x] < byte_rank[y];
+  }
+  bool actor_repr_less(int64_t x, int64_t y) const {
+    return repr_rank[x] < repr_rank[y];
+  }
+  void member(Emitter& e, int32_t m) const {
+    e.raw(members->ptr(m), members->len(m));
+  }
+  bool member_less(int32_t x, int32_t y) const {
+    return span_less(members->ptr(x), static_cast<size_t>(members->len(x)),
+                     members->ptr(y), static_cast<size_t>(members->len(y)));
+  }
+};
+
+// every key of one object row has a name: no counter in an actor column
+// at or past ``n_act``, every live member id below ``n_mem``
+template <typename C>
+bool named_row_ok(const C* clock, const int32_t* ids, const C* dots,
+                  const int32_t* d_ids, const C* d_clocks, int64_t A,
+                  int64_t M, int64_t D, int64_t n_act, int64_t n_mem) {
+  for (int64_t a = n_act; a < A; ++a) {
+    if (clock[a]) return false;
+    for (int64_t s = 0; s < M; ++s)
+      if (ids[s] != kEmpty && dots[s * A + a]) return false;
+    for (int64_t r = 0; r < D; ++r)
+      if (d_ids[r] != kEmpty && d_clocks[r * A + a]) return false;
+  }
+  for (int64_t s = 0; s < M; ++s)
+    if (ids[s] < kEmpty || ids[s] >= n_mem) return false;
+  for (int64_t r = 0; r < D; ++r)
+    if (d_ids[r] < kEmpty || d_ids[r] >= n_mem) return false;
+  return true;
+}
+
+// the two-pass encode of encode_impl with names; the sizing pass
+// returns how many rows hold a key without a name (nothing is written
+// then: the caller takes the Python encoder)
+template <typename C>
+int64_t named_encode_impl(const C* clock, const int32_t* ids, const C* dots,
+                          const int32_t* d_ids, const C* d_clocks, int64_t n,
+                          int64_t A, int64_t M, int64_t D,
+                          NameTable* actors, NameTable* members,
+                          const int32_t* repr_rank, int64_t* offsets,
+                          uint8_t* buf) {
+  ReadLocks locks(actors, members);
+  const int64_t n_act = std::min(actors->count(), A);
+  const int64_t n_mem = members->count();
+  std::vector<int32_t> order(static_cast<size_t>(n_act));
+  std::iota(order.begin(), order.end(), 0);
+  std::sort(order.begin(), order.end(), [&](int32_t x, int32_t y) {
+    return span_less(actors->ptr(x), static_cast<size_t>(actors->len(x)),
+                     actors->ptr(y), static_cast<size_t>(actors->len(y)));
+  });
+  std::vector<int32_t> byte_rank(static_cast<size_t>(A),
+                                 std::numeric_limits<int32_t>::max());
+  for (int64_t r = 0; r < n_act; ++r) byte_rank[order[r]] = static_cast<int32_t>(r);
+  const NamedEnc keys{actors, members, byte_rank.data(), repr_rank};
+  if (buf == nullptr) {
+    int64_t bad = 0;
+#if defined(_OPENMP)
+#pragma omp parallel for schedule(dynamic, 1024) reduction(+ : bad)
+#endif
+    for (int64_t i = 0; i < n; ++i) {
+      const C* cl = clock + i * A;
+      const int32_t* id = ids + i * M;
+      const C* dt = dots + i * M * A;
+      const int32_t* di = d_ids + i * D;
+      const C* dc = d_clocks + i * D * A;
+      if (!named_row_ok<C>(cl, id, dt, di, dc, A, M, D, n_act, n_mem)) {
+        offsets[i + 1] = 0;
+        ++bad;
+        continue;
+      }
+      offsets[i + 1] = encode_one<C>(cl, id, dt, di, dc, A, M, D, nullptr,
+                                     keys);
+    }
+    return bad;
+  }
+#if defined(_OPENMP)
+#pragma omp parallel for schedule(dynamic, 1024)
+#endif
+  for (int64_t i = 0; i < n; ++i)
+    encode_one<C>(clock + i * A, ids + i * M, dots + i * M * A,
+                  d_ids + i * D, d_clocks + i * D * A, A, M, D,
+                  buf + offsets[i], keys);
+  return 0;
+}
+
+}  // namespace
+
+extern "C" {
+
+void* names_new(int64_t capacity) { return new NameTable(capacity); }
+
+void names_free(void* t) { delete static_cast<NameTable*>(t); }
+
+int64_t names_count(void* t) {
+  auto* tab = static_cast<NameTable*>(t);
+  std::shared_lock<std::shared_mutex> lock(tab->mu);
+  return tab->count();
+}
+
+// append ``n`` encoded names (buf[offsets[i], offsets[i + 1])) in order;
+// returns the new count
+int64_t names_append(void* t, const uint8_t* buf, const int64_t* offsets,
+                     int64_t n) {
+  auto* tab = static_cast<NameTable*>(t);
+  std::unique_lock<std::shared_mutex> lock(tab->mu);
+  for (int64_t i = 0; i < n; ++i) {
+    const uint8_t* k = buf + offsets[i];
+    const size_t len = static_cast<size_t>(offsets[i + 1] - offsets[i]);
+    tab->append(k, len, hash_name(k, len));
+  }
+  return tab->count();
+}
+
+// bytes of names [start, end)
+int64_t names_span(void* t, int64_t start, int64_t end) {
+  auto* tab = static_cast<NameTable*>(t);
+  std::shared_lock<std::shared_mutex> lock(tab->mu);
+  return tab->offs[end] - tab->offs[start];
+}
+
+// names [start, end) back to back into ``out``; ``offsets`` gets the
+// end - start + 1 boundaries relative to ``out``
+void names_read(void* t, int64_t start, int64_t end, uint8_t* out,
+                int64_t* offsets) {
+  auto* tab = static_cast<NameTable*>(t);
+  std::shared_lock<std::shared_mutex> lock(tab->mu);
+  const int64_t base = tab->offs[start];
+  std::memcpy(out, tab->bytes.data() + base,
+              static_cast<size_t>(tab->offs[end] - base));
+  for (int64_t i = start; i <= end; ++i) offsets[i - start] = tab->offs[i] - base;
+}
+
+#define CRDT_ORSWOT_NAMED(SUF, TYPE)                                          \
+  int64_t orswot_ingest_named_##SUF(                                          \
+      const uint8_t* buf, const int64_t* offsets, int64_t n, int64_t A,       \
+      int64_t M, int64_t D, void* actors, void* members, TYPE* clock,         \
+      int32_t* ids, TYPE* dots, int32_t* d_ids, TYPE* d_clocks,               \
+      uint8_t* status) {                                                      \
+    return named_ingest_impl<TYPE>(                                           \
+        buf, offsets, n, A, M, D, static_cast<NameTable*>(actors),            \
+        static_cast<NameTable*>(members), clock, ids, dots, d_ids, d_clocks,  \
+        status);                                                              \
+  }                                                                           \
+  int64_t orswot_intern_named_##SUF(                                          \
+      const uint8_t* buf, const int64_t* offsets, const int64_t* idx,         \
+      int64_t k, int64_t A, int64_t M, int64_t D, void* actors,               \
+      void* members, TYPE* clock, int32_t* ids, TYPE* dots, int32_t* d_ids,   \
+      TYPE* d_clocks, uint8_t* status) {                                      \
+    return named_intern_impl<TYPE>(                                           \
+        buf, offsets, idx, k, A, M, D, static_cast<NameTable*>(actors),       \
+        static_cast<NameTable*>(members), clock, ids, dots, d_ids, d_clocks,  \
+        status);                                                              \
+  }                                                                           \
+  int64_t orswot_encode_named_##SUF(                                          \
+      const TYPE* clock, const int32_t* ids, const TYPE* dots,                \
+      const int32_t* d_ids, const TYPE* d_clocks, int64_t n, int64_t A,       \
+      int64_t M, int64_t D, void* actors, void* members,                      \
+      const int32_t* repr_rank, int64_t* offsets, uint8_t* buf) {             \
+    return named_encode_impl<TYPE>(                                           \
+        clock, ids, dots, d_ids, d_clocks, n, A, M, D,                        \
+        static_cast<NameTable*>(actors), static_cast<NameTable*>(members),    \
+        repr_rank, offsets, buf);                                             \
+  }
+
+CRDT_ORSWOT_NAMED(u32, uint32_t)
+CRDT_ORSWOT_NAMED(u64, uint64_t)
+
 }  // extern "C"

@@ -78,9 +78,12 @@ NAMESPACE: tuple[NameSpec, ...] = (
              "blobs that took the native path, per <type>.<direction>"),
     NameSpec("wire.*.*.fallback", "counter",
              "blobs that fell back to the Python codec"),
+    NameSpec("wire.names.interned", "counter",
+             "names (actors and members) first interned by a native "
+             "named ORSWOT ingest, adopted into the universe's registries"),
     NameSpec("wire.*.*.fallback_reason.*", "counter",
-             "fallback blobs by reason (no_engine/non_identity/grammar/"
-             "overflow_zigzag)"),
+             "fallback blobs by reason (no_engine/non_identity/key_type/"
+             "unnamed_id/grammar/overflow_zigzag)"),
     # -- sync protocol frames (utils/tracing.record_sync + sync/delta) ------
     NameSpec("wire.sync.*.bytes", "counter",
              "bytes on the wire per sync leg (digest/delta/full)"),
@@ -507,6 +510,9 @@ NAMESPACE: tuple[NameSpec, ...] = (
              "a round's fixpoint planes copied to the host (span)"),
     NameSpec("wireloop.encode", "histogram",
              "a round's fixpoint encoded as wire blobs (span)"),
+    NameSpec("wireloop.intern", "histogram",
+             "the names a native named ingest interned appended to the "
+             "universe's registries (span, only when there are any)"),
     # -- executor (parallel/executor.py) -------------------------------------
     NameSpec("executor.recovery.*", "counter",
              "recoveries by kind (regrow/transient_retry) — disjoint from "

@@ -687,8 +687,9 @@ class OrswotDeltaApplier:
     forever.
 
     Falls back to the jnp path (``from_wire`` + batch merge +
-    ``.at[ids].set``) when the native engine or identity universe is
-    unavailable; results are identical either way (the parity tests pin
+    ``.at[ids].set``) when the native engine is unavailable or the
+    universe's keys are neither identity ints nor ``str`` / ``bytes``
+    names; results are identical either way (the parity tests pin
     this)."""
 
     def __init__(self, universe):
@@ -733,7 +734,9 @@ class OrswotDeltaApplier:
         import jax.numpy as jnp
 
         from ..batch.orswot_batch import OrswotBatch
-        from ..batch.wirebulk import orswot_planes_from_wire, probe_engine
+        from ..batch.wirebulk import (
+            named_engine, orswot_planes_from_wire, probe_engine,
+        )
         from ..config import counter_dtype
         from ..error import raise_for_overflow
 
@@ -750,10 +753,12 @@ class OrswotDeltaApplier:
             raise SyncProtocolError(
                 f"delta apply: object id outside fleet [0, {n})"
             )
-        engine = probe_engine(
-            self.universe, "orswot_merge", counter_dtype(self.universe.config)
-        )
         cfg = self.universe.config
+        dt = counter_dtype(cfg)
+        if self.universe.is_identity:
+            engine = probe_engine(self.universe, "orswot_merge", dt)
+        else:
+            engine = named_engine(self.universe, "orswot_merge", dt)[0]
         if engine is not None and (
             batch.member_capacity != cfg.member_capacity
             or batch.deferred_capacity != cfg.deferred_capacity

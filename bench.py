@@ -867,7 +867,7 @@ def bench_e2e_wire():
     # and buffer pools exist OUTSIDE the timed regions (the serial
     # comparator borrows the loop's fold/egress primitives — one
     # implementation under test — so its buffers must exist first)
-    loop._ensure_buffers(chunk)
+    loop._ensure_buffers(chunk, r)
     serial_loop(1)
     warm = loop.run([rep_blobs], overlap=True)
 
@@ -918,12 +918,14 @@ def bench_e2e_wire():
     # reference the e2e ingest rate is judged against (done-bar: e2e
     # ingest within ~2x of the microbench on IDENTICAL shapes; the old
     # 160x gap was vs a 2-member/A=16 synthetic microbench)
+    # (a dense plane set of its own, warmed by one untimed parse: the
+    # device fold stages compact cells, not planes)
     from crdt_tpu.batch.wirebulk import orswot_planes_from_wire
 
+    probe_out = loop._plane_set(chunk)
+    orswot_planes_from_wire(rep_blobs[0], uni, out=probe_out)
     t0 = time.perf_counter()
-    probe_planes = orswot_planes_from_wire(
-        rep_blobs[0], uni, out=loop._staging[0] if loop._staging else None
-    )
+    probe_planes = orswot_planes_from_wire(rep_blobs[0], uni, out=probe_out)
     t_probe = max(time.perf_counter() - t0, 1e-9)
     if probe_planes is not None:
         # None = no native fast path at all — a microsecond no-op whose

@@ -1129,6 +1129,12 @@ MANIFEST: tuple = (
                    "snapshot/import inverse of device_compact; the "
                    "object count is a baked static"),
                build=lambda: _build_device_expand()),
+    KernelSpec("batch.orswot.densify_cells", _OB, "_densify_cells",
+               determinism="integer-lattice",
+               sharding=host_only(
+                   "the wire loop's per-fleet ingest: one fleet's cells "
+                   "scatter into its whole flat plane space"),
+               build=lambda: _build_densify_cells()),
     KernelSpec("batch.orswot.merge", _OB, "_merge",
                sharding=pointwise(),
                build=_b_orswot_merge()),
@@ -1493,6 +1499,23 @@ def _build_device_expand():
             rung=f"A{a}.M{m}.D{d}",
             fn=functools.partial(fn, n=LADDER_N, a=a, m=m, d=d),
             args=(cells,), key=(LADDER_N, a, m, d)))
+    return cases
+
+
+def _build_densify_cells():
+    import functools
+
+    from ..batch import orswot_batch as ob
+
+    fn = _unjit(ob._densify_cells)
+    cases = []
+    for (a, m, d) in LADDER:
+        args = (_mat((LADDER_N, m), "int32"), _mat((LADDER_N, d), "int32"),
+                _vec(LADDER_B, "int32"), _vec(LADDER_B, _clock_dt()))
+        cases.append(TraceCase(
+            rung=f"A{a}.M{m}.D{d}",
+            fn=functools.partial(fn, a=a, m=m, d=d),
+            args=args, key=(LADDER_N, a, m, d)))
     return cases
 
 

@@ -167,6 +167,25 @@ def _device_expand(cells, n, a, m, d):
     )
 
 
+@observed_kernel("batch.orswot.densify_cells")
+@functools.partial(jax.jit, static_argnames=("a", "m", "d"))
+def _densify_cells(ids, d_ids, cell_idx, cell_val, a, m, d):
+    """Dense planes from one fleet's compact cells
+    (:class:`~crdt_tpu.batch.wirebulk.OrswotCells`) ON DEVICE, so the
+    wire loop ships a fleet as what it holds instead of dense planes:
+    the id rows pass through, and the counters max-scatter into the
+    plane-major flat space ``[clock n*a | dots n*m*a | d_clocks n*d*a]``
+    that is then cut into the three counter planes.  Cells are unique,
+    so ``max`` is assignment, and padding cells (index 0, value 0) are
+    no-ops."""
+    n = ids.shape[0]
+    nc, nd = n * a, n * m * a
+    flat = jnp.zeros((n * (1 + m + d) * a,), cell_val.dtype)
+    flat = flat.at[cell_idx].max(cell_val)
+    return (flat[:nc].reshape(n, a), ids, flat[nc:nc + nd].reshape(n, m, a),
+            d_ids, flat[nc + nd:].reshape(n, d, a))
+
+
 def _build_planes(n, cfg, clock_cells, entry_cells, dot_cells, dref_cells,
                   dclk_cells, via_device=None, join_counters=False):
     """Shared ingest tail: scatter validated coordinate groups into the

@@ -88,10 +88,10 @@ def concat_blobs(blobs: Sequence[bytes]):
     n = len(blobs)
     buf = b"".join(blobs)
     offsets = np.zeros(n + 1, dtype=np.int64)
-    np.cumsum(
-        np.fromiter((len(b) for b in blobs), dtype=np.int64, count=n),
-        out=offsets[1:],
-    )
+    # map(len) rather than a generator: half the time under the
+    # interpreter lock, which a wire loop's main thread waits on
+    np.cumsum(np.fromiter(map(len, blobs), dtype=np.int64, count=n),
+              out=offsets[1:])
     return buf, offsets
 
 
@@ -254,10 +254,10 @@ def adopt_interned(universe) -> int:
     return new
 
 
-def _scalar_rows(blobs, idx, universe, planes) -> None:
+def _scalar_rows(blobs, idx, universe, planes, rows=None) -> None:
     """Decode blobs ``idx`` with the Python codec and splice their rows
-    into ``planes`` (raises exactly where the scalar path would, with
-    the caller's blob indices)."""
+    into ``planes`` at ``rows`` (default ``idx``); raises exactly where
+    the scalar path would, with the caller's blob indices."""
     import numpy as np
 
     from ..utils.serde import from_binary
@@ -279,14 +279,14 @@ def _scalar_rows(blobs, idx, universe, planes) -> None:
         except TypeError:  # an exception type with arguments of its own
             raise e from None
         raise err from None
-    rows = np.asarray(idx, dtype=np.int64)
+    rows = np.asarray(idx if rows is None else rows, dtype=np.int64)
     for dst, src in zip(planes, (sub.clock, sub.ids, sub.dots, sub.d_ids,
                                  sub.d_clocks)):
         dst[rows] = np.asarray(src)
 
 
 def _intern_pending(blobs, buf, offsets, pending, universe, planes,
-                    status, engine) -> list:
+                    status, engine, rows=None) -> list:
     """The serial pass of the named ingest over blobs ``pending``
     (status 1 or 5 after the parallel pass, ascending): each is parsed
     again natively, interning its unseen names in blob order; one the
@@ -294,11 +294,14 @@ def _intern_pending(blobs, buf, offsets, pending, universe, planes,
     come out as ``Registry.intern`` would hand them out to
     ``from_scalar([from_binary(b) for b in blobs])`` (which takes the
     unseen members buffered under one deferred clock in set order, this
-    pass in wire order).  Returns the blob indices the Python codec
-    decoded."""
+    pass in wire order).  ``rows``: where ``pending``'s blobs sit in
+    ``buf`` / ``offsets``, ``planes`` and ``status`` when those hold
+    only them (default: ``pending`` itself, the whole fleet's).  Returns
+    the blob indices the Python codec decoded."""
     fallback: list = []
     regs = _registries(universe)
     pending = [int(i) for i in pending]
+    rows = pending if rows is None else [int(j) for j in rows]
     with regs[0].lock, regs[-1].lock:
         try:
             pos = 0
@@ -308,18 +311,20 @@ def _intern_pending(blobs, buf, offsets, pending, universe, planes,
                 if None in tables:
                     # the Python codec interned a value that is not a
                     # name: the rest decode in Python, in order
-                    _scalar_rows(blobs, pending[pos:], universe, planes)
-                    status[pending[pos:]] = 0
+                    _scalar_rows(blobs, pending[pos:], universe, planes,
+                                 rows[pos:])
+                    status[rows[pos:]] = 0
                     fallback.extend(pending[pos:])
                     break
                 pos += engine.orswot_intern_named(
-                    buf, offsets, pending[pos:], planes, status, *tables)
+                    buf, offsets, rows[pos:], planes, status, *tables)
                 adopt_interned(universe)
-                last = pending[pos - 1]
+                last = rows[pos - 1]
                 if status[last] == 1:
-                    _scalar_rows(blobs, [last], universe, planes)
+                    _scalar_rows(blobs, [pending[pos - 1]], universe, planes,
+                                 [last])
                     status[last] = 0
-                    fallback.append(last)
+                    fallback.append(pending[pos - 1])
         finally:
             adopt_interned(universe)
     return fallback
@@ -420,6 +425,172 @@ def orswot_planes_from_wire(blobs, universe, out=None):
     record_wire("orswot", "from_wire", native=len(blobs) - len(fb),
                 fallback=len(fb), reason="grammar")
     return tuple(planes)
+
+
+class OrswotCells:
+    """One fleet of ``n`` ORSWOT objects as compact cells on the host,
+    reused from parse to parse: the member and deferred id rows dense
+    (``ids[n, M]``, ``d_ids[n, D]``), and each nonzero counter one cell,
+    ``idx[j]`` its flat index into the plane-major space
+    ``[clock n*A | dots n*M*A | d_clocks n*D*A]`` and ``val[j]`` the
+    counter, for ``j < count``; a fleet whose flat space outgrows the
+    int32 index is refused, to be folded in slices.  The cell columns
+    have a power-of-two
+    length and grow only when a fleet holds more cells, so a warm set
+    allocates nothing.  ``orswot_batch._densify_cells`` turns
+    :meth:`padded` back into dense planes on the device."""
+
+    CELLS_PER_OBJECT = 16  # first sizing; the ★ fleet holds ≈ 13
+
+    def __init__(self, n: int, cfg):
+        import numpy as np
+
+        from ..config import counter_dtype
+        from .orswot_batch import _next_pow2
+
+        a, m, d = cfg.num_actors, cfg.member_capacity, cfg.deferred_capacity
+        per_object = (1 + m + d) * a
+        if n * per_object > 2**31 - 1:
+            raise ValueError(
+                f"{n} objects of {per_object} counters each outgrow the "
+                "int32 cell index; fold them in slices of at most "
+                f"{(2**31 - 1) // per_object} objects")
+        self.shape = (n, a, m, d)
+        self.ids = np.full((n, m), -1, dtype=np.int32)
+        self.d_ids = np.full((n, d), -1, dtype=np.int32)
+        self.status = np.zeros(n, dtype=np.uint8)
+        cap = _next_pow2(n * self.CELLS_PER_OBJECT)
+        self.idx = np.zeros(cap, dtype=np.int32)
+        self.val = np.zeros(cap, dtype=counter_dtype(cfg))
+        self.count = 0
+
+    def reserve(self, need: int) -> None:
+        """Grow the cell columns to hold ``need`` cells, keeping the
+        first ``count``."""
+        import numpy as np
+
+        from .orswot_batch import _next_pow2
+
+        if need <= self.idx.shape[0]:
+            return
+        cap = _next_pow2(need)
+        for name in ("idx", "val"):
+            old = getattr(self, name)
+            new = np.zeros(cap, dtype=old.dtype)
+            new[:self.count] = old[:self.count]
+            setattr(self, name, new)
+
+    def add_rows(self, objs, planes) -> None:
+        """Write objects ``objs`` from dense ``planes`` whose row ``j``
+        is object ``objs[j]`` (rows the native parse left empty)."""
+        import numpy as np
+
+        n, a, m, d = self.shape
+        objs = np.asarray(objs, dtype=np.int64)
+        clock, ids, dots, d_ids, d_clocks = (np.asarray(p) for p in planes)
+        self.ids[objs] = ids
+        self.d_ids[objs] = d_ids
+        j, c = np.nonzero(clock)
+        parts = [(objs[j] * a + c, clock[j, c])]
+        j, s, c = np.nonzero(dots)
+        parts.append((n * a + (objs[j] * m + s) * a + c, dots[j, s, c]))
+        j, r, c = np.nonzero(d_clocks)
+        parts.append((n * a * (1 + m) + (objs[j] * d + r) * a + c,
+                      d_clocks[j, r, c]))
+        self.reserve(self.count + sum(len(i) for i, _ in parts))
+        for i, v in parts:
+            end = self.count + len(i)
+            self.idx[self.count:end] = i
+            self.val[self.count:end] = v
+            self.count = end
+
+    def padded(self) -> tuple:
+        """``(ids, d_ids, idx, val)`` with the cell columns cut to the
+        next power of two of ``count``, padded with cells (0, 0), which
+        a max-scatter leaves as they are: the device program's shapes
+        take a few sizes only."""
+        from .orswot_batch import _next_pow2
+
+        k = self.count
+        p = _next_pow2(k)
+        self.idx[k:p] = 0
+        self.val[k:p] = 0
+        return self.ids, self.d_ids, self.idx[:p], self.val[:p]
+
+
+def orswot_cells_from_wire(blobs, universe, cells: OrswotCells) -> OrswotCells:
+    """Parse ``blobs`` into the compact ``cells`` of
+    :class:`OrswotCells` — the device fold's ingest, with the status
+    triage of :func:`orswot_planes_from_wire` and the same states.
+
+    Identity and named universes take the native cell parse (one pass,
+    its output columns grown only when a fleet overflows them); blobs it
+    refuses, and a named fleet's blobs holding unseen names, are decoded
+    as :func:`orswot_planes_from_wire` decodes them, into a small dense
+    plane set of those rows only, whose cells are then appended.  Any
+    other universe (or no engine) decodes the whole fleet in Python the
+    same way.  Hard statuses raise ``WireFormatError`` with the caller's
+    blob index.  Counted under ``wire.orswot.from_wire``."""
+    import numpy as np
+
+    from ..config import counter_dtype
+    from ..utils.serde import from_binary
+    from .orswot_batch import OrswotBatch, _np_planes
+
+    cfg = universe.config
+    dt = counter_dtype(cfg)
+    a, m, d = cfg.num_actors, cfg.member_capacity, cfg.deferred_capacity
+    n = len(blobs)
+    cells.count = 0
+    tables = None
+    if universe.is_identity:
+        engine = probe_engine(universe, "orswot_ingest_cells", dt)
+        reason = "no_engine"
+    else:
+        engine, tables, reason = named_engine(universe,
+                                              "orswot_ingest_cells", dt)
+    if engine is None:
+        record_wire("orswot", "from_wire", fallback=n, reason=reason)
+        sub = OrswotBatch.from_scalar([from_binary(b) for b in blobs],
+                                      universe, via_device=False)
+        cells.add_rows(np.arange(n), (sub.clock, sub.ids, sub.dots,
+                                      sub.d_ids, sub.d_clocks))
+        return cells
+    buf, offsets = concat_blobs(blobs)
+    while True:
+        k = engine.orswot_ingest_cells(
+            buf, offsets, a, m, d, cells.ids, cells.d_ids, cells.idx,
+            cells.val, cells.status, tables)
+        if k <= cells.idx.shape[0]:
+            break
+        cells.reserve(k)
+    cells.count = k
+    status = cells.status
+    if universe.is_identity:
+        _raise_hard_status(status, cfg, "the identity registry range "
+                           f"[0, {cfg.num_actors})")
+        fb = np.nonzero(status == 1)[0].tolist()
+        if fb:
+            small = _np_planes(len(fb), cfg)
+            _scalar_rows(blobs, fb, universe, small, np.arange(len(fb)))
+            cells.add_rows(fb, small)
+    else:
+        fb = []
+        pending = np.nonzero((status == 1) | (status == 5))[0]
+        if pending.size:
+            sub_buf, sub_offsets = concat_blobs([blobs[i] for i in pending])
+            small = _np_planes(pending.size, cfg)
+            sub_status = status[pending]
+            fb = _intern_pending(blobs, sub_buf, sub_offsets, pending,
+                                 universe, small, sub_status, engine,
+                                 rows=np.arange(pending.size))
+            status[pending] = sub_status
+            cells.add_rows(pending, small)
+        _raise_hard_status(status, cfg, f"the {cfg.num_actors} actor "
+                           "columns (actor registry full)")
+    record_wire("orswot", "from_wire", native=n - len(fb),
+                fallback=len(fb), reason="grammar")
+    return cells
 
 
 def _repr_rank(universe, width: int):

@@ -18,11 +18,18 @@ pipeline").
 
 :class:`PipelinedWireLoop` rebuilds the loop around that finding:
 
-* **Staging-buffer reuse** — a small pool of preallocated plane sets
-  (default 3: one being parsed into, up to two held as fold inputs);
-  the native parser clears each object's rows itself
-  (``engine.orswot_ingest_wire(..., out=...)``), so no allocation ever
-  happens in steady state.
+* **Staging-buffer reuse** — a pool of preallocated staging sets,
+  sized at first use and reused, so no allocation happens in steady
+  state.  The native CPU fold parses into dense plane sets (default 3:
+  one being parsed into, up to two held as fold inputs; the native
+  parser clears each object's rows itself,
+  ``engine.orswot_ingest_wire(..., out=...)``).  The device fold parses
+  into compact cell sets (:class:`~crdt_tpu.batch.wirebulk.
+  OrswotCells`: id rows plus the nonzero counters, ≈ 24× smaller than
+  the planes at the ★ fleet's sparsity), ships those and densifies them
+  on the device; at that size the pool holds a whole round ahead
+  (``r + 1`` sets), so the next round's parse runs under this round's
+  fetch and encode.
 * **Parse/fold overlap** — a background thread parses fleet ``k+1``
   into a free staging set while the main thread folds fleet ``k``
   (the ctypes call into the OpenMP parser releases the GIL, so the
@@ -44,7 +51,12 @@ pipeline").
   profiler.  With tracing on, each host leg is a ``wireloop.*`` span
   (parse, wait_parsed, put, dispatch, wait, fetch, encode), on the
   profiler's clock when a trace is captured; ``wireloop.intern`` marks
-  a named parse adopting the names it interned.
+  a named parse adopting the names it interned.  Always-on counters say
+  how fleets reached the fold: ``wireloop.put.compact`` (as cells,
+  densified on the device), ``wireloop.put.dense`` (as dense planes:
+  every fleet of the native fold, which merges host planes and puts
+  nothing), and ``wireloop.put.bytes`` (host bytes handed to
+  ``device_put``).
 
 ``bench_e2e_wire`` (bench.py) and ``examples/anti_entropy.py`` drive
 this one implementation.
@@ -117,7 +129,10 @@ class PipelinedWireLoop:
 
     ``fold_path``: ``"native"`` (C++ row kernels, the CPU best engine),
     ``"jnp"`` (jitted device merge, async dispatch), or None to pick
-    native when available on a CPU backend, jnp otherwise.
+    native when available on a CPU backend, jnp otherwise.  The jnp fold
+    stages fleets as compact cells and densifies them on the device;
+    ``staging_sets`` is the pool's floor, which the compact pool raises
+    to a round ahead (``r + 1``).
     """
 
     def __init__(self, universe: Universe, *, fold_path: Optional[str] = None,
@@ -130,7 +145,7 @@ class PipelinedWireLoop:
         # a fold wait on the parser above this leaves a wireloop.stall
         # event in the flight recorder (0 disables the event, not the wait)
         self.stall_threshold_s = stall_threshold_s
-        self._staging: list[tuple] = []
+        self._staging: list = []
         self._pingpong: list[tuple] = []
         self._n: Optional[int] = None
         import jax
@@ -166,24 +181,36 @@ class PipelinedWireLoop:
             np.zeros((n, d, a), dtype=dt),
         )
 
-    def _ensure_buffers(self, n: int) -> None:
-        if self._n == n:
-            return
-        self._n = n
-        self._staging = [self._plane_set(n) for _ in range(self._staging_sets)]
-        self._pingpong = (
-            [self._plane_set(n) for _ in range(2)]
-            if self.fold_path == "native" else []
-        )
+    def _ensure_buffers(self, n: int, r: int) -> None:
+        """Staging sets for fleets of ``n`` objects, rounds of ``r``:
+        the pool only grows while ``n`` holds, so sets once handed out
+        are never reallocated."""
+        from .wirebulk import OrswotCells
+
+        compact = self.fold_path == "jnp"
+        want = max(self._staging_sets, r + 1) if compact \
+            else self._staging_sets
+        if self._n != n:
+            self._n = n
+            self._staging = []
+            self._pingpong = [] if compact else \
+                [self._plane_set(n) for _ in range(2)]
+        while len(self._staging) < want:
+            self._staging.append(OrswotCells(n, self.cfg) if compact
+                                 else self._plane_set(n))
 
     # -- stages --------------------------------------------------------------
 
-    def _parse_into(self, blobs: Sequence[bytes], staging: tuple) -> None:
-        """Decode ``blobs`` into the ``staging`` plane set (native fast
-        path with per-blob triage; full Python route when the fast path
-        does not apply)."""
-        from .wirebulk import orswot_planes_from_wire
+    def _parse_into(self, blobs: Sequence[bytes], staging) -> None:
+        """Decode ``blobs`` into the ``staging`` set: compact cells on
+        the jnp fold, dense planes on the native one (native fast path
+        with per-blob triage; full Python route when the fast path does
+        not apply)."""
+        from .wirebulk import orswot_cells_from_wire, orswot_planes_from_wire
 
+        if self.fold_path == "jnp":
+            orswot_cells_from_wire(blobs, self.universe, staging)
+            return
         planes = orswot_planes_from_wire(blobs, self.universe, out=staging)
         if planes is None:
             # no native fast path: decode in Python and copy into the
@@ -308,7 +335,7 @@ class PipelinedWireLoop:
         n = len(fleet_stream[0])
         if any(len(b) != n for b in fleet_stream):
             raise ValueError("all fleets must hold the same object count")
-        self._ensure_buffers(n)
+        self._ensure_buffers(n, max(len(rnd) for rnd in rounds))
         for st in self._staging:
             free_q.put(st)
 
@@ -369,18 +396,19 @@ class PipelinedWireLoop:
                 for fi in range(r):
                     staged = next_staged()
                     assert staged is not _SENTINEL
+                    if self.fold_path == "jnp":
+                        planes = self._put_device(staged)
+                        # its copy has landed: the parser may reuse it
+                        free_q.put(staged)
+                        acc = planes if acc is None else \
+                            self._merge_jnp(acc, planes)
+                        continue
+                    tracing.count("wireloop.put.dense")
                     if acc is None:
                         acc, acc_staging = staged, staged
                         continue
-                    if self.fold_path == "native":
-                        acc = self._merge_native(
-                            acc, staged, self._pingpong[pp]
-                        )
-                        pp ^= 1
-                    else:
-                        acc = self._merge_jnp(
-                            self._put_device(acc), self._put_device(staged)
-                        )
+                    acc = self._merge_native(acc, staged, self._pingpong[pp])
+                    pp ^= 1
                     # both consumed buffer sets go back to the parser
                     if acc_staging is not None:
                         free_q.put(acc_staging)
@@ -391,9 +419,7 @@ class PipelinedWireLoop:
                     acc = self._merge_native(acc, acc, self._pingpong[pp])
                     pp ^= 1
                 else:
-                    acc = self._merge_jnp(
-                        self._put_device(acc), self._put_device(acc)
-                    )
+                    acc = self._merge_jnp(acc, acc)
                 if acc_staging is not None:
                     # r == 1: the plunger read straight from staging
                     free_q.put(acc_staging)
@@ -457,27 +483,35 @@ class PipelinedWireLoop:
             ),
         }
 
-    def _put_device(self, planes: tuple):
-        """Host staging planes → device arrays for the jnp fold.
+    def _put_device(self, cells) -> tuple:
+        """One compact staging set → dense device planes for the jnp
+        fold: its id rows and power-of-two padded cell columns are
+        copied to the device, and :func:`~crdt_tpu.batch.orswot_batch.
+        _densify_cells` is dispatched behind them.
 
         ``device_put`` copies host numpy buffers into the backend's own
         allocations (on the CPU backend, which may alias an aligned host
-        buffer instead, the planes are copied on the host first), so
-        once the transfer completes the staging set is safe to hand
-        back to the parser; blocking here
-        costs only the H2D — the merges themselves still chain
-        asynchronously.  Device-resident accumulators pass through
-        untouched."""
+        buffer instead, the arrays are copied on the host first), so
+        once the copy completes the set is safe to hand back to the
+        parser; blocking here costs only the copy, ≈ 3 MB per ★ fleet —
+        the densify and the merges still chain asynchronously."""
         import jax
 
-        if not isinstance(planes[0], np.ndarray):
-            return planes
+        from .orswot_batch import _densify_cells
+
         with tracing.span("wireloop.put"):
+            host = cells.padded()
             if self._copy_before_put:
-                planes = tuple(np.array(p) for p in planes)
-            moved = jax.device_put(planes)
+                host = tuple(np.array(p) for p in host)
+            moved = jax.device_put(host)
             jax.block_until_ready(moved)
-        return moved
+        tracing.count("wireloop.put.compact")
+        tracing.count("wireloop.put.bytes", sum(p.nbytes for p in host))
+        cfg = self.cfg
+        with tracing.span("wireloop.dispatch"):
+            return _densify_cells(*moved, a=cfg.num_actors,
+                                  m=cfg.member_capacity,
+                                  d=cfg.deferred_capacity)
 
 
 class PipelinedOpLoop:

@@ -862,6 +862,53 @@ def orswot_intern_named(buf, offsets, idx, planes, status,
     ))
 
 
+def orswot_ingest_cells(buf, offsets, a: int, m: int, d: int, ids, d_ids,
+                        cell_idx, cell_val, status, tables=None) -> int:
+    """Parallel wire decode of ``n`` concatenated ORSWOT blobs into
+    compact cells: the member and deferred ids as dense int32 rows
+    (``ids[n, m]``, ``d_ids[n, d]``, overwritten whole) and each nonzero
+    counter as a ``(cell_idx, cell_val)`` pair, its flat index into the
+    plane-major space ``[clock n*a | dots n*m*a | d_clocks n*d*a]``.
+    The same parse, statuses and empty rows as
+    :func:`orswot_ingest_wire` (``tables``: the ``(actors, members)``
+    :class:`NameTable` pair of a named universe, as
+    :func:`orswot_ingest_named`'s parallel pass; None for identity
+    keys).
+
+    Returns the number of cells.  When it exceeds ``cell_idx``'s length
+    the cell columns are incomplete: grow them and call again."""
+    buf = np.ascontiguousarray(np.frombuffer(buf, dtype=np.uint8))
+    offsets = np.ascontiguousarray(offsets, dtype=np.int64)
+    n = offsets.shape[0] - 1
+    dt = np.dtype(cell_val.dtype)
+    for name, arr, shape, want in (
+        ("ids", ids, (n, m), np.int32), ("d_ids", d_ids, (n, d), np.int32),
+        ("cell_idx", cell_idx, cell_val.shape, np.int32),
+        ("status", status, (n,), np.uint8),
+    ):
+        if (arr.shape != shape or arr.dtype != want
+                or not arr.flags.c_contiguous):
+            raise ValueError(f"{name}: need C-contiguous "
+                             f"{np.dtype(want)}{shape}, got "
+                             f"{arr.dtype}{arr.shape}")
+    if not cell_val.flags.c_contiguous or cell_val.ndim != 1:
+        raise ValueError("cell_val: need a C-contiguous vector")
+    if n * (1 + m + d) * a > np.iinfo(np.int32).max:
+        raise ValueError(f"{n} objects of {(1 + m + d) * a} cells exceed "
+                         "the int32 flat index")
+    actors, members = (None, None) if tables is None else \
+        (tables[0].handle, tables[1].handle)
+    _count_native("orswot_ingest_cells", n)
+    fn = _fn("orswot_ingest_cells", dt)
+    fn.restype = ctypes.c_int64
+    return int(fn(
+        _ptr(buf), _ptr(offsets), ctypes.c_int64(n),
+        ctypes.c_int64(a), ctypes.c_int64(m), ctypes.c_int64(d),
+        actors, members, _ptr(ids), _ptr(d_ids), _ptr(cell_idx),
+        _ptr(cell_val), ctypes.c_int64(cell_val.shape[0]), _ptr(status),
+    ))
+
+
 def orswot_encode_named(clock, ids, dots, d_ids, d_clocks,
                         actors: NameTable, members: NameTable, repr_rank):
     """Parallel wire ENCODE of dense planes with names for keys —

@@ -2181,3 +2181,152 @@ CRDT_ORSWOT_NAMED(u32, uint32_t)
 CRDT_ORSWOT_NAMED(u64, uint64_t)
 
 }  // extern "C"
+
+// ---- compact ORSWOT ingest: a fleet as nonzero cells -----------------------
+//
+// The device fold ships each parsed fleet to the chip and densifies it
+// there, so a fleet crosses the host link as what it holds rather than
+// as dense planes (a ★-width replica-object is 4,936 B dense and about
+// 13 nonzero counters).  Each blob is parsed by ``parse_one`` into a
+// per-thread dense scratch row (one object's clock, dot and deferred
+// clock rows, zero between objects), so the dense parse's semantics
+// hold exactly: a repeated actor resolves last write wins, a zero
+// counter is an absent one, statuses are the same.  The object's member
+// and deferred ids are written out as dense int32 rows; each nonzero
+// counter of its live rows becomes one (flat index, counter) cell and
+// is zeroed again.  Flat indices address the plane-major space
+//
+//   [clock n*A | dots n*M*A | d_clocks n*D*A]
+//
+// (the caller checks it fits int32).  Every cell is unique, so the
+// per-thread runs are concatenated in any order.  A blob with nonzero
+// status keeps empty id rows and emits no cell.  Returns the number of
+// cells; when it exceeds ``cap`` the cell output is incomplete and the
+// caller grows its buffers and parses again.
+
+namespace {
+
+template <typename C>
+inline void emit_row(C* row, int64_t A, int64_t base,
+                     std::vector<int32_t>& idx, std::vector<C>& val) {
+  for (int64_t a = 0; a < A; ++a) {
+    if (row[a]) {
+      idx.push_back(static_cast<int32_t>(base + a));
+      val.push_back(row[a]);
+      row[a] = C{0};
+    }
+  }
+}
+
+template <typename C, typename K>
+int64_t cells_impl(const uint8_t* buf, const int64_t* offsets, int64_t n,
+                   int64_t A, int64_t M, int64_t D, int32_t* ids,
+                   int32_t* d_ids, int32_t* cell_idx, C* cell_val,
+                   int64_t cap, uint8_t* status, const K& keys) {
+  const int64_t row_len = A * (1 + M + D);
+  const int64_t dots_base = n * A;
+  const int64_t dclk_base = dots_base + n * M * A;
+  int64_t total = 0;
+#if defined(_OPENMP)
+#pragma omp parallel
+#endif
+  {
+    // kept per thread across calls: no allocation once warm
+    static thread_local std::vector<C> scratch;
+    static thread_local std::vector<int32_t> run_idx;
+    static thread_local std::vector<C> run_val;
+    if (static_cast<int64_t>(scratch.size()) != row_len)
+      scratch.assign(static_cast<size_t>(row_len), C{0});
+    run_idx.clear();
+    run_val.clear();
+    C* clock = scratch.data();
+    C* dots = clock + A;
+    C* d_clocks = dots + M * A;
+#if defined(_OPENMP)
+#pragma omp for schedule(dynamic, 1024) nowait
+#endif
+    for (int64_t i = 0; i < n; ++i) {
+      int32_t* id = ids + i * M;
+      int32_t* di = d_ids + i * D;
+      for (int64_t j = 0; j < M; ++j) id[j] = kEmpty;
+      for (int64_t j = 0; j < D; ++j) di[j] = kEmpty;
+      int st = parse_one<C>(buf, offsets[i], offsets[i + 1], A, M, D, clock,
+                            id, dots, di, d_clocks, keys);
+      status[i] = static_cast<uint8_t>(st);
+      if (st != 0) {
+        std::fill(scratch.begin(), scratch.end(), C{0});
+        for (int64_t j = 0; j < M; ++j) id[j] = kEmpty;
+        for (int64_t j = 0; j < D; ++j) di[j] = kEmpty;
+        continue;
+      }
+      // entries and deferred rows fill slots from 0, so the live rows
+      // are the leading ones with an id
+      emit_row<C>(clock, A, i * A, run_idx, run_val);
+      for (int64_t e = 0; e < M && id[e] != kEmpty; ++e)
+        emit_row<C>(dots + e * A, A, dots_base + (i * M + e) * A, run_idx,
+                    run_val);
+      for (int64_t r = 0; r < D && di[r] != kEmpty; ++r)
+        emit_row<C>(d_clocks + r * A, A, dclk_base + (i * D + r) * A,
+                    run_idx, run_val);
+    }
+    const int64_t k = static_cast<int64_t>(run_idx.size());
+    int64_t at;
+#if defined(_OPENMP)
+#pragma omp atomic capture
+#endif
+    {
+      at = total;
+      total += k;
+    }
+    if (at + k <= cap) {
+      std::memcpy(cell_idx + at, run_idx.data(), sizeof(int32_t) * k);
+      std::memcpy(cell_val + at, run_val.data(), sizeof(C) * k);
+    }
+  }
+  return total;
+}
+
+// ``actors`` null: integer keys (identity universe); else the named
+// codec's parallel pass (names looked up, none appended: status 5 marks
+// a blob holding an unseen name)
+template <typename C>
+int64_t ingest_cells(const uint8_t* buf, const int64_t* offsets, int64_t n,
+                     int64_t A, int64_t M, int64_t D, void* actors,
+                     void* members, int32_t* ids, int32_t* d_ids,
+                     int32_t* cell_idx, C* cell_val, int64_t cap,
+                     uint8_t* status) {
+  if (actors == nullptr)
+    return cells_impl<C>(buf, offsets, n, A, M, D, ids, d_ids, cell_idx,
+                         cell_val, cap, status, IntKeys{});
+  auto* at = static_cast<NameTable*>(actors);
+  auto* mt = static_cast<NameTable*>(members);
+  ReadLocks locks(at, mt);
+  return cells_impl<C>(buf, offsets, n, A, M, D, ids, d_ids, cell_idx,
+                       cell_val, cap, status, NamedKeys{at, mt, false});
+}
+
+}  // namespace
+
+extern "C" {
+
+int64_t orswot_ingest_cells_u32(const uint8_t* buf, const int64_t* offsets,
+                                int64_t n, int64_t A, int64_t M, int64_t D,
+                                void* actors, void* members, int32_t* ids,
+                                int32_t* d_ids, int32_t* cell_idx,
+                                uint32_t* cell_val, int64_t cap,
+                                uint8_t* status) {
+  return ingest_cells<uint32_t>(buf, offsets, n, A, M, D, actors, members,
+                                ids, d_ids, cell_idx, cell_val, cap, status);
+}
+
+int64_t orswot_ingest_cells_u64(const uint8_t* buf, const int64_t* offsets,
+                                int64_t n, int64_t A, int64_t M, int64_t D,
+                                void* actors, void* members, int32_t* ids,
+                                int32_t* d_ids, int32_t* cell_idx,
+                                uint64_t* cell_val, int64_t cap,
+                                uint8_t* status) {
+  return ingest_cells<uint64_t>(buf, offsets, n, A, M, D, actors, members,
+                                ids, d_ids, cell_idx, cell_val, cap, status);
+}
+
+}  // extern "C"

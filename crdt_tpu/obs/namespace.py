@@ -500,9 +500,17 @@ NAMESPACE: tuple[NameSpec, ...] = (
     NameSpec("wireloop.put", "histogram",
              "one staging set copied to the device, until the copy "
              "completes (span, jnp fold)"),
+    NameSpec("wireloop.put.compact", "counter",
+             "fleets shipped to the device fold as compact cells and "
+             "densified there"),
+    NameSpec("wireloop.put.dense", "counter",
+             "fleets handed to the fold as dense planes (every fleet "
+             "of the native CPU fold, which puts nothing)"),
+    NameSpec("wireloop.put.bytes", "counter",
+             "host bytes handed to device_put by the wire loop's puts"),
     NameSpec("wireloop.dispatch", "histogram",
-             "one fold merge dispatched (span, the merge runs async "
-             "on the jnp fold)"),
+             "one fold merge, or a staging set's densify, dispatched "
+             "(span, both run async on the jnp fold)"),
     NameSpec("wireloop.wait", "histogram",
              "a round's fold finishing on the device, then its "
              "overflow check (span, jnp fold)"),

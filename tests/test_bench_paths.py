@@ -81,3 +81,24 @@ def test_salted_scan_matches_stepped_replay(n_chunks):
             np.asarray(g), np.asarray(w),
             err_msg=f"plane {i}: scan diverged from per-step replay",
         )
+
+
+@pytest.mark.parametrize("fold_path", ["native", "jnp"])
+def test_bench_e2e_wire_runs_both_fold_paths(fold_path, monkeypatch):
+    """``bench_e2e_wire`` at its small size, on the fold each backend
+    takes (the native CPU fold; the device fold, forced on the CPU by
+    ``CRDT_SKIP_NATIVE_HEADLINE``): a failure here would silently drop
+    ``e2e_wire_s`` and every overhead gate that reads it."""
+    import bench
+    from crdt_tpu.batch.wireloop import _native_fold_engine
+
+    if fold_path == "native" and _native_fold_engine() is None:
+        pytest.skip("native fold unavailable")
+    monkeypatch.setattr(bench, "SMALL", True)
+    monkeypatch.setenv("CRDT_SKIP_NATIVE_HEADLINE",
+                       "1" if fold_path == "jnp" else "0")
+    out = bench.bench_e2e_wire()
+    assert out["e2e_wire_fold_path"] == fold_path
+    assert out["e2e_wire_s"] > 0
+    assert out["e2e_wire_replica_objects"] == 2 * 4 * 1_000
+    assert out["e2e_shape_ingest_obj_per_sec"] > 0

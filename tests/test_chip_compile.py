@@ -79,6 +79,27 @@ def test_wireloop_fold_kernel_compiles(one_chip, monkeypatch):
     assert compiled.memory_analysis().output_size_in_bytes > 0
 
 
+def test_wireloop_densify_compiles(one_chip):
+    """The wire loop's densify at the ★ fleet's shape (15,625 objects,
+    ≈ 13 cells each, padded to a power of two): the dense planes it
+    writes plus the flat space they are cut from, nothing whole-fleet
+    more."""
+    import crdt_tpu.batch  # noqa: F401  (x64 on, as on the run path)
+    from crdt_tpu.batch.orswot_batch import _densify_cells
+
+    n, k = 15_625, 1 << 18
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    compiled = _densify_cells.lower(
+        s((n, M), jnp.int32), s((n, D), jnp.int32), s((k,), jnp.int32),
+        s((k,), jnp.uint32), a=A, m=M, d=D).compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes >= n * 4936
+    assert mem.temp_size_in_bytes < 4 * n * 4936
+
+
 STAR_N = 1_250_000  # the ★ replica, one chip's whole share
 
 

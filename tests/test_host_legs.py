@@ -53,10 +53,10 @@ def _wire(uni):
         res = loop.run(blobs, collect="none")
         assert res["rounds"] == ROUNDS
     per_fleet, per_round = ROUNDS * REPLICAS, ROUNDS
-    # the jnp fold puts every staging set once and dispatches r - 1
-    # merges plus the plunger per round
+    # the jnp fold puts every staging set once and dispatches its
+    # densify, then r - 1 merges plus the plunger per round
     want = {"wireloop.parse": per_fleet, "wireloop.wait_parsed": per_fleet,
-            "wireloop.put": per_fleet, "wireloop.dispatch": per_fleet,
+            "wireloop.put": per_fleet, "wireloop.dispatch": 2 * per_fleet,
             "wireloop.wait": per_round, "wireloop.fetch": per_round,
             "wireloop.encode": per_round}
     return run, want

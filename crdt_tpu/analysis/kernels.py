@@ -244,7 +244,7 @@ class ShardContract:
           identically (or shard-locally on routed values) on every
           shard and must lower no collective.
         * ``"host_only"`` — off the mesh hot path (snapshot
-          compact/expand, bench scaffolding); never mesh-traced.
+          compact/expand); never mesh-traced.
 
     ``obj`` — ``((leaf, axis), ...)``: which flattened arg leaves carry
     the object axis and at which dim (``(ALL_LEAVES, axis)`` = every
@@ -1456,23 +1456,6 @@ MANIFEST: tuple = (
                           "region opaque to SC01"),
                build=_b_pallas("orswot_fold_aligned", "fold_merge",
                                fold=True)),
-    # utils/benchtime.py: bench-harness scaffolding, manifest-covered but
-    # not traced — the jitted bodies are caller-shaped (a warmup +1 lambda
-    # and a closure over the caller's step fn), so there is no canonical
-    # abstract call to declare.  hot_path=False: they ARE the timing
-    # harness, host sync is their job.
-    KernelSpec("utils.benchtime.sync_probe", "crdt_tpu/utils/benchtime.py",
-               "sync_overhead.<lambda>", hot_path=False,
-               sharding=host_only("bench-harness warmup probe; host "
-                                  "sync is its whole job"),
-               notrace_reason="warmup lambda; shapes fixed at call site, "
-                              "no CRDT contract"),
-    KernelSpec("utils.benchtime.chain_timer", "crdt_tpu/utils/benchtime.py",
-               "chain_timer.run", hot_path=False,
-               sharding=host_only("bench-harness chain timer; host sync "
-                                  "is its whole job"),
-               notrace_reason="closure over the caller-supplied step fn; "
-                              "shapes are caller-defined"),
 )
 
 

@@ -8,7 +8,7 @@ fault — :class:`~crdt_tpu.error.SyncProtocolError` or another
 catch at the socket), and never silently swallowed.  Telemetry rides
 the same boundary: every bulk ``from_wire``/``to_wire`` leg feeds
 ``record_wire`` so a silent native→Python fallback shows up in the
-bench artifact (the round-5 ingest-collapse lesson).
+``wire.*`` counters (the round-5 ingest-collapse lesson).
 
 * ``wire-bare-valueerror`` — ``raise ValueError`` (or TypeError /
   KeyError / struct.error) lexically inside a decode-path function of
@@ -253,5 +253,5 @@ def check_missing_record(files: List[ParsedFile]) -> Iterable[Finding]:
                 f"bulk wire leg {fn.name}() never feeds record_wire — "
                 "its native_fraction is invisible and a silent fallback "
                 "regression (the round-5 ingest collapse) cannot be "
-                "seen from the bench artifact",
+                "seen from the wire.* counters",
             )

@@ -275,7 +275,7 @@ class OrswotBatch:
         tables — on device when the backend is an accelerator, so only
         compact columns cross the host link (:func:`_build_planes`).  The
         per-dot Python bytecode of the append-based walk is what bounded
-        ingest at ~30k obj/s at 1M scale (``bench.py`` ``ingest`` line);
+        ingest at 1M scale on the CPU (``reports/INGEST_PROFILE.md``);
         this path keeps the unavoidable O(total dots) work in C."""
         import numpy as np
 

@@ -6,10 +6,10 @@ the user-facing loop is *wire blobs in → anti-entropy fold → wire blobs
 out*, processed in device-sized chunks.  The serial form of that loop —
 ``from_wire`` per replica fleet, then fold, then ``to_wire`` — measured
 **13,908 merges/s** against a 3.17M merges/s fold kernel in the same
-artifact (``BENCH_r05.json``): ingest was 87% of wall clock, ~160× off
-the wire microbench.  Profiling found the collapse was NOT a silent
+CPU run (round 5): ingest was 87% of wall clock, ~160× off the wire
+microbench.  Profiling found the collapse was NOT a silent
 Python fallback (the native parser accepts 100% of e2e-shaped blobs —
-the ``native_fraction`` counters now prove that from the artifact
+the ``native_fraction`` counters now prove that from the result
 alone); it was **allocation churn**: every ``from_wire`` call allocated
 a fresh ~300 MB dense plane set per fleet, page-faulting ~2.5 GB of
 zeroed memory per chunk and freeing it again, which measured 27× slower
@@ -40,8 +40,8 @@ pipeline").
   zero allocations (`engine.orswot_merge(out=...)`).
 * **Instrumentation** — per-stage wall times and native-vs-fallback
   blob counts (via :mod:`crdt_tpu.utils.tracing` counters) are returned
-  with the result, so the bench JSON can self-report ``native_fraction``
-  per stage.  The loop also publishes live gauges
+  with the result, so a caller can report ``native_fraction`` per
+  stage.  The loop also publishes live gauges
   (``wireloop.staging_free`` — free staging sets, ``wireloop.
   parsed_depth`` — parsed fleets waiting for the fold) to the obs
   registry, and a fold blocked on the parser for longer than
@@ -58,8 +58,8 @@ pipeline").
   nothing), and ``wireloop.put.bytes`` (host bytes handed to
   ``device_put``).
 
-``bench_e2e_wire`` (bench.py) and ``examples/anti_entropy.py`` drive
-this one implementation.
+The benchmark's ``wire.star`` and ``wire.named`` cells (``benchmark/``)
+and ``examples/anti_entropy.py`` drive this one implementation.
 """
 
 from __future__ import annotations
@@ -276,10 +276,11 @@ class PipelinedWireLoop:
         Each round is a sequence of ``r`` blob lists (one per replica
         fleet, equal lengths).  With ``overlap=True`` a background
         thread stays one fleet ahead of the fold; ``overlap=False`` runs
-        the identical staged code serially (the A/B the bench reports).
+        the identical staged code serially (the other side of an overlap
+        A/B).
 
         ``collect``: ``"last"`` keeps only the final round's egressed
-        blobs (bounded memory at bench scale), ``"all"`` keeps every
+        blobs (bounded memory at scale), ``"all"`` keeps every
         round's, ``"none"`` keeps none.  ``on_round(i, blobs)`` sees
         each round's output either way.
 
@@ -527,8 +528,7 @@ class PipelinedOpLoop:
     The overlap is real on multicore hosts: frame decode is pure
     numpy/zlib on the host, while the fold is one jitted scatter per
     batch (:meth:`crdt_tpu.oplog.OpApplier.apply_ops`) that dispatches
-    asynchronously on accelerator backends.  ``bench_oplog`` drives
-    this one implementation for its pipelined numbers.
+    asynchronously on accelerator backends.
     """
 
     def __init__(self, universe: Universe, *, applier=None, depth: int = 4,
@@ -550,7 +550,7 @@ class PipelinedOpLoop:
         ``stats = {"frames", "ops", "applied", "duplicates",
         "still_parked", "pipeline", "stage_s": {parse, fold},
         "e2e_s"}`` — the same per-stage accounting the wire loop
-        reports, so the bench can show the overlap won."""
+        reports, so a caller can see whether the overlap won."""
         from ..oplog.wire import decode_ops_frame
 
         frames = list(frames)

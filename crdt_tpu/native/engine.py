@@ -48,9 +48,9 @@ def _count_native(name: str, objects: int) -> None:
     """Always-on call/object counters for the hot native entry points
     (``native.engine.<name>.{calls,objects}``) — one dict increment per
     BULK call, same discipline as the wire codec counters.  A counter
-    family that vanishes round-over-round in the bench artifact is the
-    silent-fallback smell ``benchkit/artifacts.py`` warns on: the native
-    path stopped being exercised without anything failing loudly."""
+    family that vanishes between two snapshots is the silent-fallback
+    smell: the native path stopped being exercised without anything
+    failing loudly."""
     from ..utils import tracing
 
     tracing.count(f"native.engine.{name}.calls")

@@ -9,7 +9,7 @@ Three consumers, three shapes:
   sanitize to underscores at scrape time so hot paths never pay for it.
 * :func:`json_snapshot` — one dict with the registry snapshot, the
   flight-recorder events, and the per-peer convergence state; what
-  ``bench.py`` embeds in the artifact tail and ``/events`` serves.
+  ``/events`` serves.
 * :class:`MetricsServer` / :func:`start_metrics_server` — an opt-in,
   stdlib-only background HTTP thread serving ``GET /metrics`` (Prom
   text), ``GET /events`` (JSON; ``?session=`` / ``?kind=`` filters),
@@ -125,7 +125,7 @@ def prometheus_text(registry: Optional[metrics.MetricsRegistry] = None,
 
 def json_snapshot(registry: Optional[metrics.MetricsRegistry] = None) -> dict:
     """One JSON-ready dict: metrics + flight-recorder events + per-peer
-    convergence state (what ``/events`` and the bench artifact embed)."""
+    convergence state (what ``/events`` embeds)."""
     if registry is None:
         from . import kernels as kernels_mod
 

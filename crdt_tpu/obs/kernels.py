@@ -15,8 +15,7 @@ on the SAME single source of kernel identity — the
   site, or a wrap around a factory's return).  Each call pays two
   ``perf_counter`` reads, one ``_cache_size()`` fetch, the shape-walk
   bytes estimate and a few dict increments under the profile's own
-  lock; ``bench_kernel_obs`` gates the total below 1% of
-  ``bench_e2e_wire`` wall.
+  lock.
 * **Compile tracking** — a jit cache growing across a call IS a
   lowering+compile: counted per kernel (``kernel.<label>.compiles`` +
   the process-wide ``kernel.compiles``), flight-recorded as a

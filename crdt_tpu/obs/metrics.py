@@ -1,7 +1,7 @@
 """Typed metric registry — counters, gauges, log2-bucketed histograms.
 
 The flat span/counter dicts in :mod:`crdt_tpu.utils.tracing` only become
-legible when ``bench.py`` diffs snapshots after the fact; a live export
+legible when a caller diffs snapshots after the fact; a live export
 surface (:mod:`crdt_tpu.obs.export`) needs metrics with *types*, because
 a Prometheus scrape renders a counter, a gauge, and a histogram
 differently and a consumer alerts on them differently:

@@ -673,11 +673,6 @@ NAMESPACE: tuple[NameSpec, ...] = (
              "kernel dispatches the runtime contract gate refused "
              "(host_only/replicated/mesh-size outside the contract "
              "ladder) — the typed MeshContractError path"),
-    # -- bench probes (bench.py bench_obs_overhead) --------------------------
-    NameSpec("obs.overhead.count_probe", "counter",
-             "bench_obs_overhead per-op counter cost probe"),
-    NameSpec("obs.overhead.gauge_probe", "gauge",
-             "bench_obs_overhead per-op gauge cost probe"),
 )
 
 

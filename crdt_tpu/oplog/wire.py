@@ -28,7 +28,7 @@ The tail triples are the SPARSE remove clocks: ``Op::Rm`` ships a full
 witnessing clock (`orswot.rs:80-83`) while ``Op::Add`` ships only its
 dot (`orswot.rs:66-79`) — so the wire cost of an add is the 23-byte
 fixed row, a few dozen bytes against the wire codec's per-object state
-cost (the whole point of the op path; ``bench_oplog`` pins the ratio).
+cost (the whole point of the op path).
 """
 
 from __future__ import annotations
@@ -244,7 +244,7 @@ def frame_op_count(frame: bytes) -> int:
 
 def frame_bytes_per_op(ops: OpBatch) -> float:
     """Wire bytes per op for ``ops`` (header amortized) — the number
-    ``bench_oplog`` compares against the per-object delta-sync cost."""
+    to compare against the per-object delta-sync cost."""
     if len(ops) == 0:
         return float(_HEADER.size + _FIXED.size + 4)
     return len(encode_ops_frame(ops)) / len(ops)

@@ -1,6 +1,6 @@
-"""Random dense CRDT state generators for benchmarks and smoke tests.
+"""Random dense CRDT state generators for tests.
 
-Shared by ``bench.py`` and ``__graft_entry__.py`` so the state-layout
+Shared by the tests and ``__graft_entry__.py`` so the state-layout
 invariants live in one place.  Invariants a valid ORSWOT batch must hold
 (`/root/reference/src/orswot.rs:26-30` via the dense mapping in
 ``crdt_tpu/ops/orswot_ops.py``):
@@ -257,8 +257,7 @@ def random_mvreg_map(rng, n_keys=5, n_actors=6, max_ops=10, rm_p=0.3,
 def dense_row_to_scalar(clock_row, ids_row, dots_row, dids_row, dclocks_row):
     """Scalar Orswot from one dense object's rows — actors are the dense
     column indices, members the raw interned ids (no Universe needed).
-    The shared oracle-side converter for the bench parity sample and the
-    fold-order tests."""
+    The shared oracle-side converter for the fold parity tests."""
     from ..scalar.orswot import Orswot
     from ..scalar.vclock import VClock
 

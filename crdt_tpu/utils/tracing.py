@@ -91,8 +91,8 @@ class Tracer:
 
     Counters are the *path-taken* half of observability (SURVEY §5): the
     wire codecs count native-vs-fallback blobs per call so a silent
-    fallback regression is visible in the bench artifact, not just in
-    wall time.  Unlike spans they are always on — one dict increment per
+    fallback regression is visible in the counters, not just in wall
+    time.  Unlike spans they are always on — one dict increment per
     *bulk call* (not per blob) is free — so ``enabled`` gates spans only.
     """
 

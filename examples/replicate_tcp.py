@@ -1322,9 +1322,10 @@ def main() -> int:
         print("replicate_tcp: the two-process demo needs two processes on "
               "one backend, and a TPU chip belongs to one process at a "
               "time.  On a TPU host run the in-process form instead — "
-              "`--gossip N` (peers as threads of one process), or the sync "
-              "phase of chip_smoke.py (two SyncSessions over a "
-              "socketpair) — or pass --platform cpu.", file=sys.stderr)
+              "`--gossip N` (peers as threads of one process), or two "
+              "SyncSessions over a socketpair in one process (as "
+              "tests/test_lifecycle.py does) — or pass --platform cpu.",
+              file=sys.stderr)
         return 2
 
     with socket.socket() as probe:

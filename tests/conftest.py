@@ -2,8 +2,9 @@
 
 Runs the suite on a virtual 8-device CPU mesh so multi-chip sharding
 paths (`crdt_tpu.parallel`, `crdt_tpu.mesh`) are exercised without
-hardware; the chip itself is driven by `chip_smoke.py`, and the chip's
-compiler by `tests/test_chip_compile.py` (described devices, no chip).
+hardware; the chip itself is driven by `benchmark/run.py`, and the
+chip's compiler by `tests/test_chip_compile.py` (described devices, no
+chip).
 x64 is enabled by `import crdt_tpu` so counters are u64 like the
 reference (`/root/reference/src/vclock.rs:23`).
 

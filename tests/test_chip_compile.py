@@ -189,7 +189,7 @@ def test_union_aligned_pallas_fold_compiles(one_chip):
 
 
 def test_smoke_fold_plans_under_one_chip(one_chip):
-    """The fold `chip_smoke.py` runs — 8 fleets × 125,000 objects at
+    """A one-chip fold of 8 fleets × 125,000 objects at
     the north-star width, 4.94 GB of inputs — plans inside one chip's
     HBM, with room left for nothing but the inputs it holds."""
     from crdt_tpu.batch.orswot_batch import _fold_tree

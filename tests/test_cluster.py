@@ -503,6 +503,56 @@ def test_mixed_window_fleet_falls_back_to_stop_and_wait():
     assert sa.batch.to_wire(uni) == ref == sb.batch.to_wire(uni)
 
 
+def _diverged_pair(uni, n, k, seed):
+    """A plunged fleet, and a copy whose ``k`` sampled rows each take one
+    fresh add."""
+    import jax
+    import jax.numpy as jnp
+
+    from crdt_tpu.utils.testdata import anti_entropy_fleets
+
+    cfg = uni.config
+    rng = np.random.RandomState(seed)
+    reps = anti_entropy_fleets(rng, n, cfg.num_actors, cfg.member_capacity,
+                               cfg.deferred_capacity, 1, base=4, novel=0,
+                               deferred_frac=0.25)
+    a = OrswotBatch(*(jnp.asarray(x) for x in reps[0]))
+    a = a.merge(a)
+    rows = np.sort(rng.choice(n, size=k, replace=False)).astype(np.int64)
+    sub = jax.tree_util.tree_map(lambda p: p[rows], a)
+    sub = sub.apply_add(np.zeros(k, np.int32),
+                        jnp.max(sub.clock, axis=-1) + 1,
+                        np.full(k, 1 << 20, np.int32))
+    b = jax.tree_util.tree_map(lambda p, s: p.at[rows].set(s), a, sub)
+    return a, b
+
+
+@pytest.mark.parametrize("window, chunks", [(16, 1), (16, 3), (1, 3)])
+def test_streaming_delta_ships_one_chunk_per_chunk_rows(window, chunks):
+    """A windowed session streams the shared diverged set in
+    ``DELTA_CHUNK_ROWS``-row chunks, the last one partial: each side
+    ships exactly ``ceil(k / DELTA_CHUNK_ROWS)`` chunk frames.  A
+    window-1 pair never streams and ships none.  Both end byte-identical
+    to the plain merge."""
+    from crdt_tpu.sync.delta import DELTA_CHUNK_ROWS
+
+    uni = _uni(member_capacity=8, deferred_capacity=2)
+    k = (chunks - 1) * DELTA_CHUNK_ROWS + DELTA_CHUNK_ROWS // 2
+    a, b = _diverged_pair(uni, 2048, k, seed=31)
+    ta, tb = queue_pair(default_timeout=10.0)
+    pol = policy_replace(FAST, window=window)
+    ra = ResilientTransport(ta, pol, name="a", seed=94)
+    rb = ResilientTransport(tb, pol, name="b", seed=95)
+    sa, sb, rep_a, rep_b = _sync_sessions_over(a, b, uni, ra, rb)
+    want = chunks if window > 1 else 0
+    for rep in (rep_a, rep_b):
+        assert rep.converged and rep.diverged == k
+        assert rep.streaming == (window > 1)
+        assert rep.delta_chunks_sent == want
+    ref = a.merge(b).to_wire(uni)
+    assert sa.batch.to_wire(uni) == ref == sb.batch.to_wire(uni)
+
+
 def test_session_accepts_transport_directly():
     """The Transport-object API of SyncSession.sync — the callable pair
     stays as a shim, the cluster runtime passes transports."""

@@ -198,6 +198,46 @@ def test_fleet_counter_is_sum_of_per_node_g_counters():
     assert h["min"] == 0.5 and h["max"] == 4.0
 
 
+def _synth_observatory(node: str) -> obs_fleet.FleetObservatory:
+    """One node's observatory shaped like a live registry: counters,
+    gauges, a histogram, convergence state and an event tail."""
+    reg = obs_metrics.MetricsRegistry()
+    for i in range(40):
+        reg.counter_inc(f"wire.sync.leg{i}.bytes", i * 7 + 1)
+    for i in range(10):
+        reg.gauge_set(f"sync.peer.p{i}.divergence", float(i))
+    for i in range(64):
+        reg.observe("sync.digest_exchange", 0.0005 * (i + 1))
+    trk = obs_convergence.ConvergenceTracker(registry=reg)
+    trk.observe_session(node, converged=True, rounds=1,
+                        payload_bytes=1024, full_state_bytes=65536)
+    rec = obs_events.FlightRecorder(capacity=256)
+    for i in range(128):
+        rec.record("sync.phase", session=f"s{i:04d}", phase="digest",
+                   trace=f"t{i:04d}")
+    return obs_fleet.FleetObservatory(node, registry=reg, tracker=trk,
+                                      recorder=rec)
+
+
+@pytest.mark.parametrize("n_nodes", [2, 8, 32])
+def test_encoded_frames_merge_into_one_slice_per_node(n_nodes):
+    """Every node's encoded snapshot merged into one sink: one slice per
+    node, each counter summed over the nodes once, and the sink's own
+    frame re-delivered changes nothing."""
+    observatories = [_synth_observatory(f"b{i}") for i in range(n_nodes)]
+    frames = [o.encode() for o in observatories]
+    sink = observatories[0]
+    for f in frames:
+        sink.merge_frame(f)
+    merged = sink.merged(refresh=False)
+    assert sorted(merged.slices) == sorted(f"b{i}" for i in range(n_nodes))
+    fleet = merged.fleet_counters()
+    assert fleet["wire.sync.leg1.bytes"] == 8 * n_nodes
+    assert fleet["wire.sync.leg39.bytes"] == 274 * n_nodes
+    sink.merge_frame(frames[-1])
+    assert sink.merged(refresh=False) == merged
+
+
 # ---- the frame codec -------------------------------------------------------
 
 

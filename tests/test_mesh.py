@@ -310,7 +310,7 @@ def test_step_consumes_exactly_the_declared_contract_set():
 
 def test_contract_gate_refuses_with_typed_errors():
     cases = [
-        ("utils.benchtime.sync_probe", 1, "host_only"),
+        ("batch.orswot.device_compact", 1, "host_only"),
         ("obs.heat.sketch_update", 2, "replicated"),
         ("parallel.shard_local_merge", 2, "pointwise"),  # mesh_sizes=(1,)
     ]
@@ -327,7 +327,7 @@ def test_contract_gate_refuses_with_typed_errors():
         mesh.require_shardable("no.such.kernel", 1)
     assert ei.value.kernel == "no.such.kernel"
     # refusals never enter the consumed set
-    assert "utils.benchtime.sync_probe" not in mesh.consumed_contracts()
+    assert "batch.orswot.device_compact" not in mesh.consumed_contracts()
 
 
 # -- shard-subset sync -------------------------------------------------------

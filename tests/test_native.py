@@ -691,8 +691,8 @@ def test_map_map_mvreg_three_engine_agreement():
 
 @pytest.mark.parametrize("dtype", DTYPES)
 def test_orswot_fold_parity(engines, dtype):
-    """The bench's native-fold headline path (sequential R-way fold +
-    defer plunger, bench.py native_fold_join) must be bit-identical to
+    """The native sequential R-way fold + defer plunger (the wire loop's
+    CPU fold) must be bit-identical to
     the jnp fold on anti-entropy-shaped fleets with deferred rows."""
     import jax
 
@@ -725,9 +725,9 @@ def test_orswot_fold_parity(engines, dtype):
 
 @pytest.mark.parametrize("dtype", DTYPES)
 def test_orswot_merge_out_buffers(engines, dtype):
-    """The out= reuse path (bench fold ping-pong) must be bit-identical
-    to fresh allocation, reject shape/dtype mismatches, and reject
-    buffers aliasing an input."""
+    """The out= reuse path (the wire loop's fold ping-pong) must be
+    bit-identical to fresh allocation, reject shape/dtype mismatches,
+    and reject buffers aliasing an input."""
     engine, *_ = engines
     from crdt_tpu.utils.testdata import anti_entropy_fleets
 

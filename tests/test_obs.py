@@ -396,37 +396,6 @@ def test_wireloop_publishes_gauges():
     assert "crdt_tpu_wireloop_staging_free" in text
 
 
-# ---- counter-family regression differ --------------------------------------
-
-
-def test_counter_family_warnings():
-    from benchkit import artifacts
-
-    prior = {
-        "wire.orswot.from_wire.native": 100,
-        "wire.orswot.from_wire.fallback": 2,
-        "wire.orswot.from_wire.fallback_reason.grammar": 2,
-        "wire.gset.to_wire.native": 5,
-        "sync.sessions": 3,
-    }
-    # gset family vanished entirely; orswot lost its .native leaf while
-    # the family survives (the silent-fallback smell)
-    current = {
-        "wire.orswot.from_wire.fallback": 90,
-        "sync.sessions": 4,
-    }
-    warns = artifacts.counter_family_warnings(prior, current)
-    kinds = {(w["kind"], w["family"]) for w in warns}
-    assert ("family_vanished", "wire.gset.to_wire") in kinds
-    assert ("native_vanished", "wire.orswot.from_wire") in kinds
-    # a reason counter that stops firing is NOT a warning on its own
-    assert not any("fallback_reason" in str(w) for w in warns)
-    # no priors / no currents -> no warnings, never a crash
-    assert artifacts.counter_family_warnings(None, current) == []
-    assert artifacts.counter_family_warnings(prior, None) == []
-    assert artifacts.counter_family_warnings(prior, dict(prior)) == []
-
-
 # ---- the HTTP export surface -----------------------------------------------
 
 

@@ -70,7 +70,7 @@ def test_fold_merge_matches_sequential_fold():
     got = orswot_pallas.fold_merge(*stacked, m, d, interpret=True)
     _assert_same(acc + (over,), got)
 
-    # the pre-biased entry point (bench hot path): pad+bias once outside,
+    # the pre-biased entry point: pad+bias once outside,
     # fold in the kernel domain, unbias once after — bit-equal
     padded = orswot_pallas.pad_to_tile(stacked, m, d, n_states=r + 1)
     biased = orswot_pallas.to_kernel_domain(padded)
@@ -133,9 +133,8 @@ def test_full_uint32_counter_range_parity():
 
 
 def test_salt_chain_commutes_with_bias():
-    """The bench's headline attempt salts in the kernel's biased domain
-    (bench.py bench_pallas_north_star): XOR commutes with the x^0x80000000
-    bias, so salting-then-biasing equals biasing-then-salting, and the
+    """A salt applied in the kernel's biased domain (the prebiased
+    entry point): XOR commutes with the x^0x80000000 bias, so salting-then-biasing equals biasing-then-salting, and the
     biased-domain next_salt (max & 7 | 1) picks the same salt values."""
     rng = np.random.RandomState(7)
     n, a, m, d, r = 17, 8, 4, 2, 4

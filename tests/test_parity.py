@@ -10,6 +10,7 @@ same kernels run unchanged on TPU.
 """
 
 import jax.numpy as jnp
+import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
@@ -553,3 +554,95 @@ def test_truncate_empty_clock_is_identity():
     batch = OrswotBatch.from_scalar([s], uni)
     got = batch.truncate(jnp.zeros_like(batch.clock)).to_scalar(uni)[0]
     assert got == s
+
+
+# -- BASELINE.md configs 1-5 at their published widths ----------------------
+
+
+def _baseline_vclocks(rng, n, a):
+    return [VClock({int(x): int(rng.randint(1, 1000))
+                    for x in rng.choice(a, size=rng.randint(0, a + 1),
+                                        replace=False)})
+            for _ in range(n)]
+
+
+def _baseline_orswot(rng, a, m):
+    """Causal adds and removes over ``m`` members from ``a`` actors, and
+    now and then one causally-future remove that defers."""
+    s = Orswot()
+    for _ in range(int(rng.randint(0, 10))):
+        actor, member = int(rng.randint(0, a)), int(rng.randint(0, m))
+        if rng.rand() < 0.75 or member not in s.entries:
+            s.apply(s.add(member, s.value().derive_add_ctx(actor)))
+        else:
+            s.apply(s.remove(member, s.contains(member).derive_rm_ctx()))
+    if rng.rand() < 0.3 and s.entries:
+        member = next(iter(s.entries))
+        ctx = s.contains(member).derive_rm_ctx()
+        ctx.clock.witness(int(rng.randint(0, a)), int(rng.randint(2000, 3000)))
+        s.apply(s.remove(member, ctx))
+    return s
+
+
+def _baseline_pair(config, rng, n):
+    """``(batch_type, universe, lhs, rhs)`` for one BASELINE.md config."""
+    if config == "gcounter":  # config 1: 2 replicas, 4 actors
+        uni = Universe(CrdtConfig(num_actors=4, member_capacity=8,
+                                  deferred_capacity=4))
+        a, b = GCounter(), GCounter()
+        for actor in ("A", "B", "A", "C"):
+            a.apply(a.inc(actor))
+        for actor in ("B", "D"):
+            b.apply(b.inc(actor))
+        return GCounterBatch, uni, [a], [b]
+    if config == "vclock":  # config 2: 1k clocks x 64 actors
+        uni = Universe(CrdtConfig(num_actors=64))
+        return (VClockBatch, uni, _baseline_vclocks(rng, n, 64),
+                _baseline_vclocks(rng, n, 64))
+    if config == "pncounter":  # config 3: 1M replicas x 32 actors
+        from crdt_tpu.scalar.gcounter import GCounter as G
+
+        uni = Universe(CrdtConfig(num_actors=32))
+
+        def side():
+            return [PNCounter(G(p), G(q)) for p, q in
+                    zip(_baseline_vclocks(rng, n, 32),
+                        _baseline_vclocks(rng, n, 32))]
+
+        return PNCounterBatch, uni, side(), side()
+    if config == "orswot":  # config 4: 100k sets x 16 actors, pairwise
+        uni = Universe(CrdtConfig(num_actors=16, member_capacity=8,
+                                  deferred_capacity=4))
+        return (OrswotBatch, uni,
+                [_baseline_orswot(rng, 16, 8) for _ in range(n)],
+                [_baseline_orswot(rng, 16, 8) for _ in range(n)])
+    assert config == "lwwreg"  # config 5: 10M registers, u64 markers
+    uni = Universe(CrdtConfig(num_actors=8))
+    epoch_ms = 1_700_000_000_000
+    # even markers left, odd right: no equal-marker conflict
+    marks = epoch_ms + 2 * rng.randint(0, 1 << 20, size=(2, n))
+    return (LWWRegBatch, uni,
+            [LWWReg(val=int(rng.randint(0, 1 << 40)), marker=int(m))
+             for m in marks[0]],
+            [LWWReg(val=int(rng.randint(0, 1 << 40)), marker=int(m) + 1)
+             for m in marks[1]])
+
+
+@pytest.mark.parametrize(
+    "config", ["gcounter", "vclock", "pncounter", "orswot", "lwwreg"])
+def test_baseline_config_merge_parity(config):
+    """Each BASELINE.md config at its published width, the object count
+    cut to test size: the batch merge equals the scalar merge object by
+    object (config 1 also pins its value, 2+1+1+1)."""
+    import numpy as np
+
+    rng = np.random.RandomState(5)
+    cls, uni, lhs, rhs = _baseline_pair(config, rng, 64)
+    expected = [scalar_merge(a, b) for a, b in zip(lhs, rhs)]
+    got = cls.from_scalar(lhs, uni).merge(cls.from_scalar(rhs, uni)) \
+        .to_scalar(uni)
+    assert len(got) == len(expected)
+    for i, (g, e) in enumerate(zip(got, expected)):
+        assert g == e, f"{config} object {i}:\nbatch:  {g!r}\nscalar: {e!r}"
+    if config == "gcounter":
+        assert got[0].value() == 5

@@ -371,6 +371,52 @@ def test_dense_divergence_cutover_falls_back_to_flat():
     assert sa.batch.to_wire(uni) == a.merge(b).to_wire(uni)
 
 
+@pytest.mark.parametrize("shape, frac", [
+    ("converged", 0.0), ("one_pct", 0.01), ("dense", 1.0)])
+def test_tree_digest_bytes_against_the_flat_frame(shape, frac):
+    """Digest bytes a tree session ships per side, against one flat
+    digest frame of the same fleet: at most 5% converged (one root
+    frame), at most 15% at 1% uniform divergence, and never more than
+    the flat frame plus one root frame when every row diverged."""
+    import jax
+    import jax.numpy as jnp
+
+    from crdt_tpu.sync.delta import encode_digest_frame
+    from crdt_tpu.utils.testdata import anti_entropy_fleets
+
+    n, a, m, d = 4096, 16, 8, 2
+    uni = _uni(num_actors=a, member_capacity=m, deferred_capacity=d)
+    rng = np.random.RandomState(17)
+    reps = anti_entropy_fleets(rng, n, a, m, d, 1, base=4, novel=0,
+                               deferred_frac=0.25)
+    fa = OrswotBatch(*(jnp.asarray(x) for x in reps[0]))
+    fa = fa.merge(fa)
+    fb = fa
+    k = int(n * frac)
+    if k:
+        rows = np.sort(rng.choice(n, size=k, replace=False)).astype(np.int64)
+        sub = jax.tree_util.tree_map(lambda p: p[rows], fa)
+        sub = sub.apply_add(np.zeros(k, np.int32),
+                            jnp.max(sub.clock, axis=-1) + 1,
+                            np.full(k, 1 << 20, np.int32))
+        fb = jax.tree_util.tree_map(lambda p, s: p.at[rows].set(s), fa, sub)
+    digests = sync_digest.digest_of(fa, uni)
+    vv = sync_digest.version_vector(fa)
+    flat = len(encode_digest_frame(digests, vv))
+    root = len(encode_tree_root_frame(sync_tree.build_tree(digests), vv))
+
+    sa = SyncSession(fa, uni, digest_tree=True)
+    sb = SyncSession(fb, uni, digest_tree=True)
+    ra, rb = sync_pair(sa, sb)
+    assert ra.converged and rb.converged and ra.tree_mode
+    assert ra.diverged == k
+    spent = ra.tree_bytes_sent + ra.digest_bytes_sent
+    bound = {"converged": 0.05 * flat, "one_pct": 0.15 * flat,
+             "dense": flat + root}[shape]
+    assert spent <= bound, (shape, spent, flat, root)
+    assert sa.batch.to_wire(uni) == fa.merge(fb).to_wire(uni)
+
+
 def test_mixed_version_fleet_falls_back_flat_loudly():
     """A v3 tree-capable node gossiping with a v2 node: capability off,
     counter recorded, flat exchange, NO SyncProtocolError — the PR 6/7

@@ -1,11 +1,7 @@
 """Wire-path triage plumbing: the shared ``wirebulk`` flows (per-blob
 patch splice, hard-status raise, u64 zigzag egress guard), the
-native-vs-fallback counters they feed, and the bench-side consumers
-(``native_fraction``, round-over-round ``regression_warnings``,
-budget-proof required stages).
+native-vs-fallback counters they feed, and ``native_fraction``.
 """
-
-import json
 
 import numpy as np
 import pytest
@@ -165,73 +161,3 @@ def test_record_wire_shapes_counter_names():
         "wire.testleg.from_wire.fallback": 2,
         "wire.testleg.from_wire.fallback_reason.grammar": 2,
     }
-
-
-# ---- round-over-round artifact diffing --------------------------------------
-
-
-def test_regression_warnings_flags_30pct_movers():
-    from benchkit import artifacts
-
-    prior = {"ingest_obj_per_sec": 157000.0, "egress_obj_per_sec": 50000.0,
-             "value": 3.1e6, "kernel": "jnp_fold", "ingest_objects": 1000000,
-             "vs_baseline": 0.31, "zeroed": 5.0}
-    current = {"ingest_obj_per_sec": 100000.0,  # -36%: flagged
-               "egress_obj_per_sec": 55000.0,   # +10%: fine
-               "value": 3.1e6, "kernel": "native_fold",
-               "ingest_objects": 20000,          # workload size: ignored
-               "vs_baseline": 0.31, "zeroed": 0}
-    warns = artifacts.regression_warnings(prior, current)
-    fields = {w["field"] for w in warns}
-    assert fields == {"ingest_obj_per_sec", "zeroed"}
-    ingest = next(w for w in warns if w["field"] == "ingest_obj_per_sec")
-    assert ingest["ratio"] == pytest.approx(100000 / 157000, abs=1e-3)
-    zeroed = next(w for w in warns if w["field"] == "zeroed")
-    assert zeroed["ratio"] is None  # collapse to 0: ratio undefined
-    assert artifacts.regression_warnings(prior, dict(prior)) == []
-
-
-def test_latest_prior_artifact_picks_highest_round(tmp_path):
-    from benchkit import artifacts
-
-    (tmp_path / "BENCH_r01.json").write_text(
-        json.dumps({"n": 1, "parsed": {"metric": "m", "value": 1.0}})
-    )
-    (tmp_path / "BENCH_r05.json").write_text(
-        json.dumps({"n": 5, "parsed": {"metric": "m", "value": 5.0}})
-    )
-    name, parsed = artifacts.latest_prior_artifact(str(tmp_path))
-    assert name == "BENCH_r05.json"
-    assert parsed["value"] == 5.0
-    assert artifacts.latest_prior_artifact(str(tmp_path / "nope")) == (None, None)
-
-
-def test_latest_prior_artifact_tolerates_garbage(tmp_path):
-    from benchkit import artifacts
-
-    (tmp_path / "BENCH_r09.json").write_text("{not json")
-    name, parsed = artifacts.latest_prior_artifact(str(tmp_path))
-    assert (name, parsed) == (None, None)
-
-
-# ---- budget-proof validation stages -----------------------------------------
-
-
-def test_run_stage_required_ignores_budget(monkeypatch, capsys):
-    import sys
-
-    monkeypatch.setenv("CRDT_BENCH_BUDGET_S", "0")
-    for name in [n for n in sys.modules if n.startswith("benchkit")]:
-        sys.modules.pop(name)
-    import benchkit.core as core
-
-    ran = []
-    assert core.run_stage("opt", 10, lambda: ran.append("opt")) is None
-    assert core.run_stage(
-        "val", 10, lambda: ran.append("val") or "ok", required=True
-    ) == "ok"
-    assert ran == ["val"]
-    core.emit(value=1.0)
-    rec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert rec["opt_skipped"] == "budget"
-    assert "val_skipped" not in rec

@@ -101,6 +101,25 @@ def test_overlapped_equals_serial_bytes():
     assert a == b
 
 
+@pytest.mark.parametrize("fold_path", _FOLD_PATHS)
+def test_loop_equals_from_wire_merge_chain(fold_path):
+    """Every blob the loop emits over several rounds equals, byte for
+    byte, the batch engine's own chain on the same blobs: ``from_wire``
+    per fleet, a left fold of ``merge``, the self-merge plunger and
+    ``to_wire``."""
+    uni = _identity_uni(num_actors=16, deferred_capacity=2)
+    rep_blobs = _fleet_blobs(uni, np.random.RandomState(11), 1_000, 4)
+    res = PipelinedWireLoop(uni, fold_path=fold_path).run(
+        [rep_blobs] * 2, overlap=True)
+    assert res["fold_path"] == fold_path
+    assert res["merges"] == 2 * 4 * 1_000
+    fleets = [OrswotBatch.from_wire(blobs, uni) for blobs in rep_blobs]
+    acc = fleets[0]
+    for f in fleets[1:]:
+        acc = acc.merge(f)
+    assert res["out_blobs"] == acc.merge(acc).to_wire(uni)
+
+
 @pytest.mark.skipif(not _HAVE_ENGINE, reason="native engine unavailable")
 def test_e2e_shaped_blobs_take_native_path():
     """Regression for the round-5 ingest-collapse hypothesis: e2e-shaped
